@@ -1,13 +1,13 @@
-"""compat-symbol: version-moved jax symbols route through core/compat.py.
+"""compat-symbol: version-sensitive jax symbols route through core/compat.py.
 
-The container pins jax 0.4.37 while the codebase targets the current
-surface; the renamed/moved symbols (``shard_map`` — top-level with
-``check_vma``/``axis_names`` vs ``jax.experimental.shard_map`` with
-``check_rep``/``auto``; ``pltpu.CompilerParams`` vs
-``TPUCompilerParams``) are shimmed in exactly one place,
-``paddle_tpu/core/compat.py``.  A direct use anywhere else works on one
-jax and breaks on the other — the class of breakage that took the seed
-down (CHANGES.md, PR 1).
+jax has renamed ``shard_map`` (now top-level, ``check_vma`` /
+``axis_names``; once ``jax.experimental.shard_map`` with ``check_rep`` /
+``auto``) and ``pltpu.CompilerParams`` (once ``TPUCompilerParams``).
+The installed jax has only the new spellings, and the package reaches
+them in exactly one place, ``paddle_tpu/core/compat.py`` — so the old
+spellings are forbidden because they are gone, and a direct use of the
+new ones is forbidden because the next rename should land in one file
+(the class of breakage that took the seed down, CHANGES.md PR 1).
 
 Flagged outside ``core/compat.py``:
 
@@ -17,8 +17,8 @@ Flagged outside ``core/compat.py``:
 - ``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams`` (attribute or
   ``getattr(pltpu, "...")``) on any pallas-tpu module alias
 - ``check_rep=`` / ``auto=`` keywords on a ``shard_map`` call — the
-  0.4.37-only spelling; the compat wrapper takes ``check_vma=`` /
-  ``axis_names=`` on every jax
+  removed spelling; the compat wrapper takes ``check_vma=`` /
+  ``axis_names=``
 """
 
 from __future__ import annotations
@@ -51,28 +51,27 @@ def check(pf: ParsedFile, ctx) -> Iterable[Finding]:
             if mod == "jax.experimental.shard_map":
                 yield pf.finding(
                     RULE, node,
-                    "import from jax.experimental.shard_map — moved to "
-                    f"top-level jax in newer jax; {_FIX} "
-                    "(compat.shard_map)")
+                    "import from jax.experimental.shard_map — gone from "
+                    f"the installed jax; {_FIX} (compat.shard_map)")
             elif mod == "jax" and any(a.name == "shard_map"
                                       for a in node.names):
                 yield pf.finding(
                     RULE, node,
-                    "from jax import shard_map — absent on jax 0.4.37; "
-                    f"{_FIX} (compat.shard_map)")
+                    "from jax import shard_map — version-sensitive "
+                    f"symbol; {_FIX} (compat.shard_map)")
         elif isinstance(node, ast.Import):
             for a in node.names:
                 if a.name == "jax.experimental.shard_map":
                     yield pf.finding(
                         RULE, node,
-                        "import jax.experimental.shard_map — "
-                        f"version-moved; {_FIX} (compat.shard_map)")
+                        "import jax.experimental.shard_map — gone from "
+                        f"the installed jax; {_FIX} (compat.shard_map)")
         elif isinstance(node, ast.Attribute):
             key = expr_key(node)
             if key in ("jax.shard_map", "jax.experimental.shard_map"):
                 yield pf.finding(
                     RULE, node,
-                    f"direct use of {key} — version-moved symbol; "
+                    f"direct use of {key} — version-sensitive symbol; "
                     f"{_FIX} (compat.shard_map)")
             elif node.attr in _PARAMS and _is_pallas_tpu(node.value):
                 yield pf.finding(
@@ -96,7 +95,7 @@ def check(pf: ParsedFile, ctx) -> Iterable[Finding]:
                     if kw.arg in ("check_rep", "auto"):
                         yield pf.finding(
                             RULE, node,
-                            f"shard_map(..., {kw.arg}=) is the "
-                            "jax-0.4.37-only spelling; call "
+                            f"shard_map(..., {kw.arg}=) is a spelling "
+                            "the installed jax no longer has; call "
                             "compat.shard_map with check_vma=/"
                             "axis_names= instead")

@@ -1,65 +1,34 @@
-"""jax version-compatibility shims.
+"""The one routing point for version-sensitive jax symbols.
 
-The codebase targets the current jax surface (top-level ``jax.shard_map``
-with ``check_vma``/``axis_names``, ``pltpu.CompilerParams``); the pinned
-environment may ship an older jax (0.4.37) where those names live under
-``jax.experimental.shard_map`` with ``check_rep``/``auto`` and
-``pltpu.TPUCompilerParams``.  Every version-sensitive jax symbol is
-routed through this module so the skew is handled in exactly one place.
-
-Semantics mapping (new → 0.4.37):
-
-- ``check_vma=X``            → ``check_rep=X``  (same meaning: verify the
-  per-shard replication/varying-mesh-axes annotation)
-- ``axis_names={a, b}``      → ``auto=mesh.axis_names - {a, b}``  (new api
-  names the MANUAL axes; old api names the complement)
+The installed jax spells them ``jax.shard_map`` (``check_vma`` /
+``axis_names``) and ``pltpu.CompilerParams``; the earlier spellings
+(``jax.experimental.shard_map`` with ``check_rep`` / ``auto``,
+``pltpu.TPUCompilerParams``) are gone from it.  Everything in the
+package reaches these symbols through this module, so the next rename
+lands in exactly one place (pdtpu-lint's compat rule keeps it that way,
+docs/ANALYSIS.md).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 
 __all__ = ["shard_map", "pallas_compiler_params"]
 
 
-try:  # jax >= 0.6-ish: top-level function with the new kwarg names
-    from jax import shard_map as _new_shard_map
-    _NEW = callable(_new_shard_map)
-except ImportError:
-    _NEW = False
-
-if not _NEW:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
               axis_names=None, **kw):
-    """New-style ``jax.shard_map`` call surface on any supported jax."""
-    if _NEW:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
+    """``jax.shard_map``; ``None`` for ``check_vma`` / ``axis_names``
+    means "jax's default" and is not forwarded."""
     if check_vma is not None:
-        kw["check_rep"] = check_vma
-    # axis_names (the MANUAL set) is deliberately NOT translated to the
-    # old ``auto=complement`` parameter: 0.4.37's partial-auto shard_map
-    # hard-aborts in XLA backend_compile (observed on the CPU backend,
-    # sep+dp mesh).  Fully-manual is always correct — axes absent from
-    # the in/out specs are simply replicated through the region — it
-    # only forgoes the partial-auto partitioning optimization.
-    del axis_names
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
+        kw["check_vma"] = check_vma
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
-@functools.lru_cache(maxsize=1)
 def pallas_compiler_params():
-    """``pltpu.CompilerParams`` class (renamed from ``TPUCompilerParams``)."""
+    """The ``pltpu.CompilerParams`` class."""
     from jax.experimental.pallas import tpu as pltpu
-    return getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
+    return pltpu.CompilerParams

@@ -194,11 +194,7 @@ def install():
     # the concrete array class WITHOUT creating an array: jnp.zeros(())
     # would initialise the XLA backend at import time, which breaks
     # multi-process workers (jax.distributed.initialize must come first)
-    try:
-        from jax._src.array import ArrayImpl as _ArrayImpl
-    except ImportError:  # jax layout moved: fall back to a live array,
-        # accepting the backend init (single-process contexts only)
-        _ArrayImpl = type(jnp.zeros(()))
+    from jax._src.array import ArrayImpl as _ArrayImpl
     targets = [_ArrayImpl, jax.core.Tracer]
     seen = set()
 

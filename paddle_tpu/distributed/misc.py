@@ -228,7 +228,7 @@ class ParallelEnv:
     @property
     def device_id(self):
         sel = os.environ.get("FLAGS_selected_gpus") or \
-            os.environ.get("TPU_VISIBLE_DEVICES") or "0"
+            os.environ.get("TPU_VISIBLE_CHIPS") or "0"
         return int(sel.split(",")[0])
 
     @property

@@ -180,6 +180,14 @@ def zero_shard_spec(spec: P, shape, axis_name: str, axis_size: int,
 
 
 def _named(mesh, spec, host=False):
+    # without trailing Nones — the spelling jax gives a compiled step's
+    # outputs.  P("mp", None) places an array exactly as P("mp") does but
+    # is another jit-cache key, so a state spelled the long way going in
+    # would compile the step a second time when it came back out.
+    parts = tuple(spec)
+    while parts and parts[-1] is None:
+        parts = parts[:-1]
+    spec = P(*parts)
     if host:
         return NamedSharding(mesh, spec, memory_kind="pinned_host")
     return NamedSharding(mesh, spec)
@@ -509,14 +517,24 @@ class TrainStep:
                 old, new, is_leaf=lambda x: x is None)
             new_params = sel(params, new_params)
             new_opt = sel(state["opt"], new_opt)
-        if self.zero_offload and mesh is not None:
-            # keep updated optimizer states in pinned host memory; without
-            # this the donated step writes them back to HBM and the offload
-            # silently ends after one step
-            ospecs = self.opt_state_specs(new_opt, self.param_specs())
+        if mesh is not None:
+            # the state leaves the step laid out as shard_state() laid it
+            # out going in.  Left to itself XLA keeps e.g. ZeRO-1's
+            # updated params in the optimizer's sharded layout: the next
+            # call then misses the jit cache, compiles a second program
+            # and trains on with a layout nobody asked for (seen on the
+            # first four-chip run: 13 s second step, CHANGES.md PR 21).
+            # Offloaded optimizer states go back to pinned host memory —
+            # without that the donated step writes them to HBM and the
+            # offload silently ends after one step.
+            ospecs = self.opt_state_specs(new_opt, pspecs)
+            new_params = {k: jax.lax.with_sharding_constraint(
+                v, _named(mesh, pspecs[k])) for k, v in new_params.items()}
+            place = jax.device_put if self.zero_offload \
+                else jax.lax.with_sharding_constraint
             new_opt = {
-                slot: ({k: (jax.device_put(v, _named(mesh, ospecs[slot][k],
-                                                     host=True))
+                slot: ({k: (place(v, _named(mesh, ospecs[slot][k],
+                                            host=self.zero_offload))
                             if v is not None else None)
                         for k, v in val.items()}
                        if isinstance(val, dict) else val)

@@ -28,7 +28,7 @@ class Context:
     elastic_level: int = 0                # 0=off, 1=restart on failure
     elastic_timeout: float = 30.0
     max_restarts: int = 3
-    devices: Optional[str] = None         # visible device ids (CPU tests)
+    devices: Optional[str] = None         # chip ids the children may see
     host: str = dataclasses.field(default_factory=socket.gethostname)
 
     @property

@@ -130,7 +130,10 @@ def build_container(ctx, global_rank: int, local_rank: int, world_size: int,
         "PDTPU_LOCAL_RANK": str(local_rank),
     }
     if ctx.devices is not None:
-        env["CUDA_VISIBLE_DEVICES"] = ctx.devices
+        # the variable libtpu reads when the child first touches jax (a
+        # TPU process ignores CUDA_VISIBLE_DEVICES); the launcher itself
+        # never initialises a backend, so the chips are the child's
+        env["TPU_VISIBLE_CHIPS"] = ctx.devices
     log_path = os.path.join(ctx.log_dir,
                             f"workerlog.{global_rank}")
     entry = [sys.executable, "-u", ctx.script, *ctx.script_args]

@@ -63,10 +63,7 @@ def _active_mesh():
     Mosaic kernels cannot be auto-partitioned by GSPMD: under a mesh the
     kernel needs an explicit shard_map (column-parallel path below) or
     the XLA fallback.  One definition lives in ops/pallas."""
-    try:
-        from ..ops.pallas import _active_mesh as impl
-    except ImportError:  # pragma: no cover — jax internals moved
-        return None
+    from ..ops.pallas import _active_mesh as impl
     return impl()
 
 
@@ -124,11 +121,6 @@ def _kernel_column_sharded(matmul_fn, x2d, weight, scale, mesh):
         out_specs=P(bt, "mp"),
         check_vma=False)
     return f(x2d, weight, scale)
-
-
-def _int4_kernel_column_sharded(x2d, weight, scale, mesh):
-    return _kernel_column_sharded(_int4_matmul_fn(), x2d, weight, scale,
-                                  mesh)
 
 
 def _int4_matmul_fn():

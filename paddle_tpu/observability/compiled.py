@@ -19,7 +19,8 @@ sentinel's site attribution.  On top of the rows:
 
 Capture point: ``jax._src.interpreters.pxla.MeshComputation.compile``
 — the one choke point both normal jit dispatch and AOT lowering flow
-through in the pinned jax (0.4.37).  Wrapping it sees exactly one
+through (checked on the installed jax 0.9.0: tests/test_compiled_obs.py
+pins one ledger row per sentinel compile).  Wrapping it sees exactly one
 executable per real backend compile (cache hits never reach it), so the
 ledger adds ZERO compiles and changes no behavior; the wrapper is only
 installed while telemetry is enabled (``observability.enable()``), so
@@ -87,32 +88,34 @@ def chip_spec(kind: Optional[str] = None, override: Optional[dict] = None
               ) -> dict:
     """Resolve the roofline spec for a device kind.
 
-    ``kind=None`` asks jax for device 0's ``device_kind`` (falling back
-    to ``"cpu"`` when jax is absent — the standalone-load contract).
-    ``override`` merges user-supplied ``peak_flops``/``hbm_gbps`` on
-    top, the escape hatch for chips not in the table.
+    ``kind=None`` asks jax for device 0's ``device_kind`` (``"cpu"``
+    when jax is absent — the standalone-load contract).  ``override``
+    merges user-supplied ``peak_flops``/``hbm_gbps`` on top.  A kind the
+    table does not know is an error, never a default — unless the
+    override supplies both numbers, the way to describe a new chip.
     Returns ``{"kind", "peak_flops", "hbm_gbps"}``.
     """
     if kind is None:
-        kind = "cpu"
         try:
             import jax
-            kind = getattr(jax.devices()[0], "device_kind", "cpu")
-        except Exception:
-            pass
-    spec = None
-    best_len = -1
-    for k, v in CHIP_SPECS.items():
-        if kind.startswith(k) and len(k) > best_len:
-            spec, best_len = v, len(k)
-    if spec is None:
-        spec = CHIP_SPECS["cpu"]
-    out = {"kind": kind, "peak_flops": spec["peak_flops"],
-           "hbm_gbps": spec["hbm_gbps"]}
+        except ImportError:
+            kind = "cpu"
+        else:
+            kind = jax.devices()[0].device_kind
+    override = {k: v for k, v in (override or {}).items() if v is not None}
+    best = max((k for k in CHIP_SPECS if kind.startswith(k)), key=len,
+               default=None)
+    if best is None and not {"peak_flops", "hbm_gbps"} <= set(override):
+        raise ValueError(
+            f"no chip spec known for device kind {kind!r}; add it to "
+            "observability.compiled.CHIP_SPECS with its source, or pass "
+            "both peak_flops and hbm_gbps as chip_spec_override")
+    spec = CHIP_SPECS[best] if best is not None else {}
+    out = {"kind": kind, "peak_flops": spec.get("peak_flops"),
+           "hbm_gbps": spec.get("hbm_gbps")}
+    out.update(override)
     if out["hbm_gbps"] is None:
         out["hbm_gbps"] = _measured_cpu_gbps()
-    if override:
-        out.update({k: v for k, v in override.items() if v is not None})
     return out
 
 

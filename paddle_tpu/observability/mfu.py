@@ -34,14 +34,18 @@ PEAK_BF16_FLOPS = {
 
 @functools.lru_cache(maxsize=1)
 def peak_flops() -> float:
-    """Peak bf16 FLOP/s of device 0's chip kind (1e12 nominal on CPU)."""
+    """Peak bf16 FLOP/s of device 0's chip kind (1e12 nominal on CPU).
+    A kind the table does not know is an error, never a default: an MFU
+    over the wrong peak is a wrong number under a device metric's name."""
     import jax
-    d = jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu")
-    for k, v in PEAK_BF16_FLOPS.items():
-        if kind.startswith(k):
-            return v
-    return PEAK_BF16_FLOPS.get(kind, 197e12)
+    kind = jax.devices()[0].device_kind
+    best = max((k for k in PEAK_BF16_FLOPS if kind.startswith(k)),
+               key=len, default=None)
+    if best is None:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {kind!r}; add it to "
+            "observability.mfu.PEAK_BF16_FLOPS with its source")
+    return PEAK_BF16_FLOPS[best]
 
 
 def causal_lm_flops_per_token(n_params: int, num_layers: int,

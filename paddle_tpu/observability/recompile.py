@@ -6,9 +6,9 @@ dimension (or a Python scalar leaking into a traced signature) makes
 spends its life in the compiler — silently, because nothing in the
 runtime counts compilations.  The reference framework surfaces this
 through its profiler/monitor stack; jax exposes the raw signal via
-``jax.monitoring`` (pinned 0.4.37: ``/jax/core/compile/
-backend_compile_duration`` fires once per real backend compile, cache
-hits excluded).
+``jax.monitoring`` (``/jax/core/compile/backend_compile_duration``
+fires once per real backend compile, cache hits excluded — checked on
+the installed jax 0.9.0 by tests/test_observability.py).
 
 This module turns that signal into:
 
@@ -32,8 +32,8 @@ from typing import Optional
 __all__ = ["RecompileSentinel", "RecompileStormWarning",
            "BACKEND_COMPILE_EVENT"]
 
-# jax 0.4.37: jax._src.dispatch.BACKEND_COMPILE_EVENT — the string is
-# stable monitoring API surface; not imported from the private module.
+# jax._src.dispatch.BACKEND_COMPILE_EVENT — the string is stable
+# monitoring API surface; not imported from the private module.
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 UNATTRIBUTED = "<unattributed>"
@@ -129,19 +129,13 @@ class RecompileSentinel:
         self._active = True
 
     def uninstall(self) -> None:
-        """Deactivate; physically unregister when jax exposes the hook.
-
-        0.4.37 only has the private test helper, so the fallback is a
-        registered-but-inert listener (``_active`` gates everything)."""
+        """Deactivate and unregister the listener."""
         self._active = False
-        if not self._installed:
-            return
-        try:
-            from jax._src import monitoring as _m
-            _m._unregister_event_duration_listener_by_callback(self._on_event)
+        if self._installed:
+            import jax
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_event)
             self._installed = False
-        except Exception:
-            pass
 
     # -- the listener ------------------------------------------------------
 

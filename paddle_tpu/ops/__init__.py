@@ -727,14 +727,10 @@ _EXCLUDE = {"jax", "jnp", "np", "dispatch", "more", "Optional", "Sequence",
 __all__ = [_n for _n in dir() if not _n.startswith("_") and _n not in _EXCLUDE]
 
 # Register Pallas TPU kernels into the dispatch table (no-op off-TPU: the
-# registry gates on the active backend at call time).
-try:
-    from . import pallas as _pallas_kernels  # noqa: F401
-except ImportError as _e:  # pallas unavailable (e.g. minimal jax build);
-    # real defects inside the kernel pack (NameError &c.) must fail loudly,
-    # not silently lose the TPU kernels — hence ImportError only
-    import warnings as _warnings
-    _warnings.warn(f"pallas kernel pack not loaded: {_e}")
+# registry gates on the active backend at call time).  A kernel pack that
+# does not import is an error, not a warning: a run without it would be
+# green with no kernel in any program.
+from . import pallas as _pallas_kernels  # noqa: F401,E402
 
 
 # -- linalg tail (reference: python/paddle/tensor/linalg.py round-2 batch) --

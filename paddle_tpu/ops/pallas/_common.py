@@ -8,6 +8,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Mosaic grants a kernel 16 MiB of scoped VMEM unless it asks for more;
+# a v5e core has 128 MiB.  The weight-resident kernels (fused_mlp,
+# fused_norm_qkv, mega_decode) ask for VMEM_LIMIT, and their
+# ``supported()`` gates admit only block geometries whose estimated
+# allocation fits VMEM_BUDGET — the gap is headroom for the internal
+# scratch the estimates do not model (measured 1-5 MiB by deviceless
+# v5e compiles, tests/test_multichip_pallas_compile.py).
+VMEM_LIMIT = 48 * 2 ** 20
+VMEM_BUDGET = 40 * 2 ** 20
+
 
 def pick_block(n: int, preferred: int, quantum: int = 128) -> int:
     """Largest multiple of ``quantum`` that divides ``n`` and is

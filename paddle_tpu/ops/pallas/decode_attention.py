@@ -140,6 +140,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lens, scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv * g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, lens, qg, k_pool, v_pool)
     return out.reshape(b, h, d)
 

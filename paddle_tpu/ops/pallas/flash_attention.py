@@ -182,6 +182,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
         compiler_params=_DIMS,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]  # (b,s,h,d), (b,h,s)
 
@@ -444,6 +445,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
             compiler_params=_DIMS,
+            name="flash_attention_bwd",
         )(qt, kt, vt, dot, lse4, delta4)
         dq = dqp.sum(axis=2).astype(q.dtype)
         dk = dk_h.reshape(b, hkv, group, sk, d).sum(axis=2).astype(k.dtype)
@@ -481,6 +483,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         compiler_params=_DIMS,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse4, delta4)
 
     kernel_dq = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -503,6 +506,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_DIMS,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse4, delta4)
 
     # fold GQA group: sum per-q-head dk/dv into kv heads

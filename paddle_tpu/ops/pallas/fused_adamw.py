@@ -108,6 +108,7 @@ def fused_adamw_update(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
         input_output_aliases={1: 0, 3: 1, 4: 2},
         compiler_params=_pcp()(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_adamw",
     )(scal, p2, g2, m2, v2)
     return (new_p.reshape(shape), new_m.reshape(shape),
             new_v.reshape(shape))

@@ -38,14 +38,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.compat import pallas_compiler_params as _pcp
 from .. import tuning
+from ._common import VMEM_BUDGET, VMEM_LIMIT
 from ._common import mxu_precision as _precision
 from ._common import pick_block as _pick_block
 
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_I = 512
-# resident VMEM budget for supported(): weight blocks + x/acc tiles must
-# fit well under the ~16 MiB scoped limit (autotuner may shrink blocks)
-VMEM_BUDGET = 12 * 2 ** 20
 
 
 def _round_up(n: int, q: int) -> int:
@@ -118,9 +116,14 @@ def _blocks(t, h, i, block_t, block_i, itemsize, op="fused_swiglu_mlp"):
 
 
 def _vmem_estimate(bt, bi, h, itemsize):
-    # x tile + 2 weight blocks + down block + f32 acc + f32 g/u tiles
-    return (bt * h * itemsize + 3 * h * bi * itemsize
-            + bt * h * 4 + 2 * bt * bi * 4)
+    """Scoped VMEM Mosaic allocates for one grid cell: every pipelined
+    operand is double-buffered (x tile, out tile, the three weight
+    blocks), plus the f32 accumulator, the f32 g/u tiles with their
+    rounded product, and the rounded copy of the accumulator at emit."""
+    pipelined = 2 * (2 * bt * h + 3 * h * bi) * itemsize
+    acc = bt * h * 4
+    temps = 2 * bt * bi * 4 + bt * bi * itemsize + bt * h * itemsize
+    return pipelined + acc + temps
 
 
 def _pad_tokens(x, bt):
@@ -159,8 +162,10 @@ def fused_swiglu_mlp(x, w_gate, w_up, w_down, block_t=None, block_i=None,
         out_shape=jax.ShapeDtypeStruct((tp, h), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, h), jnp.float32)],
         compiler_params=_pcp()(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="fused_swiglu_mlp",
     )(xp, w_gate, w_up, w_down)
     return out[:t]
 
@@ -193,8 +198,10 @@ def fused_gelu_mlp(x, w1, b1, w2, b2, block_t=None, block_i=None,
         out_shape=jax.ShapeDtypeStruct((tp, h), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, h), jnp.float32)],
         compiler_params=_pcp()(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="fused_gelu_mlp",
     )(xp, w1, b1.reshape(1, f), w2, b2.reshape(1, h))
     return out[:t]
 
@@ -204,6 +211,12 @@ def supported(x, w1, w2, op: str = "fused_swiglu_mlp") -> bool:
     dtypes, and block geometry inside the VMEM budget.  ``op`` selects
     whose tuned-config table the block estimate resolves against — the
     gate must agree with the blocks the kernel will actually use."""
+    if op == "fused_gelu_mlp":
+        # the installed Pallas TPU lowering has no rule for erf/erfc, so
+        # the exact-gelu kernel cannot compile for the chip at any
+        # shape; GPT's FFN keeps the XLA composition (the kernel's
+        # arithmetic stays pinned by its interpret-mode test)
+        return False
     if x.ndim != 2 or w1.ndim != 2 or w2.ndim != 2:
         return False
     h, i = w1.shape

@@ -42,10 +42,10 @@ from jax.experimental import pallas as pl
 
 from ...core.compat import pallas_compiler_params as _pcp
 from .. import tuning
+from ._common import VMEM_BUDGET, VMEM_LIMIT
 from ._common import mxu_precision as _precision
 
 DEFAULT_BLOCK_T = 256
-VMEM_BUDGET = 12 * 2 ** 20
 
 
 @functools.lru_cache(maxsize=8)
@@ -86,19 +86,21 @@ def _kernel(x_ref, g_ref, wq_ref, wk_ref, wv_ref, cos_ref, sin_ref,
 
     def rope(y, r_ref, t_ref):
         # cos/sin tiled across heads and the rotation — all MXU passes
-        # against {0, ±1} selectors (exact in bf16, stored in x.dtype to
-        # halve their VMEM residency), accumulation in f32.  The
-        # projection is rounded to x.dtype FIRST, mirroring the unfused
-        # path (rope there runs on the projection layer's output dtype).
+        # against {0, ±1} selectors (one non-zero per column, so exact
+        # in any dtype), accumulation in f32.  Mosaic wants both
+        # operands of a matmul in ONE dtype and refuses an fp32 contract
+        # precision on bf16 operands, so each selector is stored in its
+        # partner's dtype (R in x.dtype, T in the cos/sin dtype) and the
+        # precision request follows the operands.  The projection is
+        # rounded to x.dtype FIRST, mirroring the unfused path (rope
+        # there runs on the projection layer's output dtype).
         yb = y.astype(x_ref.dtype)
-        cos = jax.lax.dot(cos_ref[...], t_ref[...],
-                          precision=jax.lax.Precision.HIGHEST,
+        cprec = _precision(cos_ref.dtype)
+        cos = jax.lax.dot(cos_ref[...], t_ref[...], precision=cprec,
                           preferred_element_type=jnp.float32)
-        sin = jax.lax.dot(sin_ref[...], t_ref[...],
-                          precision=jax.lax.Precision.HIGHEST,
+        sin = jax.lax.dot(sin_ref[...], t_ref[...], precision=cprec,
                           preferred_element_type=jnp.float32)
-        rot = jax.lax.dot(yb, r_ref[...],
-                          precision=jax.lax.Precision.HIGHEST,
+        rot = jax.lax.dot(yb, r_ref[...], precision=prec,
                           preferred_element_type=jnp.float32)
         return yb.astype(jnp.float32) * cos + rot * sin
 
@@ -109,12 +111,31 @@ def _kernel(x_ref, g_ref, wq_ref, wk_ref, wv_ref, cos_ref, sin_ref,
     v_ref[...] = proj(wv_ref).astype(v_ref.dtype)
 
 
-def _resident_bytes(h, nq, nk, head_dim, itemsize):
-    # weights + the two rotate selectors + the two tile selectors, all
-    # stored in the activation dtype
-    return (h * (nq + 2 * nk) * itemsize
-            + (nq * nq + nk * nk) * itemsize
-            + head_dim * (nq + nk) * itemsize)
+def _block_t(t, h, nq, nk, head_dim, block_t=None):
+    """Token-tile rows: explicit arg, then tuned configs (trace time,
+    ops.tuning), then the default — sublane-aligned, at most T."""
+    if block_t is None:
+        cfg = tuning.tuned_config(
+            "fused_rms_rope_qkv",
+            tuning.geom_key(h=h, nq=nq, nk=nk, hd=head_dim))
+        block_t = cfg.get("block_t", DEFAULT_BLOCK_T)
+    bt = max(8, int(block_t) // 8 * 8)
+    return min(bt, -(-t // 8) * 8)
+
+
+def _vmem_estimate(bt, h, nq, nk, head_dim, itemsize):
+    """Scoped VMEM Mosaic allocates: the weight-side operands (one
+    buffer each — their block never changes), the double-buffered x /
+    cos / sin / q / k / v tiles, and the f32 temporaries of one tile
+    (x, projection, tiled cos and sin, rotation, product).  The T
+    selectors and cos/sin tiles are counted at 4 bytes: the rope tables
+    may arrive in f32 whatever the activation dtype."""
+    resident = ((h * (nq + 2 * nk) + nq * nq + nk * nk + h) * itemsize
+                + head_dim * (nq + nk) * 4)
+    pipelined = 2 * (bt * (h + nq + 2 * nk) * itemsize
+                     + 2 * bt * head_dim * 4)
+    temps = 6 * bt * max(h, nq) * 4
+    return resident + pipelined + temps
 
 
 def fused_rms_rope_qkv(x, norm_weight, w_q, w_k, w_v, cos, sin,
@@ -131,13 +152,7 @@ def fused_rms_rope_qkv(x, norm_weight, w_q, w_k, w_v, cos, sin,
     t, h = x.shape
     nq = w_q.shape[1]
     nk = w_k.shape[1]
-    if block_t is None:
-        cfg = tuning.tuned_config(
-            "fused_rms_rope_qkv",
-            tuning.geom_key(h=h, nq=nq, nk=nk, hd=head_dim))
-        block_t = cfg.get("block_t", DEFAULT_BLOCK_T)
-    bt = max(8, int(block_t) // 8 * 8)
-    bt = min(bt, -(-t // 8) * 8)
+    bt = _block_t(t, h, nq, nk, head_dim, block_t)
     rem = t % bt
     xp = jnp.pad(x, ((0, bt - rem), (0, 0))) if rem else x
     cosp = jnp.pad(cos, ((0, bt - rem), (0, 0))) if rem else cos
@@ -146,8 +161,8 @@ def fused_rms_rope_qkv(x, norm_weight, w_q, w_k, w_v, cos, sin,
 
     rq = jnp.asarray(_rot_selector(nq, head_dim), x.dtype)
     rk = jnp.asarray(_rot_selector(nk, head_dim), x.dtype)
-    tq = jnp.asarray(_tile_selector(head_dim, nq), x.dtype)
-    tk = jnp.asarray(_tile_selector(head_dim, nk), x.dtype)
+    tq = jnp.asarray(_tile_selector(head_dim, nq), cos.dtype)
+    tk = jnp.asarray(_tile_selector(head_dim, nk), cos.dtype)
 
     def tmap(it):
         return (it, 0)
@@ -181,8 +196,10 @@ def fused_rms_rope_qkv(x, norm_weight, w_q, w_k, w_v, cos, sin,
             jax.ShapeDtypeStruct((tp, nk), x.dtype),
             jax.ShapeDtypeStruct((tp, nk), x.dtype),
         ],
-        compiler_params=_pcp()(dimension_semantics=("parallel",)),
+        compiler_params=_pcp()(dimension_semantics=("parallel",),
+                               vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="fused_rms_rope_qkv",
     )(xp, norm_weight.reshape(1, h), w_q, w_k, w_v, cosp, sinp,
       rq, rk, tq, tk)
     return q[:t], k[:t], v[:t]
@@ -190,7 +207,8 @@ def fused_rms_rope_qkv(x, norm_weight, w_q, w_k, w_v, cos, sin,
 
 def supported(x, w_q, w_k, head_dim: int) -> bool:
     """Mosaic-shape gate: 128-aligned widths, even head_dim, fp dtypes,
-    all weight-side operands resident within the VMEM budget."""
+    and the allocation at the block the kernel will use (resident
+    weight-side operands, tiles, temporaries) within the VMEM budget."""
     if x.ndim != 2 or w_q.ndim != 2 or w_k.ndim != 2:
         return False
     h = x.shape[1]
@@ -201,5 +219,6 @@ def supported(x, w_q, w_k, head_dim: int) -> bool:
         return False
     if x.dtype not in (jnp.float32, jnp.bfloat16):
         return False
-    return _resident_bytes(h, nq, nk, head_dim,
-                           x.dtype.itemsize) <= VMEM_BUDGET
+    bt = _block_t(x.shape[0], h, nq, nk, head_dim)
+    return _vmem_estimate(bt, h, nq, nk, head_dim,
+                          x.dtype.itemsize) <= VMEM_BUDGET

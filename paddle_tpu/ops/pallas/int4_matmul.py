@@ -154,6 +154,7 @@ def int4_matmul(x, packed, scale, block_k2: int = DEFAULT_BLOCK_K2,
             compiler_params=_pcp()(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="int4_matmul",
         )(xe, xo, packed, s2)
 
     bk2 = _pick_block(k2, block_k2)
@@ -173,4 +174,5 @@ def int4_matmul(x, packed, scale, block_k2: int = DEFAULT_BLOCK_K2,
         compiler_params=_pcp()(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="int4_matmul",
     )(xe, xo, packed, s2)

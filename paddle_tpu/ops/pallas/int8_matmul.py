@@ -102,6 +102,7 @@ def int8_matmul(x, w, scale, block_k=None, block_n=None,
             compiler_params=_pcp()(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="int8_matmul",
         )(x, w, s2)
 
     bk = _pick_block(k, block_k)
@@ -121,4 +122,5 @@ def int8_matmul(x, w, scale, block_k=None, block_n=None,
         compiler_params=_pcp()(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="int8_matmul",
     )(x, w, s2)

@@ -121,6 +121,7 @@ def grouped_bgmv(x, a, b, idx, block_o=None, interpret: bool = False):
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, c, d_out), x.dtype),
         interpret=interpret,
+        name="lora_bgmv",
     )(idx, x, a, b)
 
 

@@ -63,12 +63,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.compat import pallas_compiler_params as _pcp
 from .. import tuning
+from ._common import VMEM_BUDGET, VMEM_LIMIT
 from ._common import mxu_precision as _precision
 from .fused_norm_qkv import _rot_selector, _tile_selector
 
 NEG_INF = -1e30
-VMEM_BUDGET = 12 * 2 ** 20
 
 
 def _kernel(tables_ref, starts_ref, lens_ref,            # scalar prefetch
@@ -99,16 +100,15 @@ def _kernel(tables_ref, starts_ref, lens_ref,            # scalar prefetch
         def rope(y, r_ref, t_ref):
             # identical arithmetic to fused_norm_qkv._kernel: the
             # projection rounds to x.dtype FIRST (mirroring the unfused
-            # path), the {0,±1}/{0,1} selector matmuls are exact
+            # path); each {0,±1}/{0,1} selector is stored in its matmul
+            # partner's dtype and the precision follows the operands
             yb = y.astype(x_ref.dtype)
-            cos = jax.lax.dot(cos_ref[0], t_ref[...],
-                              precision=jax.lax.Precision.HIGHEST,
+            cprec = _precision(cos_ref.dtype)
+            cos = jax.lax.dot(cos_ref[0], t_ref[...], precision=cprec,
                               preferred_element_type=jnp.float32)
-            sin = jax.lax.dot(sin_ref[0], t_ref[...],
-                              precision=jax.lax.Precision.HIGHEST,
+            sin = jax.lax.dot(sin_ref[0], t_ref[...], precision=cprec,
                               preferred_element_type=jnp.float32)
-            rot = jax.lax.dot(yb, r_ref[...],
-                              precision=jax.lax.Precision.HIGHEST,
+            rot = jax.lax.dot(yb, r_ref[...], precision=prec,
                               preferred_element_type=jnp.float32)
             return yb.astype(jnp.float32) * cos + rot * sin
 
@@ -235,8 +235,8 @@ def mega_decode(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
 
     rq = jnp.asarray(_rot_selector(nq, head_dim), x.dtype)
     rk = jnp.asarray(_rot_selector(nk, head_dim), x.dtype)
-    tq = jnp.asarray(_tile_selector(head_dim, nq), x.dtype)
-    tk = jnp.asarray(_tile_selector(head_dim, nk), x.dtype)
+    tq = jnp.asarray(_tile_selector(head_dim, nq), cos.dtype)
+    tk = jnp.asarray(_tile_selector(head_dim, nk), cos.dtype)
 
     grid = (b, mb)
 
@@ -298,24 +298,31 @@ def mega_decode(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
             jax.ShapeDtypeStruct((b, c, nk), x.dtype),
             jax.ShapeDtypeStruct((b, c, nk), x.dtype),
         ],
+        compiler_params=_pcp()(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="mega_decode",
     )(block_tables, starts, lens, x, norm_weight.reshape(1, h),
       w_q, w_k, w_v, w_o, cos, sin, rq, rk, tq, tk, k_pool, v_pool)
     return out, k_out, v_out
 
 
 def _resident_bytes(c, h, nq, nk, head_dim, page, h_kv, itemsize):
-    """Everything the kernel keeps VMEM-resident at once: the five
-    weight-side operands, the four rope selectors, the x/cos/sin/out
-    tiles, two pool page blocks, and the scratch state."""
+    """Everything the kernel keeps in scoped VMEM at once: the five
+    weight-side operands, the four rope selectors (T at 4 bytes — the
+    rope tables may arrive in f32), the double-buffered x/cos/sin/out
+    tiles and pool page blocks, the scratch state, and the f32
+    temporaries of the pre-attention stage (as fused_norm_qkv)."""
     g = (nq // head_dim) // h_kv
     weights = (h * (nq + 2 * nk) + nq * h) * itemsize
-    selectors = (nq * nq + nk * nk + head_dim * (nq + nk)) * itemsize
-    tiles = (2 * c * h + 2 * c * head_dim + 2 * c * nk) * itemsize
-    pages = 2 * page * h_kv * head_dim * itemsize
+    selectors = (nq * nq + nk * nk) * itemsize \
+        + head_dim * (nq + nk) * 4
+    tiles = 2 * ((2 * c * h + 2 * c * nk) * itemsize
+                 + 2 * c * head_dim * 4)
+    pages = 4 * page * h_kv * head_dim * itemsize
     scratch = (h_kv * g * c * head_dim + 2 * c * nk) * itemsize \
         + h_kv * g * c * (head_dim + 2) * 4
-    return weights + selectors + tiles + pages + scratch
+    temps = 6 * c * max(h, nq) * 4
+    return weights + selectors + tiles + pages + scratch + temps
 
 
 def supported(x, w_q, w_k, w_o, head_dim: int, cache=None) -> bool:

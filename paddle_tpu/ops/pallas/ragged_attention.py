@@ -153,6 +153,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv * c * g, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_tables, starts, lens, qg, k_pool, v_pool)
     return out.reshape(b, h_kv, c, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, c, h, d)

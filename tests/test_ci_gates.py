@@ -19,10 +19,7 @@ CI = os.path.join(REPO, "tools", "ci.py")
 
 def _run_gate(name, timeout):
     env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           # the pytest process may hold libtpu (compile-only topologies
-           # in test_memproof_dcn); let the gate subprocess load it too
-           "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
     r = subprocess.run([sys.executable, CI, "--only", name], env=env,
                        cwd=REPO, capture_output=True, text=True,
                        timeout=timeout)

@@ -197,8 +197,15 @@ class TestRoofline:
         assert v5e["peak_flops"] == 197e12
         # v5p must not be swallowed by the shorter "TPU v5" prefix
         assert chip_spec("TPU v5p")["hbm_gbps"] == 2765.0
-        unknown = chip_spec("FancyChip 9000")
-        assert unknown["peak_flops"] == CHIP_SPECS["cpu"]["peak_flops"]
+        # a kind the table does not know is an error, not the CPU row —
+        # unless the override describes the chip in full
+        with pytest.raises(ValueError, match="FancyChip 9000"):
+            chip_spec("FancyChip 9000")
+        with pytest.raises(ValueError):
+            chip_spec("FancyChip 9000", override={"hbm_gbps": 999.0})
+        fancy = chip_spec("FancyChip 9000", override={
+            "peak_flops": 1e15, "hbm_gbps": 999.0})
+        assert fancy["peak_flops"] == 1e15 and fancy["hbm_gbps"] == 999.0
         ov = chip_spec("TPU v4", override={"hbm_gbps": 999.0})
         assert ov["hbm_gbps"] == 999.0 and ov["peak_flops"] == 275e12
         # CPU stand-in is measured, positive, sane

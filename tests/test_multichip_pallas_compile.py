@@ -1,17 +1,26 @@
-"""Deviceless TPU compile of the FLASH-chunk ring — the only coverage
-of Pallas-kernels-under-SPMD-partitioning possible without a pod.
+"""Deviceless compiles for a described TPU v5e — what interpret mode and
+the CPU mesh cannot show.
 
-Guards the two M107 multi-chip ring bugs (PartitionId from
-lax.axis_index under partial-manual shard_map; Mosaic kernels landing
-in the SPMD partitioner when any mesh axis stays auto): both only
-reproduce when compiling FOR a multi-chip TPU topology with the Pallas
-pack registered — the CPU test mesh never sees them.
+The chip's own compiler (Mosaic + XLA:TPU, installed here) compiles for
+a ``v5e:2x2`` topology that is described, not attached.  Three groups:
 
-~12 s: one tiny llama (2 layers) + ring(sep2) x ZeRO-3(2) AOT compile
-against a deviceless v5e:2x2 topology.
+- Pallas kernels under SPMD partitioning (the flash-chunk ring, the
+  quantized kernel under an ``mp`` mesh): the two M107 multi-chip ring
+  bugs only reproduce when compiling FOR a multi-chip TPU topology;
+- every kernel of ``paddle_tpu/ops/pallas/`` at Llama-2-7B widths, or at
+  the widest preset its ``supported()`` gate admits — a kernel whose
+  gate says yes must get through Mosaic, and what Mosaic refuses the
+  gate must decline (VMEM limits, operand types: none of it shows in
+  interpret mode);
+- the two programs ``chip_smoke.py`` runs on the chip, built by its own
+  builders: the 7B-width ``TrainStep`` and the engine's ragged step.
+
+Nothing runs, so nothing here says a result is right or fast.  The
+topology is described inside a module-scoped fixture: only the xdist
+worker that is handed this file loads libtpu, and it skips where the
+topology cannot be described.  Keep every such test in THIS file.
 """
 
-import dataclasses  # noqa: F401 — mirrors memproof's config handling
 import os
 
 import pytest
@@ -23,114 +32,135 @@ pytestmark = pytest.mark.skipif(
     os.environ.get("PDTPU_SKIP_DEVICELESS") == "1",
     reason="deviceless TPU compile disabled by env")
 
+# Llama-2-7B widths (models/llama.py PRESETS["llama2-7b"])
+H, I, NH, HD = 4096, 11008, 32, 128
+# llama-350m-hd128: the widest preset whose weights the two
+# weight-resident kernels (fused_norm_qkv, mega_decode) can hold in VMEM
+H350, NH350 = 1024, 8
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
-def test_flash_ring_compiles_for_multichip_tpu(monkeypatch):
+
+@pytest.fixture(scope="module")
+def topo():
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a deviceless compile is written to the persistent cache but cannot
+    # be read back without a chip (the next run warns and recompiles)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield td
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def shape(one_chip):
+    """``shape((m, n), dtype)`` — an abstract array on one described chip."""
+    def make(dims, dtype=BF16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The suite pins the PROCESS backend to cpu (conftest), but these
+    programs are traced FOR a TPU: every "which backend?" the package
+    asks while tracing answers tpu, so the kernel registry and the
+    ``supported()`` gates decide as they do on the chip."""
+    from paddle_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _hybrid_llama_step(topo, hybrid_configs, *, heads, zero_stage=None):
+    """A tiny ring-attention llama TrainStep lowered for the four
+    described chips under ``hybrid_configs``."""
     from jax.sharding import NamedSharding
 
     from paddle_tpu import amp, nn, optimizer
     from paddle_tpu.distributed import fleet
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models.llama import LlamaConfig, causal_lm_loss, llama
+
+    s = fleet.DistributedStrategy()
+    s.hybrid_configs = hybrid_configs
+    fleet.init(is_collective=True, strategy=s, devices=list(topo.devices))
+    cfg = LlamaConfig(hidden_size=128, intermediate_size=256,
+                      num_hidden_layers=2, num_attention_heads=heads,
+                      num_key_value_heads=heads, vocab_size=256,
+                      max_position_embeddings=512, dtype="bfloat16",
+                      context_parallel="ring")
+    with nn.meta_init():
+        model = llama(cfg)
+    opt = optimizer.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters())
+    model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
+    step = TrainStep(model, causal_lm_loss, opt, zero_stage=zero_stage)
+    bsh = NamedSharding(step.mesh, step.batch_spec)
+    batch = {"input_ids": jax.ShapeDtypeStruct((2, 512), jnp.int32,
+                                               sharding=bsh),
+             "labels": jax.ShapeDtypeStruct((2, 512), jnp.int64,
+                                            sharding=bsh)}
+    return step.lower(step.abstract_state(), batch).compile()
+
+
+def test_flash_ring_compiles_for_multichip_tpu(topo, as_tpu, monkeypatch):
+    """ring(sep2) x ZeRO-3(2): guards PartitionId from lax.axis_index
+    under partial-manual shard_map, and Mosaic kernels landing in the
+    SPMD partitioner when any mesh axis stays auto."""
+    from paddle_tpu.distributed import fleet
 
     # chunk is 256 here; drop the ring's flash threshold so the Pallas
     # path (the thing under test) is what compiles
     monkeypatch.setenv("PDTPU_RING_FLASH_MIN_CHUNK", "64")
-    # the suite pins the PROCESS backend to cpu (conftest), but we are
-    # compiling FOR a TPU topology: treat the dispatch backend as tpu so
-    # the kernel registry serves the Pallas entry being tested
-    from paddle_tpu.ops import dispatch
-    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
-
-    td = topologies.get_topology_desc(platform="tpu",
-                                      topology_name="v5e:2x2")
     fleet._reset()
     try:
-        s = fleet.DistributedStrategy()
-        s.hybrid_configs = {"sharding_degree": 2, "sep_degree": 2}
-        fleet.init(is_collective=True, strategy=s, devices=list(td.devices))
-        cfg = LlamaConfig(hidden_size=128, intermediate_size=256,
-                          num_hidden_layers=2, num_attention_heads=2,
-                          num_key_value_heads=2, vocab_size=256,
-                          max_position_embeddings=512, dtype="bfloat16",
-                          context_parallel="ring")
-        with nn.meta_init():
-            model = llama(cfg)
-        opt = optimizer.AdamW(learning_rate=1e-4,
-                              parameters=model.parameters())
-        model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
-        step = TrainStep(model, causal_lm_loss, opt, zero_stage=3)
-        astate = step.abstract_state()
-        bsh = NamedSharding(step.mesh, step.batch_spec)
-        batch = {"input_ids": jax.ShapeDtypeStruct((2, 512), jnp.int32,
-                                                   sharding=bsh),
-                 "labels": jax.ShapeDtypeStruct((2, 512), jnp.int64,
-                                                sharding=bsh)}
-        compiled = step.lower(astate, batch).compile()
+        compiled = _hybrid_llama_step(
+            topo, {"sharding_degree": 2, "sep_degree": 2}, heads=2,
+            zero_stage=3)
         # the Pallas kernel must actually BE in the program (flash path
         # engaged, not the einsum fallback silently covering for it)
-        hlo = compiled.as_text()
-        assert "tpu_custom_call" in hlo, \
+        assert "tpu_custom_call" in compiled.as_text(), \
             "flash ring did not engage — einsum fallback compiled instead"
-        ma = compiled.memory_analysis()
-        assert ma.temp_size_in_bytes > 0
+        assert compiled.memory_analysis().temp_size_in_bytes > 0
     finally:
         fleet._reset()
 
 
-def test_flash_ring_with_mp_head_sharding(monkeypatch):
+def test_flash_ring_with_mp_head_sharding(topo, as_tpu, monkeypatch):
     """The hspec path: heads sharded over mp WHILE the flash ring runs —
     exercises the manual-over-all axis set with a >1 mp axis."""
-    from jax.experimental import topologies
-    from jax.sharding import NamedSharding
-
-    from paddle_tpu import amp, nn, optimizer
     from paddle_tpu.distributed import fleet
-    from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models.llama import LlamaConfig, causal_lm_loss, llama
 
     monkeypatch.setenv("PDTPU_RING_FLASH_MIN_CHUNK", "64")
-    from paddle_tpu.ops import dispatch
-    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
-
-    td = topologies.get_topology_desc(platform="tpu",
-                                      topology_name="v5e:2x2")
     fleet._reset()
     try:
-        s = fleet.DistributedStrategy()
-        s.hybrid_configs = {"mp_degree": 2, "sep_degree": 2}
-        fleet.init(is_collective=True, strategy=s, devices=list(td.devices))
-        cfg = LlamaConfig(hidden_size=128, intermediate_size=256,
-                          num_hidden_layers=2, num_attention_heads=4,
-                          num_key_value_heads=4, vocab_size=256,
-                          max_position_embeddings=512, dtype="bfloat16",
-                          context_parallel="ring")
-        with nn.meta_init():
-            model = llama(cfg)
-        opt = optimizer.AdamW(learning_rate=1e-4,
-                              parameters=model.parameters())
-        model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
-        step = TrainStep(model, causal_lm_loss, opt)
-        astate = step.abstract_state()
-        bsh = NamedSharding(step.mesh, step.batch_spec)
-        batch = {"input_ids": jax.ShapeDtypeStruct((2, 512), jnp.int32,
-                                                   sharding=bsh),
-                 "labels": jax.ShapeDtypeStruct((2, 512), jnp.int64,
-                                                sharding=bsh)}
-        compiled = step.lower(astate, batch).compile()
+        compiled = _hybrid_llama_step(
+            topo, {"mp_degree": 2, "sep_degree": 2}, heads=4)
         assert "tpu_custom_call" in compiled.as_text(), \
             "flash ring with mp head sharding did not engage"
     finally:
         fleet._reset()
 
 
-def test_int4_kernel_compiles_for_multichip_mp(monkeypatch):
+def test_int4_kernel_compiles_for_multichip_mp(topo, monkeypatch):
     """The int4 dequant kernel under an mp mesh: the column-parallel
     layer routes through an explicit shard_map (GSPMD cannot partition
     Mosaic kernels); the generic weight_only_linear entry and the
     row-parallel layer fall back to XLA under a mesh.  Both must COMPILE
     for a real multichip TPU topology."""
-    from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import paddle_tpu as pt
@@ -141,25 +171,24 @@ def test_int4_kernel_compiles_for_multichip_mp(monkeypatch):
     from paddle_tpu.nn.layer import functional_call, raw_params
 
     monkeypatch.setattr(QN, "_use_int4_kernel", lambda: True)
-    # spy: the column layer must actually ENGAGE the shard_map path (a
-    # stale branch condition silently compiling the XLA fallback would
-    # keep this test green for no coverage)
+    # spy on the function the column layer CALLS: it must actually
+    # engage the shard_map path (a stale branch condition silently
+    # compiling the XLA fallback would keep this test green for no
+    # coverage) — the one guard that the quantized kernel serves under mp
     engaged = []
-    real = QN._int4_kernel_column_sharded
+    real = QN._kernel_column_sharded
 
     def spy(*a, **k):
         engaged.append(1)
         return real(*a, **k)
-    monkeypatch.setattr(QN, "_int4_kernel_column_sharded", spy)
+    monkeypatch.setattr(QN, "_kernel_column_sharded", spy)
 
-    td = topologies.get_topology_desc(platform="tpu",
-                                      topology_name="v5e:2x2")
     fleet._reset()
     try:
         s = fleet.DistributedStrategy()
         s.hybrid_configs = {"mp_degree": 2, "dp_degree": 2}
         hcg = fleet.init(is_collective=True, strategy=s,
-                         devices=list(td.devices))
+                         devices=list(topo.devices))
         pt.seed(0)
         col = QN.QuantizedColumnParallelLinear(
             ColumnParallelLinear(256, 512, has_bias=False),
@@ -182,7 +211,201 @@ def test_int4_kernel_compiles_for_multichip_mp(monkeypatch):
         xs = jax.ShapeDtypeStruct((2, 1, 256), jnp.bfloat16,
                                   sharding=NamedSharding(hcg.mesh, P()))
         with hcg.mesh:
-            jax.jit(fwd).lower(ps, xs).compile()   # must not raise
+            compiled = jax.jit(fwd).lower(ps, xs).compile()
         assert engaged, "column layer never took the shard_map kernel path"
+        assert "tpu_custom_call" in compiled.as_text()
     finally:
         fleet._reset()
+
+
+# -- every kernel, one chip, real widths -------------------------------------
+
+def _pools(shape, page, heads=NH, batch=8, ctx=2048):
+    mb = ctx // page
+    pool = shape((batch * mb, page, heads, HD))
+    return pool, shape((batch, mb), I32), shape((batch,), I32)
+
+
+def _case_fused_swiglu_mlp(shape):
+    from paddle_tpu.ops.pallas import fused_mlp as m
+    x, wg, wd = shape((2048, H)), shape((H, I)), shape((I, H))
+    assert m.supported(x, wg, wd)
+    return m.fused_swiglu_mlp, (x, wg, wg, wd), ["fused_swiglu_mlp"]
+
+
+def _case_flash_attention(shape):
+    from paddle_tpu.ops.pallas import flash_attention as m
+    q = shape((1, 2048, NH, HD))
+    assert m.supported(q, q, q, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(
+            lambda *a: m.flash_attention(*a, causal=True)
+            .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+    return fwd_bwd, (q, q, q), ["flash_attention_fwd", "flash_attention_bwd"]
+
+
+def _case_ragged(page):
+    def case(shape):
+        from paddle_tpu.ops.pallas import ragged_attention as m
+        pool, tables, lens = _pools(shape, page)
+        args = (shape((8, 16, NH, HD)), pool, pool, tables, lens, lens)
+        assert m.supported(*args)
+        return m.ragged_paged_attention, args, ["ragged_paged_attention"]
+    return case
+
+
+def _case_paged_attention(shape):
+    from paddle_tpu.ops.pallas import decode_attention as m
+    pool, tables, lens = _pools(shape, 16)
+    args = (shape((8, NH, HD)), pool, pool, tables, lens)
+    assert m.supported(*args)
+    return m.paged_attention, args, ["paged_attention"]
+
+
+def _case_fused_adamw(shape):
+    from paddle_tpu.ops.pallas import fused_adamw as m
+    p, c = shape((H, I), F32), shape((), F32)
+    assert m.eligible(p)
+
+    def update(p, g, mom, v, lr, c1, c2):
+        return m.fused_adamw_update(p, g, mom, v, lr, c1, c2, beta1=0.9,
+                                    beta2=0.999, eps=1e-8, wd=0.1)
+    return update, (p, p, p, p, c, c, c), ["fused_adamw"]
+
+
+def _case_int8_matmul(shape):
+    from paddle_tpu.ops.pallas.int8_matmul import int8_matmul
+    args = (shape((8, H)), shape((H, I), I8), shape((I,), F32))
+    return int8_matmul, args, ["int8_matmul"]
+
+
+def _case_int4_matmul(shape):
+    from paddle_tpu.ops.pallas.int4_matmul import int4_matmul
+    args = (shape((8, I)), shape((I // 2, H), I8), shape((H,), F32))
+    return int4_matmul, args, ["int4_matmul"]
+
+
+def _case_lora_bgmv(shape):
+    from paddle_tpu.ops.pallas import lora_matmul as m
+    x, a, b = shape((8, 16, H)), shape((4, H, 16)), shape((4, 16, I))
+    assert m.supported(x, a, b)
+    return m.grouped_bgmv, (x, a, b, shape((8,), I32)), ["lora_bgmv"]
+
+
+def _case_fused_rms_rope_qkv(shape):
+    from paddle_tpu.ops.pallas import fused_norm_qkv as m
+    x, w = shape((2048, H350)), shape((H350, H350))
+    assert m.supported(x, w, w, HD)
+    rope = shape((2048, HD), F32)        # the tables arrive in float32
+
+    def qkv(x, g, wq, wk, wv, cos, sin):
+        return m.fused_rms_rope_qkv(x, g, wq, wk, wv, cos, sin, HD)
+    return qkv, (x, shape((H350,)), w, w, w, rope, rope), \
+        ["fused_rms_rope_qkv"]
+
+
+def _case_mega_decode(shape):
+    from paddle_tpu.ops.pallas import mega_decode as m
+    pool, tables, lens = _pools(shape, 16, heads=NH350, ctx=256)
+    x, w, rope = (shape((8, 16, H350)), shape((H350, H350)),
+                  shape((8, 16, HD), F32))
+    assert m.supported(x, w, w, w, HD, cache=(pool, pool))
+
+    def layer(x, g, wq, wk, wv, wo, cos, sin, kp, vp, t, starts, ln):
+        return m.mega_decode(x, g, wq, wk, wv, wo, cos, sin, kp, vp, t,
+                             starts, ln, head_dim=HD)
+    return layer, (x, shape((H350,)), w, w, w, w, rope, rope, pool, pool,
+                   tables, lens, lens), ["mega_decode"]
+
+
+KERNEL_CASES = {
+    "fused_swiglu_mlp-7b": _case_fused_swiglu_mlp,
+    "flash_attention-7b": _case_flash_attention,
+    "ragged_paged_attention-7b-page16": _case_ragged(16),
+    "ragged_paged_attention-7b-page64": _case_ragged(64),
+    "paged_attention-7b": _case_paged_attention,
+    "fused_adamw-7b": _case_fused_adamw,
+    "int8_matmul-7b": _case_int8_matmul,
+    "int4_matmul-7b": _case_int4_matmul,
+    "lora_bgmv-7b": _case_lora_bgmv,
+    "fused_rms_rope_qkv-350m-hd128": _case_fused_rms_rope_qkv,
+    "mega_decode-350m-hd128": _case_mega_decode,
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(case, shape, as_tpu, smoke):
+    """The kernel's own ``supported()`` admits the shapes, Mosaic
+    compiles them, and the kernel is in the program under its name."""
+    fn, args, names = KERNEL_CASES[case](shape)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    found = smoke.pallas_kernels(hlo)
+    assert all(n in found for n in names), (names, found)
+
+
+def test_gates_decline_what_mosaic_refuses(as_tpu):
+    """At Llama-2-7B widths the weight-resident kernels do not fit VMEM
+    and the exact-gelu kernel has no erf to lower to: their gates say
+    no, and the model keeps the XLA composition."""
+    from paddle_tpu.ops.pallas import fused_mlp, fused_norm_qkv, mega_decode
+
+    def z(*dims):
+        return jax.ShapeDtypeStruct(dims, BF16)
+    x, w = z(2048, H), z(H, H)
+    assert not fused_norm_qkv.supported(x, w, w, HD)
+    pool = z(64, 16, NH, HD)
+    assert not mega_decode.supported(z(8, 16, H), w, w, w, HD,
+                                     cache=(pool, pool))
+    # llama-1b widths: compiles to 50 MiB of scoped VMEM, past the limit
+    x1, w1 = z(2048, 2048), z(2048, 2048)
+    assert not fused_norm_qkv.supported(x1, w1, w1, HD)
+    assert not fused_mlp.supported(x, z(H, 4 * H), z(4 * H, H),
+                                   op="fused_gelu_mlp")
+
+
+def test_smoke_train_step_compiles_for_v5e(topo, as_tpu, smoke):
+    """chip_smoke's TrainStep at its real size: Llama-2-7B widths, its
+    depth, batch 1 x seq 2048, ``fused_ops="auto"``."""
+    from jax.sharding import NamedSharding
+
+    from paddle_tpu import nn
+    from paddle_tpu.distributed import fleet
+
+    fleet._reset()
+    try:
+        fleet.init(is_collective=True, devices=[topo.devices[0]])
+        with nn.meta_init():
+            _, step = smoke.build_train(smoke.PRESET, smoke.LAYERS,
+                                        smoke.SEQ)
+        bsh = NamedSharding(step.mesh, step.batch_spec)
+        ids = jax.ShapeDtypeStruct((1, smoke.SEQ), jnp.int32, sharding=bsh)
+        compiled = step.lower(step.abstract_state(),
+                              {"input_ids": ids, "labels": ids}).compile()
+    finally:
+        fleet._reset()
+    found = smoke.pallas_kernels(compiled.as_text())
+    layers = smoke.LAYERS
+    assert found.get("flash_attention_fwd") == layers, found
+    assert found.get("flash_attention_bwd") == layers, found
+    assert found.get("fused_swiglu_mlp") == layers, found
+    assert found.get("fused_adamw", 0) > 0, found
+    assert "fused_rms_rope_qkv" not in found, found
+    # state + temporaries leave room on a 16 GiB chip for the phases
+    # that follow in the same process
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13 * 2 ** 30
+
+
+def test_smoke_serve_step_compiles_for_v5e(one_chip, as_tpu, smoke):
+    """chip_smoke's Engine at Llama-2-7B widths: the one ragged step."""
+    from paddle_tpu import nn
+
+    with nn.meta_init():
+        _, eng = smoke.build_engine(smoke.PRESET, smoke.LAYERS,
+                                    max_batch=4, max_seq_len=128)
+    found = smoke.pallas_kernels(
+        smoke.serve_step_hlo(eng, sharding=one_chip))
+    assert found.get("ragged_paged_attention") == smoke.LAYERS, found
+    assert found.get("fused_swiglu_mlp") == smoke.LAYERS, found
+    assert "mega_decode" not in found, found

@@ -331,8 +331,10 @@ class TestClusterServing:
     ROLES = ("prefill", "prefill", "decode", "decode")
 
     def _env(self):
-        cache = os.path.abspath(
-            os.path.join(REPO, ".pytest_cache", "xla_cache"))
+        # the workers share the harness's compile cache (conftest.py):
+        # the variable where it is set, else the one fixed directory
+        cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+            os.path.join(REPO, ".pytest_cache", "xla_cache")
         env = {**os.environ,
                "PDTPU_REPO": REPO,
                "JAX_PLATFORMS": "cpu",

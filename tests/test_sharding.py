@@ -111,3 +111,48 @@ def test_stage2_grads_use_zero_sharded_specs():
     _, step1, state1 = _run("os", steps=1)
     g1 = step1.grad_specs(state1["params"], step1.param_specs())
     assert g1 == step1.param_specs()
+
+
+@pytest.mark.parametrize("level", ["os", "os_g", "p_g_os"])
+def test_state_keeps_its_layout_and_the_step_compiles_once(level):
+    """The state leaves the step laid out as it went in.  Left to
+    itself XLA returns ZeRO-1's updated params in the optimizer's
+    sharded layout: the second call then compiles another program
+    (13 s at Llama-2-7B widths on four real chips, PR 21) and every
+    later step runs with a layout nobody asked for."""
+    from paddle_tpu.observability.recompile import RecompileSentinel
+
+    fleet._reset()
+    pt.seed(0)
+    s = fleet.DistributedStrategy()
+    s.hybrid_configs = {"sharding_degree": 2, "mp_degree": 2,
+                        "dp_degree": 2}
+    mesh = fleet.init(strategy=s).mesh
+    model = llama("tiny")
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    model, opt, _ = group_sharded_parallel(model, opt, level)
+    step = TrainStep(model, causal_lm_loss, opt, mesh=mesh)
+    state = step.init_state(seed=0)
+    ids = np.random.default_rng(0).integers(0, 256, size=(8, 32))
+    batch = {"input_ids": jnp.asarray(ids, jnp.int32),
+             "labels": jnp.asarray(np.roll(ids, -1, 1), jnp.int32)}
+
+    def layout(st):
+        return {jax.tree_util.keystr(path): leaf.sharding
+                for path, leaf in jax.tree_util.tree_leaves_with_path(st)}
+
+    before = layout(state)
+    state, _ = step(state, batch)
+    after = layout(state)
+    assert after.keys() == before.keys()
+    moved = [k for k in before if after[k] != before[k]]
+    assert not moved, f"layout changed across one step: {moved[:4]}"
+    sentinel = RecompileSentinel()
+    sentinel.install()
+    try:
+        for _ in range(2):
+            state, m = step(state, batch)
+        float(m["loss"])
+    finally:
+        sentinel.uninstall()
+    assert sentinel.compiles() == 0

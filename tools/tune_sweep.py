@@ -42,7 +42,7 @@ def main():
                   f"| {stats['tokens_per_sec_per_chip']} |", flush=True)
             out[name] = {"mfu": round(mfu, 4),
                          "ms_per_step": stats["ms_per_step"]}
-        except Exception as e:  # keep sweeping on OOM/relay errors
+        except Exception as e:  # keep sweeping past a config that OOMs
             print(f"| {name} | ERROR {type(e).__name__} | | |", flush=True)
             out[name] = {"error": str(e)[:200]}
     print()
