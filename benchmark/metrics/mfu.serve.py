@@ -1,0 +1,23 @@
+"""The whole step's share of the chip's bf16 peak over the traced
+seconds: the forward operations the algorithm needs for the prompt and
+output tokens processed there (layers' matrices per processed token, the
+head per emitted token, attention over the mean context), over the
+traced window and the peak.  Padded lanes earn nothing."""
+
+from benchmark import trace_reduce
+from benchmark.work import transformer
+
+
+def read(ctx):
+    t = ctx["counters"].get("traced") or {}
+    bw = trace_reduce.busy_and_window(ctx["trace"])
+    if not t.get("occ_count") or bw["window_s"] <= 0:
+        return None
+    eng = ctx["engine"]
+    lanes = eng["max_batch"] * eng["prefill_chunk"]
+    processed = t["occ_sum"] * lanes
+    live_tokens, slots = transformer.live_context(ctx["counters"], eng)
+    flops = transformer.serve_flops(
+        ctx["config"], ctx["layers"], processed,
+        ctx["counters"].get("traced_emitted", 0), live_tokens / slots)
+    return 100.0 * flops / (bw["window_s"] * ctx["peaks"]["bf16_flops_per_s"])
