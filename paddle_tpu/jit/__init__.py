@@ -27,8 +27,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import random as prandom
 from ..nn.layer import Layer, functional_call, raw_params, trainable_mask
 from ..observability import _state as _obs_state
-from ..resilience import _state as _rs_state
+from ..observability.regions import region as _region
+from ..observability.spans import TRACE_PREFIX as _TRACE_PREFIX
 from ..observability.spans import span as _span
+from ..resilience import _state as _rs_state
 from . import control_flow
 from .control_flow import (GraphBreakError, case, cond, switch_case,
                            while_loop)
@@ -220,6 +222,11 @@ class TrainStep:
     program with the state donated (in-place buffer reuse, reference:
     InterpreterCore inplace pass).
     """
+
+    # calls so far, counted on the host: the step number of the profiler's
+    # ``pdtpu.train`` step event (no device read; a class default, so an
+    # instance built without __init__ counts too)
+    _calls = 0
 
     def __init__(self, model: Layer, loss_fn: Callable, optimizer,
                  scaler=None, mesh: Optional[Mesh] = None,
@@ -427,6 +434,9 @@ class TrainStep:
     def _loss(self, train_params, frozen, batch, key, scaler_state):
         from ..nn.layer import _swapped_params, _train_mode
         params = {**frozen, **train_params}
+        # "forward" is the parent of the model's regions
+        # (observability/regions.py); autodiff carries the names into the
+        # backward pass
         with jax.named_scope("forward"), _swapped_params(self.model, params), \
                 _train_mode(self.model, True), prandom.rng_scope(key):
             loss = self.loss_fn(self.model, batch)
@@ -506,7 +516,7 @@ class TrainStep:
             gspecs = self.grad_specs(grads, pspecs)
             grads = {k: jax.lax.with_sharding_constraint(
                 g, _named(mesh, gspecs[k])) for k, g in grads.items()}
-        with jax.named_scope("optimizer"):
+        with _region("optimizer"):
             new_params, new_opt = self.optimizer.apply(grads, state["opt"], params)
         if scaler_state is not None and "found_inf" in scaler_state:
             # paddle GradScaler semantics: skip the whole optimizer step on
@@ -584,6 +594,18 @@ class TrainStep:
         return self._run(state, batch, accumulate)
 
     def _run(self, state, batch, accumulate):
+        # one step event per call on the host timeline of whatever
+        # profiler session is live (TraceMe's own check when none is), on
+        # every path: the harness never enables telemetry
+        n = self._calls
+        self._calls = n + 1
+        if not jax.profiler.TraceAnnotation.is_enabled():
+            return self._dispatch(state, batch, accumulate)
+        with jax.profiler.StepTraceAnnotation(_TRACE_PREFIX + "train",
+                                              step_num=n):
+            return self._dispatch(state, batch, accumulate)
+
+    def _dispatch(self, state, batch, accumulate):
         if self.mesh is not None:
             with self.mesh:
                 return self._compiled(state, batch, accumulate)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer import Layer, ParamAttr
+from ..observability.regions import region
 from ..nn.layers_common import Dropout, Embedding, LayerList, LayerNorm
 from ..distributed.mp_layers import (ColumnParallelLinear,
                                      ParallelCrossEntropy,
@@ -119,21 +120,35 @@ class GPTAttention(Layer):
         # x here is already ln_1-normed, exactly the projections' input
         from ..incubate.nn.functional import lora_delta
 
-        def _out(t):
-            y = self.out_proj(t)
-            d = lora_delta(lora, t, "attn.out_proj")
-            return y if d is None else y + d
+        with region("attn_proj"):
+            qkv = self.qkv_proj(x)
+            dqkv = lora_delta(lora, x, "attn.qkv_proj")
+            if dqkv is not None:
+                qkv = qkv + dqkv
+            qkv = qkv.reshape(b, s, 3, cfg.num_attention_heads,
+                              cfg.head_dim)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q = constrain(q, ("dp", "sharding"), None, "mp", None)
+            k = constrain(k, ("dp", "sharding"), None, "mp", None)
+            v = constrain(v, ("dp", "sharding"), None, "mp", None)
+        with region("attn_core"):
+            out, new_cache = self._attend(q, k, v, attn_mask, cache,
+                                          seq_lens, block_tables,
+                                          span_starts)
+        with region("attn_proj"):
+            y = self.out_proj(out)
+            d = lora_delta(lora, out, "attn.out_proj")
+            if d is not None:
+                y = y + d
+            y = self.dropout(y)
+        return y if cache is None else (y, new_cache)
 
-        qkv = self.qkv_proj(x)
-        dqkv = lora_delta(lora, x, "attn.qkv_proj")
-        if dqkv is not None:
-            qkv = qkv + dqkv
-        qkv = qkv.reshape(b, s, 3, cfg.num_attention_heads,
-                          cfg.head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = constrain(q, ("dp", "sharding"), None, "mp", None)
-        k = constrain(k, ("dp", "sharding"), None, "mp", None)
-        v = constrain(v, ("dp", "sharding"), None, "mp", None)
+    def _attend(self, q, k, v, attn_mask, cache, seq_lens, block_tables,
+                span_starts):
+        """The attention core, whichever kernel serves it: (out as
+        (b, s, hidden), the new cache or None)."""
+        cfg = self.cfg
+        b, s = q.shape[:2]
         if cache is not None and block_tables is not None:
             # paged KV pools (serving.Engine) — see LlamaAttention
             from ..incubate.nn.functional import (paged_decode_attend,
@@ -144,13 +159,13 @@ class GPTAttention(Layer):
                 out, new_cache = ragged_paged_attend(
                     cache, q, k, v, block_tables, span_starts, seq_lens)
                 out = out.reshape(b, s, cfg.hidden_size)
-                return self.dropout(_out(out)), new_cache
+                return out, new_cache
             if s == 1 and seq_lens is not None:
                 out, new_cache = paged_decode_attend(
                     cache, q[:, 0], k[:, 0], v[:, 0], block_tables,
                     seq_lens)
                 out = out[:, None].reshape(b, s, cfg.hidden_size)
-                return self.dropout(_out(out)), new_cache
+                return out, new_cache
             plens = seq_lens if seq_lens is not None else \
                 jnp.full((b,), s, jnp.int32)
             new_cache = paged_prefill_write(cache, k, v, block_tables,
@@ -159,7 +174,7 @@ class GPTAttention(Layer):
                 q, k, v, is_causal=True,
                 dropout_p=cfg.attention_dropout, training=self.training)
             out = out.reshape(b, s, cfg.hidden_size)
-            return self.dropout(_out(out)), new_cache
+            return out, new_cache
         if cache is not None and s == 1 and seq_lens is not None:
             # single-token decode against the dense (or int8-quantized
             # 4-tuple) KV cache — shared cache-arity dispatch
@@ -167,7 +182,7 @@ class GPTAttention(Layer):
             out, new_cache = decode_attend_cache(
                 cache, q[:, 0], k[:, 0], v[:, 0], seq_lens)
             out = out[:, None].reshape(b, s, cfg.hidden_size)
-            return self.dropout(_out(out)), new_cache
+            return out, new_cache
         if cache is not None:
             from ..incubate.nn.functional import prefill_write_cache
             new_cache = prefill_write_cache(cache, k, v)
@@ -175,12 +190,12 @@ class GPTAttention(Layer):
                 q, k, v, is_causal=True,
                 dropout_p=cfg.attention_dropout, training=self.training)
             out = out.reshape(b, s, cfg.hidden_size)
-            return self.dropout(_out(out)), new_cache
+            return out, new_cache
         out = F.scaled_dot_product_attention(
             q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
             dropout_p=cfg.attention_dropout, training=self.training)
         out = out.reshape(b, s, cfg.hidden_size)
-        return self.dropout(_out(out))
+        return out, None
 
 
 class GPTMLP(Layer):
@@ -199,6 +214,10 @@ class GPTMLP(Layer):
         self.dropout = Dropout(cfg.hidden_dropout)
 
     def forward(self, x, lora=None):
+        with region("mlp"):
+            return self._forward(x, lora)
+
+    def _forward(self, x, lora):
         cfg = self.cfg
         from .llama import _use_fused
         from ..ops.tuning import geom_key
@@ -252,17 +271,27 @@ class GPTDecoderLayer(Layer):
 
     def forward(self, x, attn_mask=None, cache=None, seq_lens=None,
                 block_tables=None, span_starts=None, lora=None):
+        # regions (observability/regions.py): each block opens its own;
+        # LayerNorm is the shared nn layer, so its region is opened here,
+        # and the residual adds sit in their block's region (XLA fuses
+        # them into its last matmul, and a fusion carries its root's path)
+        with region("norm"):
+            h = self.ln_1(x)
         if cache is not None:
-            attn, cache = self.attn(self.ln_1(x), attn_mask, cache=cache,
+            attn, cache = self.attn(h, attn_mask, cache=cache,
                                     seq_lens=seq_lens,
                                     block_tables=block_tables,
                                     span_starts=span_starts, lora=lora)
+        else:
+            attn = self.attn(h, attn_mask)
+        with region("attn_proj"):
             x = x + attn
-            x = x + self.mlp(self.ln_2(x), lora=lora)
-            return x, cache
-        x = x + self.attn(self.ln_1(x), attn_mask)
-        x = x + self.mlp(self.ln_2(x))
-        return x
+        with region("norm"):
+            h = self.ln_2(x)
+        h = self.mlp(h, lora=lora)
+        with region("mlp"):
+            x = x + h
+        return x if cache is None else (x, cache)
 
 
 class GPTModel(Layer):
@@ -352,14 +381,15 @@ class GPTModel(Layer):
         per-slot adapter ids).  Returns (hidden, new_caches)."""
         b, s = input_ids.shape
         decode = (s == 1 and seq_lens is not None)
-        if span_starts is not None:
-            pos = span_starts[:, None] + jnp.arange(s)[None, :]
-        elif decode:
-            pos = seq_lens[:, None]
-        else:
-            pos = jnp.arange(s)[None, :]
-        x = self.embed_tokens(input_ids) + self.embed_positions(pos)
-        x = self.embed_dropout(x)
+        with region("embed"):
+            if span_starts is not None:
+                pos = span_starts[:, None] + jnp.arange(s)[None, :]
+            elif decode:
+                pos = seq_lens[:, None]
+            else:
+                pos = jnp.arange(s)[None, :]
+            x = self.embed_tokens(input_ids) + self.embed_positions(pos)
+            x = self.embed_dropout(x)
         kw = {} if block_tables is None else {"block_tables": block_tables}
         if span_starts is not None:
             kw["span_starts"] = span_starts
@@ -373,7 +403,8 @@ class GPTModel(Layer):
             lambda inner, x, cache: inner(
                 x, cache=cache, seq_lens=lens_arg,
                 lora=None if lit is None else (next(lit), laids), **kw))
-        return self.ln_f(x), new_caches
+        with region("norm"):
+            return self.ln_f(x), new_caches
 
     def forward(self, input_ids, attn_mask=None, position_ids=None,
                 caches=None, seq_lens=None, block_tables=None,
@@ -393,17 +424,19 @@ class GPTModel(Layer):
             raise ValueError(
                 f"sequence length {input_ids.shape[1]} exceeds "
                 f"max_position_embeddings={cfg.max_position_embeddings}")
-        if position_ids is None:
-            position_ids = jnp.arange(input_ids.shape[1])[None, :]
-        x = (self.embed_tokens(input_ids)
-             + self.embed_positions(position_ids))
-        x = self.embed_dropout(x)
+        with region("embed"):
+            if position_ids is None:
+                position_ids = jnp.arange(input_ids.shape[1])[None, :]
+            x = (self.embed_tokens(input_ids)
+                 + self.embed_positions(position_ids))
+            x = self.embed_dropout(x)
         if cfg.pipeline_stages > 1:
             x = self.h(x, attn_mask)
         else:
             for layer in self.h:
                 x = layer(x, attn_mask)
-        return self.ln_f(x)
+        with region("norm"):
+            return self.ln_f(x)
 
 
 class GPTForCausalLM(CachedGenerationMixin, Layer):
@@ -422,11 +455,12 @@ class GPTForCausalLM(CachedGenerationMixin, Layer):
         self.loss_fn = ParallelCrossEntropy(ignore_index=-100)
 
     def logits(self, hidden):
-        if self.cfg.tie_word_embeddings:
-            w = self.model.embed_tokens.weight
-            logits = hidden @ w.T
-            return constrain(logits, ("dp", "sharding"), None, "mp")
-        return self.lm_head(hidden)
+        with region("lm_head_loss"):
+            if self.cfg.tie_word_embeddings:
+                w = self.model.embed_tokens.weight
+                logits = hidden @ w.T
+                return constrain(logits, ("dp", "sharding"), None, "mp")
+            return self.lm_head(hidden)
 
     def forward(self, input_ids, labels=None, attn_mask=None,
                 position_ids=None):
@@ -434,9 +468,10 @@ class GPTForCausalLM(CachedGenerationMixin, Layer):
         logits = self.logits(hidden)
         if labels is None:
             return logits
-        loss = self.loss_fn(logits.astype(jnp.float32), labels)
-        valid = (labels != -100)
-        return jnp.sum(loss * valid) / jnp.maximum(jnp.sum(valid), 1)
+        with region("lm_head_loss"):
+            loss = self.loss_fn(logits.astype(jnp.float32), labels)
+            valid = (labels != -100)
+            return jnp.sum(loss * valid) / jnp.maximum(jnp.sum(valid), 1)
 
 def gpt(name_or_config="tiny", **overrides) -> GPTForCausalLM:
     cfg = (PRESETS[name_or_config] if isinstance(name_or_config, str)

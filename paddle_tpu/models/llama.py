@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer import Layer
+from ..observability.regions import region
 from ..distributed.mp_layers import (ColumnParallelLinear, ParallelCrossEntropy,
                                      RowParallelLinear, VocabParallelEmbedding,
                                      constrain)
@@ -153,7 +154,8 @@ class LlamaRMSNorm(Layer):
             (cfg.hidden_size,), default_initializer=I.Constant(1.0))
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self.eps)
+        with region("norm"):
+            return F.rms_norm(x, self.weight, self.eps)
 
 
 class LlamaAttention(Layer):
@@ -176,6 +178,24 @@ class LlamaAttention(Layer):
     def forward(self, x, cos, sin, attn_mask=None, cache=None,
                 seq_lens=None, block_tables=None, span_starts=None,
                 norm_weight=None, lora=None):
+        from ..incubate.nn.functional import lora_delta
+
+        with region("attn_proj"):
+            q, k, v = self._qkv(x, cos, sin, norm_weight, lora)
+        with region("attn_core"):
+            out, new_cache = self._attend(q, k, v, attn_mask, cache,
+                                          seq_lens, block_tables,
+                                          span_starts)
+        with region("attn_proj"):
+            y = self.o_proj(out)
+            d = lora_delta(lora, out, "self_attn.o_proj")
+            if d is not None:
+                y = y + d
+        return y if cache is None else (y, new_cache)
+
+    def _qkv(self, x, cos, sin, norm_weight, lora):
+        """Projections (+ per-slot LoRA deltas) and RoPE: (q, k, v) as
+        (b, s, heads, head_dim)."""
         cfg = self.cfg
         b, s = x.shape[:2]
         roped = False
@@ -186,10 +206,6 @@ class LlamaAttention(Layer):
         # decoder layer pins norm_weight=None when lora is threaded).
         from ..incubate.nn.functional import lora_delta
 
-        def _o(t):
-            y = self.o_proj(t)
-            d = lora_delta(lora, t, "self_attn.o_proj")
-            return y if d is None else y + d
         if norm_weight is not None:
             # fused RMSNorm→QKV→RoPE (docs/KERNELS.md): ``x`` is the
             # UN-NORMED residual stream — the decoder layer skipped its
@@ -249,6 +265,14 @@ class LlamaAttention(Layer):
         v = constrain(v, ("dp", "sharding"), None, "mp", None)
         if not roped:
             q, k = F.apply_rotary_pos_emb(q, k, cos, sin)
+        return q, k, v
+
+    def _attend(self, q, k, v, attn_mask, cache, seq_lens, block_tables,
+                span_starts):
+        """The attention core, whichever kernel serves it: (out as
+        (b, s, heads * head_dim), the new cache or None)."""
+        cfg = self.cfg
+        b, s = q.shape[:2]
         if cache is not None and block_tables is not None:
             # paged KV pools (serving.Engine): the cache is the GLOBAL
             # (num_blocks, page, H_kv, D) pool pair (or int8 4-tuple),
@@ -265,14 +289,14 @@ class LlamaAttention(Layer):
                     cache, q, k, v, block_tables, span_starts, seq_lens)
                 out = out.reshape(
                     b, s, cfg.num_attention_heads * cfg.head_dim)
-                return _o(out), new_cache
+                return out, new_cache
             if s == 1 and seq_lens is not None:
                 out, new_cache = paged_decode_attend(
                     cache, q[:, 0], k[:, 0], v[:, 0], block_tables,
                     seq_lens)
                 out = out[:, None].reshape(
                     b, s, cfg.num_attention_heads * cfg.head_dim)
-                return _o(out), new_cache
+                return out, new_cache
             # paged prefill: causal attention over the (bucket-padded)
             # prompt; pages written only at positions < seq_lens, so
             # padding rows never land in the pool
@@ -282,7 +306,7 @@ class LlamaAttention(Layer):
                                             plens)
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
             out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
-            return _o(out), new_cache
+            return out, new_cache
         if cache is not None and s == 1 and seq_lens is not None:
             # single-token decode against the dense KV cache (2-tuple fp
             # or int8-quantized 4-tuple) — shared cache-arity dispatch
@@ -291,7 +315,7 @@ class LlamaAttention(Layer):
                 cache, q[:, 0], k[:, 0], v[:, 0], seq_lens)
             out = out[:, None].reshape(b, s,
                                        cfg.num_attention_heads * cfg.head_dim)
-            return _o(out), new_cache
+            return out, new_cache
         if cache is not None:
             # single-shot prefill: causal attention over the prompt, cache
             # written at [0, s) (chunked prefill lives in incubate's
@@ -300,7 +324,7 @@ class LlamaAttention(Layer):
             new_cache = prefill_write_cache(cache, k, v)
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
             out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
-            return _o(out), new_cache
+            return out, new_cache
         if cfg.context_parallel and attn_mask is None:
             from ..distributed import cp
             q = cp.split_sequence(q)
@@ -312,7 +336,7 @@ class LlamaAttention(Layer):
             out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
                                                  is_causal=attn_mask is None)
         out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
-        return _o(out)
+        return out, None
 
 
 class LlamaMLP(Layer):
@@ -330,6 +354,12 @@ class LlamaMLP(Layer):
                                            weight_attr=attr, sequence_parallel=sp)
 
     def forward(self, x, lora=None):
+        # the region lives here so that every user of the block (a MoE
+        # layer's experts too) carries it
+        with region("mlp"):
+            return self._forward(x, lora)
+
+    def _forward(self, x, lora):
         cfg = self.cfg
         from ..ops.tuning import geom_key
 
@@ -472,13 +502,17 @@ class LlamaDecoderLayer(Layer):
                 else:
                     cos2, sin2 = cos, sin
                 attn = self.self_attn
-                x, cache = mega_decode_layer(
-                    x, self.input_layernorm.weight, attn.q_proj.weight,
-                    attn.k_proj.weight, attn.v_proj.weight,
-                    attn.o_proj.weight, cos2, sin2, cache, block_tables,
-                    span_starts, seq_lens, hd, cfg.rms_norm_eps)
-                x = x + self.mlp(self.post_attention_layernorm(x),
-                                 lora=lora)
+                # one dispatch holds norm, projections and the core; the
+                # core (the live pages' read) is what its time follows
+                with region("attn_core"):
+                    x, cache = mega_decode_layer(
+                        x, self.input_layernorm.weight, attn.q_proj.weight,
+                        attn.k_proj.weight, attn.v_proj.weight,
+                        attn.o_proj.weight, cos2, sin2, cache, block_tables,
+                        span_starts, seq_lens, hd, cfg.rms_norm_eps)
+                h = self.mlp(self.post_attention_layernorm(x), lora=lora)
+                with region("mlp"):
+                    x = x + h
                 return x, cache
             if lora is None:
                 attn_in, nw = self._attn_input(x)
@@ -493,17 +527,23 @@ class LlamaDecoderLayer(Layer):
                                          block_tables=block_tables,
                                          span_starts=span_starts,
                                          norm_weight=nw, lora=lora)
-            x = x + attn
-            x = x + self.mlp(self.post_attention_layernorm(x),
-                             lora=lora)
+            with region("attn_proj"):
+                x = x + attn
+            h = self.mlp(self.post_attention_layernorm(x), lora=lora)
+            with region("mlp"):
+                x = x + h
             return x, cache
-        # named scopes → readable xprof/Perfetto traces (profiler facade)
-        with jax.named_scope("attn"):
-            attn_in, nw = self._attn_input(x)
-            x = x + self.self_attn(attn_in, cos, sin, attn_mask,
-                                   norm_weight=nw)
-        with jax.named_scope("mlp"):
-            x = x + self.mlp(self.post_attention_layernorm(x))
+        # regions (observability/regions.py): each block opens its own;
+        # the residual adds sit in their block's region too, since XLA
+        # fuses them into its last matmul and a fusion carries its root's
+        # path
+        attn_in, nw = self._attn_input(x)
+        attn = self.self_attn(attn_in, cos, sin, attn_mask, norm_weight=nw)
+        with region("attn_proj"):
+            x = x + attn
+        h = self.mlp(self.post_attention_layernorm(x))
+        with region("mlp"):
+            x = x + h
         return x
 
 
@@ -593,10 +633,12 @@ class LlamaModel(Layer):
                     "ignored (left-pad or trim prompts instead)")
             return self._forward_cached(input_ids, caches, seq_lens,
                                         block_tables, span_starts, lora)
-        x = self.embed_tokens(input_ids)
-        cos, sin = F.rope_cos_sin(input_ids.shape[1], cfg.head_dim,
-                                  base=cfg.rope_theta, dtype=x.dtype,
-                                  position_ids=position_ids)
+        with region("embed"):
+            x = self.embed_tokens(input_ids)
+        with region("attn_proj"):
+            cos, sin = F.rope_cos_sin(input_ids.shape[1], cfg.head_dim,
+                                      base=cfg.rope_theta, dtype=x.dtype,
+                                      position_ids=position_ids)
         aux = 0.0
         if cfg.pipeline_stages > 1:
             x = self.layers(x, cos, sin, attn_mask)
@@ -626,21 +668,25 @@ class LlamaModel(Layer):
         adapter ids) — each decoder layer consumes its own pack.
         Returns (hidden, new_caches)."""
         cfg = self.cfg
-        x = self.embed_tokens(input_ids)
+        with region("embed"):
+            x = self.embed_tokens(input_ids)
         b, s = input_ids.shape
         decode = (s == 1 and seq_lens is not None)
-        if span_starts is not None:
-            # per-slot positions: the span's tokens sit at start..start+s
-            cos, sin = F.rope_cos_sin(
-                s, cfg.head_dim, base=cfg.rope_theta, dtype=x.dtype,
-                position_ids=span_starts[:, None] + jnp.arange(s)[None, :])
-        elif decode:
-            cos, sin = F.rope_cos_sin(1, cfg.head_dim, base=cfg.rope_theta,
-                                      dtype=x.dtype,
-                                      position_ids=seq_lens[:, None])
-        else:
-            cos, sin = F.rope_cos_sin(s, cfg.head_dim, base=cfg.rope_theta,
-                                      dtype=x.dtype)
+        with region("attn_proj"):
+            if span_starts is not None:
+                # per-slot positions: the span's tokens sit at
+                # start..start+s
+                cos, sin = F.rope_cos_sin(
+                    s, cfg.head_dim, base=cfg.rope_theta, dtype=x.dtype,
+                    position_ids=span_starts[:, None]
+                    + jnp.arange(s)[None, :])
+            elif decode:
+                cos, sin = F.rope_cos_sin(
+                    1, cfg.head_dim, base=cfg.rope_theta, dtype=x.dtype,
+                    position_ids=seq_lens[:, None])
+            else:
+                cos, sin = F.rope_cos_sin(s, cfg.head_dim,
+                                          base=cfg.rope_theta, dtype=x.dtype)
         # the paged kwargs are only threaded when present: decoder-layer
         # subclasses without paged support (MoE) keep their signature
         kw = {} if block_tables is None else {"block_tables": block_tables}
@@ -658,7 +704,10 @@ class LlamaModel(Layer):
             self.layers, x, caches,
             lambda inner, x, cache: inner(
                 x, cos, sin, cache=cache, seq_lens=lens_arg,
-                lora=None if lit is None else (next(lit), laids), **kw))
+                # threaded only when present, like the paged kwargs (MoE
+                # layers keep their signature)
+                **({} if lit is None else {"lora": (next(lit), laids)}),
+                **kw))
         self.__dict__["_moe_aux"] = 0.0
         return self.norm(x), new_caches
 
@@ -677,11 +726,13 @@ class LlamaForCausalLM(CachedGenerationMixin, Layer):
         self.loss_fn = ParallelCrossEntropy(ignore_index=-100)
 
     def logits(self, hidden):
-        if self.cfg.tie_word_embeddings:
-            w = self.model.embed_tokens.weight  # (vocab, hidden), mp on vocab
-            logits = hidden @ w.T
-            return constrain(logits, ("dp", "sharding"), None, "mp")
-        return self.lm_head(hidden)
+        with region("lm_head_loss"):
+            if self.cfg.tie_word_embeddings:
+                # (vocab, hidden), mp on vocab
+                w = self.model.embed_tokens.weight
+                logits = hidden @ w.T
+                return constrain(logits, ("dp", "sharding"), None, "mp")
+            return self.lm_head(hidden)
 
     def forward(self, input_ids, labels=None, attn_mask=None, position_ids=None):
         hidden = self.model(input_ids, attn_mask, position_ids)
@@ -698,9 +749,10 @@ class LlamaForCausalLM(CachedGenerationMixin, Layer):
                 "[B,S,V] logits path (full logits WILL be materialized)",
                 stacklevel=2)
         logits = self.logits(hidden)
-        loss = self.loss_fn(logits.astype(jnp.float32), labels)
-        valid = (labels != -100)
-        return jnp.sum(loss * valid) / jnp.maximum(jnp.sum(valid), 1)
+        with region("lm_head_loss"):
+            loss = self.loss_fn(logits.astype(jnp.float32), labels)
+            valid = (labels != -100)
+            return jnp.sum(loss * valid) / jnp.maximum(jnp.sum(valid), 1)
 
     def _chunked_loss(self, hidden, labels, chunks):
         """Memory-efficient vocab CE: the [B,S,V] logits tensor (the
@@ -719,14 +771,15 @@ class LlamaForCausalLM(CachedGenerationMixin, Layer):
             valid = (l != -100)
             return jnp.sum(loss * valid), jnp.sum(valid)
 
-        total = jnp.float32(0.0)
-        count = jnp.int32(0)
-        for c in range(chunks):  # unrolled: XLA overlaps chunk pipelines
-            sl = slice(c * s_chunk, (c + 1) * s_chunk)
-            s, n = chunk_sums(hidden[:, sl], labels[:, sl])
-            total += s
-            count += n
-        return total / jnp.maximum(count, 1)
+        with region("lm_head_loss"):
+            total = jnp.float32(0.0)
+            count = jnp.int32(0)
+            for c in range(chunks):  # unrolled: XLA overlaps chunks
+                sl = slice(c * s_chunk, (c + 1) * s_chunk)
+                s, n = chunk_sums(hidden[:, sl], labels[:, sl])
+                total += s
+                count += n
+            return total / jnp.maximum(count, 1)
 
     def _cache_supported(self) -> bool:
         return (self.cfg.pipeline_stages == 1

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..distributed.moe import GATES, MoELayer
 from ..nn.layer import Layer
+from ..observability.regions import region
 from .llama import (LlamaAttention, LlamaConfig, LlamaForCausalLM, LlamaMLP,
                     LlamaModel, LlamaRMSNorm)
 
@@ -63,11 +64,20 @@ class MixtralDecoderLayer(Layer):
             attn, cache = self.self_attn(self.input_layernorm(x), cos, sin,
                                          attn_mask, cache=cache,
                                          seq_lens=seq_lens)
-            x = x + attn
-            x = x + self.block_sparse_moe(self.post_attention_layernorm(x))
+            with region("attn_proj"):
+                x = x + attn
+            with region("mlp"):
+                x = x + self.block_sparse_moe(
+                    self.post_attention_layernorm(x))
             return x, cache
-        x = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        x = x + self.block_sparse_moe(self.post_attention_layernorm(x))
+        # the shared regions (observability/regions.py): attention and the
+        # experts carry theirs by inheritance; the router and the residual
+        # adds are the expert block's
+        attn = self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
+        with region("attn_proj"):
+            x = x + attn
+        with region("mlp"):
+            x = x + self.block_sparse_moe(self.post_attention_layernorm(x))
         # aux read immediately after the call, same trace level (the
         # MoELayer contract), then threaded outward through our output
         return x, self.block_sparse_moe.aux_loss

@@ -38,6 +38,12 @@ RECORDER = [None]
 # (ckpt, Engine.fit epochs, eager collectives, jit AOT export).
 SPAN = [None]
 
+# callable(name, start_ns, end_ns) or None: the chrome-export sink of
+# ``paddle_tpu.profiler`` (set while any ``Profiler`` is between start()
+# and stop(), by that module alone).  Read by ``span.__exit__`` and
+# ``RecordEvent.end`` so both land in a recording Profiler's host events.
+HOST_EVENTS = [None]
+
 # callable(reason=...) -> path|None (flight_recorder.write_postmortem)
 # or None. Read by launch.preempt's signal handler so a preempted run
 # drains the flight-recorder ring without importing anything inside a

@@ -1,5 +1,5 @@
-"""Trace spans: one vocabulary for the always-on JSONL stream AND the
-deep-dive chrome trace.
+"""Trace spans: one vocabulary for the always-on JSONL stream, the
+flight ring and whatever profiler session is live.
 
 ``with span("ckpt.save"):`` feeds, depending on what is enabled:
 
@@ -8,19 +8,28 @@ deep-dive chrome trace.
   span that never returns is visible as a stuck name, not silence);
 - the **registry**: a ``span[<name>].ms`` duration histogram;
 - the **event stream**: one ``span`` JSONL event on exit;
-- the **profiler**: while a ``paddle_tpu.profiler.Profiler`` is active,
-  the span opens a ``RecordEvent`` so the same name lands on the host
-  timeline of the chrome-trace export (and, via ``jax.named_scope``,
-  inside the device trace).
+- the **profiler's host timeline**: a ``jax.profiler.TraceAnnotation``
+  named ``pdtpu.<name>`` (``TRACE_PREFIX``: the one prefix by which a
+  trace reducer tells the program's events from JAX's own).  TraceMe's
+  own check (``is_enabled``) is the switch, so the event lands in the
+  xplane of any live session — ``jax.profiler.start_trace``, the
+  profiler server, ``SLOCapture``,
+  ``paddle_tpu.profiler.Profiler(trace_dir=...)`` — on the clock the
+  device events are on, and costs that one call when none is.  It is a
+  HOST event: nothing is written into the device's lines (regions of a
+  compiled program are ``jax.named_scope``s, ``observability/regions.py``);
+- the **chrome export** of a recording ``paddle_tpu.profiler.Profiler``
+  (``_state.HOST_EVENTS``, the sink ``RecordEvent`` writes to as well).
 
 Pre-instrumented sites: ``jit.TrainStep`` steps (via StepMonitor, as
 ``emit=False`` spans — the ``step`` event already carries the numbers),
-``distributed.Engine.fit`` / ``hapi.Model.fit`` epochs, ``ckpt``
-save/load, eager collectives, and ``jit.save``/``jit.load`` AOT export.
+the serving loop's phases (``serve.*``, docs/OBSERVABILITY.md "Trace
+spans"), ``distributed.Engine.fit`` / ``hapi.Model.fit`` epochs,
+``ckpt`` save/load, eager collectives, and ``jit.save``/``jit.load``
+AOT export.
 
-Disabled cost: one falsy check on the ``_state.SPAN`` hook plus one
-falsy check on the profiler's active list — no imports, no clock reads
-beyond ``perf_counter`` when something is on.
+Disabled cost: ``TraceMe.is_enabled()``, two clock reads and two falsy
+checks on ``_state`` containers — no registry, no sink, no lock.
 """
 
 from __future__ import annotations
@@ -28,34 +37,24 @@ from __future__ import annotations
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import _state
 
-__all__ = ["span", "spans_active"]
+__all__ = ["span", "spans_active", "TRACE_PREFIX"]
 
-# lazily bound to paddle_tpu.profiler's module-level _active_profilers
-# list (a stable object) + its RecordEvent class; the profiler drags jax
-# in, so nothing is imported until a span runs with a profiler plausible
-_PROF = [None, None]            # [_active_profilers, RecordEvent]
-
-
-def _profiler_bridge():
-    lst = _PROF[0]
-    if lst is None:
-        try:
-            from .. import profiler as _p
-            _PROF[0] = lst = _p._active_profilers
-            _PROF[1] = _p.RecordEvent
-        except Exception:
-            _PROF[0] = lst = ()
-    return lst
+TRACE_PREFIX = "pdtpu."
 
 
 def spans_active() -> bool:
-    """True when a span would observe anything (telemetry span hook or
-    an active profiler).  Per-call producers (eager collectives) use
-    this as a fast path so the fully-disabled cost stays two falsy
-    checks, with no span/f-string construction."""
-    return _state.SPAN[0] is not None or bool(_profiler_bridge())
+    """True when a span would observe anything (telemetry span hook, a
+    recording ``profiler.Profiler`` or a live profiler session).
+    Per-call producers (eager collectives) use this as a fast path so
+    the fully-disabled cost stays three falsy checks, with no
+    span/f-string construction."""
+    return (_state.SPAN[0] is not None
+            or _state.HOST_EVENTS[0] is not None
+            or TraceAnnotation.is_enabled())
 
 
 class _SpanHook:
@@ -94,39 +93,44 @@ class _SpanHook:
 class span:
     """Context manager: ``with span("name", **attrs): ...``.
 
-    ``emit=False`` keeps the breadcrumbs and the profiler bridge but
-    suppresses the JSONL event + registry histogram — used where another
-    event already carries the numbers (TrainStep's ``step`` event).
+    ``emit=False`` keeps the breadcrumbs and the profiler's host event
+    but suppresses the JSONL event + registry histogram — used where
+    another event already carries the numbers (TrainStep's ``step``
+    event) or where a span is one of many per step (the serving loop's
+    phases).
     """
 
-    __slots__ = ("name", "attrs", "emit", "_t0", "_rec_event", "_hook")
+    __slots__ = ("name", "attrs", "emit", "_t0", "_trace", "_hook")
 
     def __init__(self, name: str, emit: bool = True, **attrs):
         self.name = name
         self.attrs = attrs
         self.emit = emit
-        self._rec_event = None
+        self._trace = None
         self._hook = None
-        self._t0 = 0.0
+        self._t0 = 0
 
     def __enter__(self):
         self._hook = hook = _state.SPAN[0]
         if hook is not None:
             hook.begin(self.name)
-        if _profiler_bridge():
-            self._rec_event = _PROF[1](self.name)
-            self._rec_event.begin()
-        self._t0 = time.perf_counter()
+        if TraceAnnotation.is_enabled():      # a profiler session is live
+            self._trace = tm = TraceAnnotation(TRACE_PREFIX + self.name)
+            tm.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        rec_event = self._rec_event
-        if rec_event is not None:
-            rec_event.end()
-            self._rec_event = None
+        t1 = time.perf_counter_ns()
+        tm = self._trace
+        if tm is not None:
+            tm.__exit__(None, None, None)
+            self._trace = None
+        host = _state.HOST_EVENTS[0]
+        if host is not None:
+            host(self.name, self._t0, t1)
         hook = self._hook
         if hook is not None:
-            hook.end(self.name, (t1 - self._t0) * 1e3, self.attrs,
+            hook.end(self.name, (t1 - self._t0) * 1e-6, self.attrs,
                      self.emit)
         return False

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ..nn.clip import ClipGradBase, ClipGradByGlobalNorm
 from ..nn.layer import Layer, ParameterList, raw_params
+from ..observability.regions import region
 from . import lr as lr_mod
 from .lr import LRScheduler
 
@@ -116,7 +117,8 @@ class Optimizer:
                 lambda g: g.astype(jnp.float32)
                 if jnp.issubdtype(g.dtype, jnp.floating) else g, grads)
         if self.grad_clip is not None:
-            grads = self.grad_clip(grads)
+            with region("clip"):
+                grads = self.grad_clip(grads)
         step = state["step"]
         lr = _lr_value(self._lr, step)
         masters = state.get("master", {})

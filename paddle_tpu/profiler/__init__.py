@@ -7,10 +7,13 @@ chrome-trace export, summary tables; C++ HostTracer + CUPTI device tracer.
 TPU mapping: device-side timelines come from XLA via ``jax.profiler``
 (xplane → TensorBoard/Perfetto — that's the CUPTI equivalent and needs no
 code here beyond start/stop).  Host-side user scopes are recorded by
-``RecordEvent`` (which *also* opens a ``jax.named_scope``+TraceAnnotation so
-the same name shows up inside the device trace), and exported as a
-chrome-trace JSON with a summary table, preserving the reference's
-reporting surface.
+``RecordEvent`` and by ``observability.span`` alike: each is a host event
+of every recording ``Profiler`` (exported as a chrome-trace JSON with a
+summary table, preserving the reference's reporting surface) and a
+``jax.profiler.TraceAnnotation`` named ``pdtpu.<name>`` on the host
+timeline of whatever profiler session is live, on the device events' own
+clock.  Neither writes into the device's lines: a region of a compiled
+program is a ``jax.named_scope`` at TRACE time (observability/regions.py).
 """
 
 from __future__ import annotations
@@ -24,6 +27,9 @@ from enum import Enum
 from typing import Callable, Iterable, List, Optional, Tuple
 
 import jax
+
+from ..observability import _state as _obs_state
+from ..observability.spans import TRACE_PREFIX
 
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -60,12 +66,21 @@ _lock = threading.Lock()
 
 def active_profilers() -> List["Profiler"]:
     """Profilers between ``start()`` and ``stop()`` (any scheduler state).
-
-    ``observability.span`` keys its chrome-trace bridge off this list —
-    the same names flow to the always-on JSONL stream and the deep-dive
-    trace (docs/OBSERVABILITY.md, "Trace spans")."""
+    While the list is non-empty ``_state.HOST_EVENTS`` holds
+    :func:`_record_host_event`, which is how ``observability.span`` and
+    ``RecordEvent`` reach the chrome export (docs/OBSERVABILITY.md,
+    "Trace spans")."""
     with _lock:
         return list(_active_profilers)
+
+
+def _record_host_event(name: str, start_ns: int, end_ns: int) -> None:
+    """A finished host scope, onto every profiler in a RECORD window."""
+    ev = _HostEvent(name, start_ns, end_ns, threading.get_ident())
+    with _lock:
+        for p in _active_profilers:
+            if p._recording:
+                p._events.append(ev)
 
 
 def is_recording() -> bool:
@@ -76,30 +91,29 @@ def is_recording() -> bool:
 
 class RecordEvent:
     """User scope: ``with RecordEvent("forward"):``.  Recorded on the host
-    timeline of every active profiler, and annotated into the device trace
-    via jax's TraceAnnotation (named_scope)."""
+    timeline of every recording profiler, and as the host event
+    ``pdtpu.forward`` in the xplane of a live ``jax.profiler`` session
+    (the same TraceAnnotation ``observability.span`` opens)."""
 
     def __init__(self, name: str, event_type=None):
         del event_type  # API compat
         self.name = name
-        self._scope = None
+        self._trace = None
         self._t0 = 0
 
     def begin(self):
-        self._scope = jax.named_scope(self.name)
-        self._scope.__enter__()
+        self._trace = jax.profiler.TraceAnnotation(TRACE_PREFIX + self.name)
+        self._trace.__enter__()
         self._t0 = time.perf_counter_ns()
 
     def end(self):
         t1 = time.perf_counter_ns()
-        if self._scope is not None:
-            self._scope.__exit__(None, None, None)
-            self._scope = None
-        ev = _HostEvent(self.name, self._t0, t1, threading.get_ident())
-        with _lock:
-            for p in _active_profilers:
-                if p._recording:
-                    p._events.append(ev)
+        if self._trace is not None:
+            self._trace.__exit__(None, None, None)
+            self._trace = None
+        host = _obs_state.HOST_EVENTS[0]
+        if host is not None:
+            host(self.name, self._t0, t1)
 
     def __enter__(self):
         self.begin()
@@ -185,6 +199,7 @@ class Profiler:
     def start(self):
         with _lock:
             _active_profilers.append(self)
+            _obs_state.HOST_EVENTS[0] = _record_host_event
         self._apply_state(self._schedule(self._step) if self._schedule
                           else ProfilerState.RECORD)
         self._step_t0 = time.perf_counter_ns()
@@ -201,6 +216,8 @@ class Profiler:
         with _lock:
             if self in _active_profilers:
                 _active_profilers.remove(self)
+            if not _active_profilers:
+                _obs_state.HOST_EVENTS[0] = None
 
     def step(self):
         """Mark a train-step boundary; advances the scheduler."""

@@ -60,7 +60,10 @@ Telemetry (all zero-overhead when observability is disabled):
 + ``serve_request`` / ``serve_step`` / ``serve_finish`` /
 ``serve_preempt`` / ``serve_restore`` / ``serve_isolated_failure``
 events and ``serve.step`` / ``serve.step.finish`` flight-recorder spans
-per step (dispatch and sync/post-processing phases).  With request
+per step (dispatch and sync/post-processing phases), tiled by the leaf
+phases ``serve.step.admit`` / ``draft`` / ``plan`` / ``dispatch`` /
+``sync`` / ``emit`` / ``account``, which a live profiler session sees as
+``pdtpu.*`` host events (docs/OBSERVABILITY.md "Trace spans").  With request
 tracing on, every lifecycle transition additionally feeds the
 per-request timeline (``observability/trace.py``: submit → admit →
 prefill chunks → first token → preempt/restore → retire, with exact
@@ -86,6 +89,7 @@ import jax.numpy as jnp
 
 from .. import observability as obs
 from ..observability import _state as _obs_state
+from ..observability.regions import region
 from ..observability.spans import span
 from ..nn.layer import _swapped_params, functional_call, serving_params
 from ..resilience import _state as _rs_state
@@ -517,15 +521,20 @@ class Engine:
                 block_tables=tables, span_starts=starts,
                 lora=None if lora_ab is None else (lora_ab, adapters),
                 training=False)
-            if spec:
-                with _swapped_params(model, params):
-                    lg = model.logits(hidden)          # (B, C, V)
-                return _sample_span(lg, temps, key, seeds, emit), caches
-            # the last REAL span token's hidden state, not the padding's
-            idx = jnp.clip(lens - 1, 0, tokens.shape[1] - 1)[:, None, None]
-            h_last = jnp.take_along_axis(hidden, idx, axis=1)
-            lg = _logits_of(params, h_last)
-            return _sample(lg, temps, key, seeds, emit), caches
+            # the model's own regions end with the final norm; the head
+            # and the sampler are one region (observability/regions.py)
+            with region("lm_head_loss"):
+                if spec:
+                    with _swapped_params(model, params):
+                        lg = model.logits(hidden)          # (B, C, V)
+                    return _sample_span(lg, temps, key, seeds, emit), caches
+                # the last REAL span token's hidden state, not the
+                # padding's
+                idx = jnp.clip(lens - 1, 0,
+                               tokens.shape[1] - 1)[:, None, None]
+                h_last = jnp.take_along_axis(hidden, idx, axis=1)
+                lg = _logits_of(params, h_last)
+                return _sample(lg, temps, key, seeds, emit), caches
 
         def cow_fn(caches, src, dst):
             """Copy-on-write page copies src[i] → dst[i] in every layer's
@@ -1319,21 +1328,28 @@ class Engine:
             # against global fleet state, or nothing) — warm up now
             self.warmup()
         t0 = time.perf_counter()
+        # serve.step stays for the flight ring's breadcrumbs; its leaf
+        # phases tile it, so that on a profiler's trace a gap of the
+        # device has one owner (docs/OBSERVABILITY.md "Trace spans")
         with span("serve.step", emit=False):
-            self._admit_all()
+            with span("serve.step.admit", emit=False):
+                self._admit_all()
             if self.spec is not None:
-                self._propose_drafts()
-            plan = self.scheduler.plan_spans(self.prefill_chunk,
-                                             self.prefill_token_budget)
-            if plan:
-                plan = self._run_cow(plan)
-            live_tokens = sum(n for _, _, n, _ in plan)
+                with span("serve.step.draft", emit=False):
+                    self._propose_drafts()
+            with span("serve.step.plan", emit=False):
+                plan = self.scheduler.plan_spans(self.prefill_chunk,
+                                                 self.prefill_token_budget)
+                if plan:
+                    plan = self._run_cow(plan)
+                live_tokens = sum(n for _, _, n, _ in plan)
+                if plan:
+                    (tokens, tables, starts, lens, temps, seeds, emit,
+                     adapters) = self.scheduler.span_arrays(
+                        plan, self.prefill_chunk,
+                        spec_emit=self.spec is not None)
             nxt = None
             if plan:
-                (tokens, tables, starts, lens, temps, seeds, emit,
-                 adapters) = self.scheduler.span_arrays(
-                    plan, self.prefill_chunk,
-                    spec_emit=self.spec is not None)
                 # device_put of ready numpy arrays only: jnp.asarray of
                 # a Python list/scalar traces a tiny program whose
                 # one-off compile would break the zero-compiles-after-
@@ -1342,13 +1358,14 @@ class Engine:
                 # as a per-step Python scalar (pdtpu-lint R4f).  The
                 # same rule covers adapter ids: per-slot DATA in the
                 # adapters array, never a static argument.
-                nxt, caches = self._step_fn(
-                    self.params, self.kv.caches, jnp.asarray(tokens),
-                    jnp.asarray(tables), jnp.asarray(starts),
-                    jnp.asarray(lens), jnp.asarray(temps), self._key,
-                    jnp.asarray(seeds), jnp.asarray(emit),
-                    self._lora_stacks(), jnp.asarray(adapters))
-                self.kv.caches = caches
+                with span("serve.step.dispatch", emit=False):
+                    nxt, caches = self._step_fn(
+                        self.params, self.kv.caches, jnp.asarray(tokens),
+                        jnp.asarray(tables), jnp.asarray(starts),
+                        jnp.asarray(lens), jnp.asarray(temps), self._key,
+                        jnp.asarray(seeds), jnp.asarray(emit),
+                        self._lora_stacks(), jnp.asarray(adapters))
+                    self.kv.caches = caches
         # busy accounting covers THIS engine's own engagement only
         # (begin and finish timed separately): under a replica set the
         # phases interleave across engines, so begin-to-finish wall
@@ -1371,16 +1388,36 @@ class Engine:
         events: List[TokenEvent] = []
         # own span so a crash in device sync / post-processing still
         # lands inside a serve.step.* breadcrumb pair on the flight
-        # ring (the serve.step span closed with step_begin's dispatch)
+        # ring (the serve.step span closed with step_begin's dispatch);
+        # its three leaf phases tile it
         with span("serve.step.finish", emit=False):
-            self._finish_events(plan, nxt, events)
+            if plan:
+                # np.asarray is the device sync (JAX dispatch is async):
+                # the one phase in which an idle device is not the
+                # host's doing, and the TTFT clock in _finish_events
+                # stops only after the first token has materialized
+                with span("serve.step.sync", emit=False):
+                    nxt = np.asarray(nxt)
+            with span("serve.step.emit", emit=False):
+                self._finish_events(plan, nxt, events)
+            with span("serve.step.account", emit=False):
+                n_tok = len(events)
+                now = time.perf_counter()
+                # this engine's own step time: begin + finish phases,
+                # excluding any sibling-replica slices interleaved
+                # between them
+                dt = begin_s + (now - tf)
+                self.busy_s += now - tf
+                self.tokens_emitted += n_tok
+                self._account_step(plan, events, live_tokens, dt)
+        return events
+
+    # requires-lock: _lock — reads _states (per-adapter token counters)
+    def _account_step(self, plan, events, live_tokens: int,
+                      dt: float) -> None:
+        """The registry / gauge / ``serve_step`` block of a finished
+        step (the ``serve.step.account`` phase)."""
         n_tok = len(events)
-        now = time.perf_counter()
-        # this engine's own step time: begin + finish phases, excluding
-        # any sibling-replica slices interleaved between them
-        dt = begin_s + (now - tf)
-        self.busy_s += now - tf
-        self.tokens_emitted += n_tok
         reg = obs.get_registry()
         if reg is not None and plan:
             reg.counter("serve.tokens").inc(n_tok)
@@ -1444,15 +1481,10 @@ class Engine:
                 # SLO-triggered capture bookkeeping: host-side counters
                 # only, until a breach arms the bounded profiler window
                 cap.on_step()
-        return events
 
     def _finish_events(self, plan, nxt,
                        events: List[TokenEvent]) -> None:
         if plan:
-            # np.asarray is the device sync: JAX dispatch is async,
-            # so the TTFT clock below must stop AFTER the first
-            # token materializes, or it reports queueing overhead
-            nxt = np.asarray(nxt)
             fi = _rs_state.FAULTS[0]
             tr = _obs_state.TRACE[0]
             for i, st, n, is_prefill in plan:
@@ -1623,8 +1655,9 @@ class Engine:
             reg = obs.get_registry()
             if reg is not None:
                 reg.counter("serve.spec.proposed").inc(k)
-                if acc:
-                    reg.counter("serve.spec.accepted").inc(acc)
+                # created with the first proposal, at nought too: a rate
+                # needs both counters
+                reg.counter("serve.spec.accepted").inc(acc)
                 reg.histogram("serve.spec.accept_len").observe(acc)
 
     def step(self) -> List[TokenEvent]:

@@ -42,6 +42,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Set
 
 from .. import observability as obs
 from ..observability import _state as _obs_state
+from ..observability.spans import span
 from .errors import (AdmissionError, BudgetUnsatisfiable, QueueFull,
                      RateLimited, UnknownAdapter)
 from .scheduler import Request, RequestState
@@ -623,7 +624,8 @@ class FrontDoor:
 
     def step(self):
         """One pump + one engine step; returns the engine's events."""
-        self.pump()
+        with span("serve.pump", emit=False):
+            self.pump()
         return self.engine.step()
 
     def run(self) -> Dict[str, List[int]]:

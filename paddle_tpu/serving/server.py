@@ -56,6 +56,7 @@ from typing import Callable, Optional
 
 from .. import observability as obs
 from ..observability.sinks import registry_to_prometheus
+from ..observability.spans import span
 from ..observability.trace import trace_context
 from ..launch.preempt import PreemptionGuard
 from .engine import Engine
@@ -300,8 +301,9 @@ class _Handler(BaseHTTPRequestHandler):
 
         def chunk(data: str):
             payload = f"data: {data}\n\n".encode()
-            self.wfile.write(f"{len(payload):x}\r\n".encode()
-                             + payload + b"\r\n")
+            with span("serve.stream.write", emit=False):
+                self.wfile.write(f"{len(payload):x}\r\n".encode()
+                                 + payload + b"\r\n")
 
         try:
             while True:
@@ -390,29 +392,40 @@ class ServingServer:
         return self.address
 
     def _loop(self):
+        # one iteration is tiled by leaf phases (docs/OBSERVABILITY.md
+        # "Trace spans"): serve.loop.wait, then under door.step()
+        # serve.pump and the engine's serve.step.* phases, then
+        # serve.stream.route.  On a profiler's trace every idle gap of
+        # the device then has an owner on this thread.
         while not self._stop.is_set():
             evs = ()
+            # the wait for the lock (a handler submitting) is the wait
+            # phase's, like the sleep below
+            wait = span("serve.loop.wait", emit=False).__enter__()
             with self._lock:
+                wait.__exit__()
                 if self.door.has_work():
                     evs = self.door.step()
-            for ev in evs:
-                # under the lock: handler threads insert routes
-                # concurrently (lint's lock-discipline rule flagged the
-                # bare read here — a handler registering its queue
-                # between this get and the pop could be missed)
-                with self._lock:
-                    q = self._routes.get(ev.request_id)
-                    if q is not None and ev.finished:
-                        self._routes.pop(ev.request_id, None)
-                if q is not None:
-                    q.put(ev)
-            if self._draining.is_set():
-                with self._lock:
-                    idle = not self.door.has_work()
-                if idle:
-                    self._drained.set()
+            with span("serve.stream.route", emit=False):
+                for ev in evs:
+                    # under the lock: handler threads insert routes
+                    # concurrently (lint's lock-discipline rule flagged
+                    # the bare read here — a handler registering its
+                    # queue between this get and the pop could be missed)
+                    with self._lock:
+                        q = self._routes.get(ev.request_id)
+                        if q is not None and ev.finished:
+                            self._routes.pop(ev.request_id, None)
+                    if q is not None:
+                        q.put(ev)
+                if self._draining.is_set():
+                    with self._lock:
+                        idle = not self.door.has_work()
+                    if idle:
+                        self._drained.set()
             if not evs:
-                time.sleep(self.poll_s)
+                with span("serve.loop.wait", emit=False):
+                    time.sleep(self.poll_s)
 
     def begin_drain(self, reason: str = "requested") -> None:
         """Stop accepting new requests (503 + Retry-After); in-flight

@@ -342,7 +342,12 @@ class TestClusterServing:
                "JAX_COMPILATION_CACHE_DIR": cache,
                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
                "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
-               "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+               "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+               # every persistent-cache hit logs two ~2 KB
+               # cpu_aot_loader error lines on some hosts; the workers'
+               # stderr is a pipe nobody reads until they exit, and a
+               # warm cache filled it (64 KiB) before a worker came up
+               "TF_CPP_MIN_LOG_LEVEL": "3"}
         env.pop("PDTPU_FAULTS", None)
         return env
 
