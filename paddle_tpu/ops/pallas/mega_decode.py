@@ -13,8 +13,7 @@ VMEM-resident between stages (FlashFuser / CUTLASS FA2 tier —
 PAPERS.md), and the only HBM traffic is the x tile in, the pool pages
 in, and the (o, span-k, span-v) tiles out.
 
-Structure (grid = (batch, pages); page axis innermost/sequential, as in
-ragged_attention.py):
+Structure (grid = (batch, pages); page axis innermost/sequential):
 
 - ``ip == 0``: rms-norm the slot's span tile, run the q/k/v projections
   against VMEM-resident weights, apply the selector-matmul rotate-half

@@ -11,21 +11,27 @@ the cached prefix plus the causal part of its own span.  This is what
 lets chunked prefill and decode share one fixed-shape dispatch instead of
 one bucket-prefill program per length plus a separate decode program.
 
-TPU-native design (shared with decode_attention.py):
-- block tables + span starts/lens are SCALAR-PREFETCH operands, so each
-  grid step's KV page is DMA'd straight from its pool slot via the
-  BlockSpec index_map;
-- grid = (batch, pages); the page axis is innermost/sequential, so the
-  online-softmax running (m, l, acc) lives in VMEM scratch across pages;
-  pages at or past ``starts+lens`` are skipped (``pl.when``), so a
-  mostly-decode batch does decode-sized work;
-- one page block carries ALL kv heads; the q rows of one kv head form a
+TPU-native design:
+- block tables + span starts/lens are SCALAR-PREFETCH operands; the
+  pools stay in HBM (``memory_space=ANY``) and the kernel fetches KV
+  through the block table with its own DMAs;
+- grid = (batch,): ONE grid step per slot.  Inside it a loop walks the
+  slot's live context in KV blocks of ``_pages_per_block`` pages (128
+  positions where a page is 16 or 64), ``ceil((starts+lens) / block)``
+  times: a dead slot runs no iteration and finalises to zeros, and a
+  table entry past the live pages costs nothing and is never
+  dereferenced.  Block ``i + 1`` loads into the second buffer while
+  block ``i`` computes; the online-softmax running (m, l, acc) lives in
+  VMEM scratch across blocks;
+- one KV block carries ALL kv heads; the q rows of one kv head form a
   (C*G, D) tile — span rows and GQA groups share the MXU pass, KV is
-  never repeated;
-- rows ``j >= lens[b]`` are DEAD: their scores mask to -inf everywhere,
-  and because page 0 is always visited first for a live slot their
-  running max is finite, so they accumulate bounded garbage the caller
-  discards (the engine reads logits only at row ``lens[b]-1``).
+  never repeated.  The MXU takes q, k, v and p in the pool's own dtype
+  and accumulates in float32 (float32 pools stay float32 end to end);
+- rows ``j >= lens[b]`` are DEAD: their scores mask to -inf past
+  position ``starts[b] + j``, and because block 0 is always visited
+  first for a live slot their running max is finite, so they accumulate
+  bounded garbage the caller discards (the engine reads logits only at
+  row ``lens[b]-1``).
 
 Layouts: q (B, C, H, D); pools (NB, page, H_kv, D); tables (B, MB) int32;
 starts/lens (B,) int32.
@@ -42,61 +48,97 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# VMEM for the double-buffered K and V blocks (2 pools x 2 buffers), and
+# the key positions one MXU pass covers: a block is as many pages as
+# fill the budget, at most BLOCK_TOKENS of them
+KV_VMEM_BUDGET = 4 * 2 ** 20
+BLOCK_TOKENS = 128
+
+
+def _pages_per_block(page, h_kv, d, dtype, mb):
+    """Pages of K (and of V) fetched and attended per loop iteration."""
+    page_bytes = page * h_kv * d * jnp.dtype(dtype).itemsize
+    by_vmem = KV_VMEM_BUDGET // (4 * page_bytes)
+    return max(1, min(by_vmem, BLOCK_TOKENS // page, mb))
 
 
 def _kernel(tables_ref, starts_ref, lens_ref,   # scalar prefetch
-            q_ref, k_ref, v_ref,                # blocks
+            q_ref, k_hbm, v_hbm,                # q block; pools left in HBM
             o_ref,                              # out block
-            m_scr, l_scr, acc_scr,              # VMEM scratch
-            *, page, scale, pages_per_seq, h_kv, g, c):
+            k_buf, v_buf, sems,                 # double-buffered KV blocks
+            m_scr, l_scr, acc_scr,              # online-softmax state
+            *, page, ppb, scale, g):
     b = pl.program_id(0)
-    ip = pl.program_id(1)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    rows = acc_scr.shape[1]
+    blk = ppb * page
     start = starts_ref[b]
     total = start + lens_ref[b]          # tokens in the pool for this slot
+    n_blk = pl.cdiv(total, blk)          # 0 for a dead slot
+    last_page = jnp.maximum(total - 1, 0) // page
 
-    @pl.when(ip * page < total)
-    def _compute():
-        rows = c * g
-        # pool position of each key column in this page
-        pos = ip * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
-        # span index j of each query row (row = j * g + gq)
-        j_row = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) // g
+    def copies(i, buf):
+        """The DMAs of KV block ``i`` into buffer ``buf``, page by page
+        through the block table.  Pages past the slot's last live one
+        re-read that page: a table's padding is never dereferenced, and
+        what lands there is finite and masked."""
+        out = []
+        for p in range(ppb):
+            idx = tables_ref[b, jnp.minimum(i * ppb + p, last_page)]
+            dst = pl.ds(p * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[idx], k_buf.at[buf, dst], sems.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[idx], v_buf.at[buf, dst], sems.at[1, buf]))
+        return out
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_blk > 0)
+    def _first():
+        for cp in copies(0, 0):
+            cp.start()
+
+    precision = (jax.lax.Precision.HIGHEST if k_buf.dtype == jnp.float32
+                 else None)
+    # span index j of each query row (row = j * g + gq)
+    j_row = jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 0) // g
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 1)
+
+    def block(i, carry):
+        buf = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blk)
+        def _next():
+            for cp in copies(i + 1, 1 - buf):
+                cp.start()
+
+        for cp in copies(i, buf):
+            cp.wait()
         # causal vs the pool: row j sees positions [0, start + j]
-        live = pos <= start + j_row
-        for hk in range(h_kv):               # static unroll over kv heads
-            rr = slice(hk * rows, (hk + 1) * rows)
-            q = q_ref[0, hk].astype(jnp.float32)          # (C*G, D)
-            k = k_ref[0, :, hk].astype(jnp.float32)       # (page, D)
-            v = v_ref[0, :, hk].astype(jnp.float32)       # (page, D)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32,
-                                    precision=jax.lax.Precision.HIGHEST)
-            s = jnp.where(live, s * scale, NEG_INF)       # (C*G, page)
+        live = i * blk + col <= start + j_row
+        q = q_ref[0].astype(k_buf.dtype)                  # (H_kv, C*G, D)
+        k = jnp.swapaxes(k_buf[buf], 0, 1)                # (H_kv, blk, D)
+        v = jnp.swapaxes(v_buf[buf], 0, 1)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
+        s = jnp.where(live, s * scale, NEG_INF)           # (H_kv, C*G, blk)
+        m_prev = m_scr[...]                               # (H_kv, C*G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32, precision=precision)
+        m_scr[...] = m_new
+        return carry
 
-            m_prev = m_scr[rr]                            # (C*G, 1)
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_scr[rr] = l_scr[rr] * alpha + jnp.sum(p, axis=1,
-                                                    keepdims=True)
-            acc_scr[rr] = acc_scr[rr] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST)
-            m_scr[rr] = m_new
-
-    @pl.when(ip == pages_per_seq - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blk, block, None)
+    denom = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
@@ -106,52 +148,43 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
     ``interpret=True`` runs the kernel in the Pallas interpreter (CPU CI).
     """
     b, c, h, d = q.shape
-    nb, page, h_kv, _ = k_pool.shape
+    _, page, h_kv, _ = k_pool.shape
     mb = block_tables.shape[1]
     g = h // h_kv
+    rows = c * g
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    ppb = _pages_per_block(page, h_kv, d, k_pool.dtype, mb)
     # (B, H_kv, C*G, D): span rows grouped under their kv head, row = j*g+gq
     qg = q.reshape(b, c, h_kv, g, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, h_kv, c * g, d)
+        .reshape(b, h_kv, rows, d)
 
-    grid = (b, mb)
-
-    def q_map(ib, ip, tables, starts_, lens_):
+    def slot_map(ib, tables, starts_, lens_):
         return (ib, 0, 0, 0)
 
-    def kv_map(ib, ip, tables, starts_, lens_):
-        # Clamp dead pages (past the span's end) to the last live page:
-        # Pallas elides the re-fetch of an already-resident block, so
-        # short contexts skip the dead DMA traffic — and padding entries
-        # of the block table are never dereferenced as pool indices.
-        last_live = jnp.maximum(starts_[ib] + lens_[ib] - 1, 0) // page
-        idx = tables[ib, jnp.minimum(ip, last_live)]
-        return (jnp.clip(idx, 0, nb - 1), 0, 0, 0)
-
-    def o_map(ib, ip, tables, starts_, lens_):
-        return (ib, 0, 0)
-
-    kernel = functools.partial(_kernel, page=page, scale=float(scale),
-                               pages_per_seq=mb, h_kv=h_kv, g=g, c=c)
+    kernel = functools.partial(_kernel, page=page, ppb=ppb,
+                               scale=float(scale), g=g)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, h_kv, c * g, d), q_map),
-                pl.BlockSpec((1, page, h_kv, d), kv_map),
-                pl.BlockSpec((1, page, h_kv, d), kv_map),
+                pl.BlockSpec((1, h_kv, rows, d), slot_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, h_kv * c * g, d), o_map),
+            out_specs=pl.BlockSpec((1, h_kv, rows, d), slot_map),
             scratch_shapes=[
-                pltpu.VMEM((h_kv * c * g, 1), jnp.float32),
-                pltpu.VMEM((h_kv * c * g, 1), jnp.float32),
-                pltpu.VMEM((h_kv * c * g, d), jnp.float32),
+                pltpu.VMEM((2, ppb * page, h_kv, d), k_pool.dtype),
+                pltpu.VMEM((2, ppb * page, h_kv, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((h_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((h_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((h_kv, rows, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv * c * g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, rows, d), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
     )(block_tables, starts, lens, qg, k_pool, v_pool)
@@ -168,5 +201,10 @@ def supported(q, k_pool, v_pool, block_tables, starts, lens) -> bool:
     # same page-size gates as the decode kernel (v5e sweep 2026-07-30:
     # page=32 triggers a Mosaic layout pathology and is excluded)
     page_ok = page == 16 or page % 64 == 0
-    return (h % h_kv == 0 and d % 128 == 0 and page_ok
+    # a page is DMA'd whole: Mosaic slices one out of a 16-bit pool only
+    # where the kv heads fill the (H_kv, D) face's HBM tiles (deviceless
+    # v5e compiles, PR 26: 1, 3, 6 and 12 kv heads are refused)
+    heads_ok = (jnp.dtype(k_pool.dtype).itemsize >= 4 or h_kv % 8 == 0
+                or h_kv in (2, 4))
+    return (h % h_kv == 0 and d % 128 == 0 and page_ok and heads_ok
             and jax.default_backend() == "tpu")

@@ -245,11 +245,12 @@ def _case_flash_attention(shape):
     return fwd_bwd, (q, q, q), ["flash_attention_fwd", "flash_attention_bwd"]
 
 
-def _case_ragged(page):
+def _case_ragged(page, kv_heads=NH, batch=8, ctx=2048):
     def case(shape):
         from paddle_tpu.ops.pallas import ragged_attention as m
-        pool, tables, lens = _pools(shape, page)
-        args = (shape((8, 16, NH, HD)), pool, pool, tables, lens, lens)
+        pool, tables, lens = _pools(shape, page, heads=kv_heads,
+                                    batch=batch, ctx=ctx)
+        args = (shape((batch, 16, NH, HD)), pool, pool, tables, lens, lens)
         assert m.supported(*args)
         return m.ragged_paged_attention, args, ["ragged_paged_attention"]
     return case
@@ -324,6 +325,10 @@ KERNEL_CASES = {
     "flash_attention-7b": _case_flash_attention,
     "ragged_paged_attention-7b-page16": _case_ragged(16),
     "ragged_paged_attention-7b-page64": _case_ragged(64),
+    # the serving cell's own shape: Mistral-7B's GQA 32/8, 32 slots of
+    # 256 table entries, pages of 16
+    "ragged_paged_attention-mistral-gqa8": _case_ragged(
+        16, kv_heads=8, batch=32, ctx=4096),
     "paged_attention-7b": _case_paged_attention,
     "fused_adamw-7b": _case_fused_adamw,
     "int8_matmul-7b": _case_int8_matmul,
@@ -348,7 +353,8 @@ def test_gates_decline_what_mosaic_refuses(as_tpu):
     """At Llama-2-7B widths the weight-resident kernels do not fit VMEM
     and the exact-gelu kernel has no erf to lower to: their gates say
     no, and the model keeps the XLA composition."""
-    from paddle_tpu.ops.pallas import fused_mlp, fused_norm_qkv, mega_decode
+    from paddle_tpu.ops.pallas import (fused_mlp, fused_norm_qkv, mega_decode,
+                                       ragged_attention)
 
     def z(*dims):
         return jax.ShapeDtypeStruct(dims, BF16)
@@ -357,6 +363,14 @@ def test_gates_decline_what_mosaic_refuses(as_tpu):
     pool = z(64, 16, NH, HD)
     assert not mega_decode.supported(z(8, 16, H), w, w, w, HD,
                                      cache=(pool, pool))
+    # a bf16 page whose kv heads do not fill its HBM tiles (MQA, 6 or 12
+    # kv heads) cannot be DMA'd whole; 2, 4 and multiples of 8 can
+    span, i32 = z(8, 16, 24, HD), jax.ShapeDtypeStruct((8,), I32)
+    for kv_heads, ok in ((1, False), (6, False), (12, False), (2, True),
+                         (4, True), (8, True), (24, True)):
+        kv = z(64, 16, kv_heads, HD)
+        assert ragged_attention.supported(
+            span, kv, kv, jax.ShapeDtypeStruct((8, 8), I32), i32, i32) == ok
     # llama-1b widths: compiles to 50 MiB of scoped VMEM, past the limit
     x1, w1 = z(2048, 2048), z(2048, 2048)
     assert not fused_norm_qkv.supported(x1, w1, w1, HD)

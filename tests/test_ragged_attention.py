@@ -52,6 +52,15 @@ def _case(B=4, C=8, H=4, HKV=2, D=128, BS=16, NB=32, MB=4,
     return q, kp, vp, tables, starts, lens
 
 
+def _run_kernel(q, kp, vp, tables, starts, lens, dtype="float32"):
+    out = RA.ragged_paged_attention(
+        jnp.asarray(q, dtype), jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+        jnp.asarray(tables), jnp.asarray(starts), jnp.asarray(lens),
+        interpret=True)
+    assert out.dtype == jnp.dtype(dtype)
+    return np.asarray(out.astype(jnp.float32))
+
+
 def _assert_live_rows_close(got, want, lens, rtol=2e-4, atol=2e-5):
     for b in range(got.shape[0]):
         if lens[b]:
@@ -67,10 +76,7 @@ class TestRaggedKernelVsOracle:
             starts=[10, 33, 0, 0], lens=[6, 1, 0, 8])
         tables = tables.copy()
         tables[2, :] = -1                 # dead slot: padding table
-        got = np.asarray(RA.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(tables), jnp.asarray(starts), jnp.asarray(lens),
-            interpret=True))
+        got = _run_kernel(q, kp, vp, tables, starts, lens)
         _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
                                              lens), lens)
         # dead slot: no page is ever visited → finalized to zeros
@@ -84,10 +90,7 @@ class TestRaggedKernelVsOracle:
     def test_gqa_and_span_shapes(self, h, hkv, starts, lens):
         q, kp, vp, tables, starts, lens = _case(H=h, HKV=hkv,
                                                 starts=starts, lens=lens)
-        got = np.asarray(RA.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(tables), jnp.asarray(starts), jnp.asarray(lens),
-            interpret=True))
+        got = _run_kernel(q, kp, vp, tables, starts, lens)
         _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
                                              lens), lens)
 
@@ -96,12 +99,57 @@ class TestRaggedKernelVsOracle:
         next page) read and mask the right positions."""
         q, kp, vp, tables, starts, lens = _case(
             C=8, BS=16, starts=[14, 15, 31, 62], lens=[8, 2, 8, 2])
-        got = np.asarray(RA.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(tables), jnp.asarray(starts), jnp.asarray(lens),
-            interpret=True))
+        got = _run_kernel(q, kp, vp, tables, starts, lens)
         _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
                                              lens), lens)
+
+    # What the block schedule can get wrong.  A KV block is
+    # ``_pages_per_block`` pages = 128 positions at these shapes (8 pages
+    # of 16, 2 of 64), so MB = 16 pages of 16 is two blocks.
+    SCHEDULE = {
+        "ends-inside-second-block": dict(starts=[150, 0], lens=[8, 7]),
+        "ends-on-block-boundary": dict(starts=[120, 248], lens=[8, 8]),
+        "one-page-and-one-token": dict(starts=[8, 0], lens=[8, 1]),
+        "table-full-to-mb": dict(starts=[248, 255], lens=[8, 1]),
+        "span-crosses-block-boundary": dict(starts=[124, 127], lens=[8, 2]),
+        "dead-between-live": dict(B=4, starts=[130, 0, 0, 17],
+                                  lens=[3, 0, 0, 8]),
+        "mha-g1": dict(H=4, HKV=4, starts=[125, 40], lens=[8, 8]),
+        "gqa-g4": dict(H=8, HKV=2, starts=[125, 40], lens=[8, 8]),
+        "page64": dict(BS=64, MB=4, NB=16, starts=[125, 200], lens=[8, 8]),
+        "page64-one-page": dict(BS=64, MB=4, NB=16, starts=[0, 63],
+                                lens=[5, 1]),
+        "c1-decode": dict(C=1, starts=[127, 128], lens=[1, 1]),
+        "c1-dead": dict(C=1, B=3, starts=[200, 0, 15], lens=[1, 0, 1]),
+    }
+
+    @pytest.mark.parametrize("dtype,rtol,atol", [
+        ("float32", 2e-4, 2e-5), ("bfloat16", 3e-2, 3e-2)])
+    @pytest.mark.parametrize("name", sorted(SCHEDULE))
+    def test_block_schedule(self, name, dtype, rtol, atol):
+        """Each case against the oracle, with every table entry past a
+        slot's live pages pointing at a page of NaNs (a dead slot's at
+        -1): a padding entry that is fetched poisons the output, and a
+        dead slot comes out as zeros."""
+        kw = dict(B=2, MB=16, NB=40)
+        kw.update(self.SCHEDULE[name])
+        q, kp, vp, tables, starts, lens = _case(**kw)
+        if dtype == "bfloat16":       # the oracle sees what the kernel sees
+            q, kp, vp = (np.array(jnp.asarray(x, dtype).astype("float32"))
+                         for x in (q, kp, vp))
+        page, nb = kp.shape[1], kp.shape[0]
+        assert RA._pages_per_block(page, kp.shape[2], 128, dtype,
+                                   tables.shape[1]) * page == 128
+        tables = np.minimum(tables, nb - 2)
+        kp[nb - 1] = vp[nb - 1] = np.nan
+        for b in range(len(lens)):
+            live_pages = -(-(starts[b] + lens[b]) // page)
+            tables[b, live_pages:] = nb - 1 if lens[b] else -1
+        got = _run_kernel(q, kp, vp, tables, starts, lens, dtype)
+        assert np.isfinite(got[lens > 0]).all()
+        _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
+                                             lens), lens, rtol, atol)
+        assert np.abs(got[lens == 0]).sum() == 0
 
     def test_supported_gating(self):
         import jax
