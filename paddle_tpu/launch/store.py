@@ -62,6 +62,8 @@ def _unpack(sock: socket.socket):
 class _Handler(socketserver.BaseRequestHandler):
     def handle(self):
         srv: "_StoreServer" = self.server  # type: ignore[assignment]
+        with srv._cv:
+            srv._live.add(self.request)
         try:
             while True:
                 fields = _unpack(self.request)
@@ -70,16 +72,35 @@ class _Handler(socketserver.BaseRequestHandler):
                 self.request.sendall(_pack(*resp))
         except (ConnectionError, OSError):
             return
+        finally:
+            with srv._cv:
+                srv._live.discard(self.request)
 
 
 class _StoreServer(socketserver.ThreadingTCPServer):
     allow_reuse_address = True
     daemon_threads = True
+    # the native server's backlog; socketserver's 5 overflows when a handful
+    # of clients connect at once, and each dropped SYN costs its client 1 s
+    request_queue_size = 128
 
     def __init__(self, addr):
         super().__init__(addr, _Handler)
         self._kv: Dict[str, bytes] = {}
         self._cv = threading.Condition()
+        self._live: set = set()     # accepted connections, under _cv
+
+    def stop(self):
+        """Stop accepting, then hang up on every live connection — what the
+        native server's Stop() does: a closed store answers nobody."""
+        self.shutdown()
+        self.server_close()
+        with self._cv:
+            for conn in self._live:
+                try:
+                    conn.shutdown(socket.SHUT_RDWR)
+                except OSError:
+                    pass
 
     def dispatch(self, op: str, args):
         with self._cv:
@@ -310,8 +331,7 @@ class TCPStore:
             self._sock.close()
         finally:
             if self._server is not None:
-                self._server.shutdown()
-                self._server.server_close()
+                self._server.stop()
                 self._server = None
             if self._native_server is not None:
                 self._native_server.close()

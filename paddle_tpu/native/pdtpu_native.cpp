@@ -24,15 +24,19 @@
 #include <sys/socket.h>
 #include <unistd.h>
 
+#include <atomic>
+#include <cerrno>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
 #include <deque>
+#include <list>
 #include <map>
 #include <mutex>
-#include <set>
 #include <stdexcept>
 #include <string>
+#include <system_error>
 #include <thread>
 #include <vector>
 
@@ -46,6 +50,7 @@ bool read_exact(int fd, void* buf, size_t n) {
   char* p = static_cast<char*>(buf);
   while (n > 0) {
     ssize_t r = ::recv(fd, p, n, 0);
+    if (r < 0 && errno == EINTR) continue;
     if (r <= 0) return false;
     p += r;
     n -= static_cast<size_t>(r);
@@ -57,6 +62,7 @@ bool write_all(int fd, const void* buf, size_t n) {
   const char* p = static_cast<const char*>(buf);
   while (n > 0) {
     ssize_t r = ::send(fd, p, n, MSG_NOSIGNAL);
+    if (r < 0 && errno == EINTR) continue;
     if (r <= 0) return false;
     p += r;
     n -= static_cast<size_t>(r);
@@ -167,48 +173,74 @@ class StoreServer {
       ::close(listen_fd_);
       listen_fd_ = -1;
     }
-    // Unblock workers parked in recv() on live client connections BEFORE
-    // joining, or Stop would hang until every remote peer disconnects.
+    // No accept thread any more, so conns_ is final.  Unblock every worker
+    // parked in recv()/send() on a live connection, then join OUTSIDE the
+    // mutex: a worker's last act takes conns_mu_.  A worker closes its fd
+    // under that mutex, so the fds shut down here are exactly the open ones.
+    std::list<Conn> conns;
     {
-      std::lock_guard<std::mutex> lk(workers_mu_);
-      for (int fd : live_fds_) ::shutdown(fd, SHUT_RDWR);
+      std::lock_guard<std::mutex> lk(conns_mu_);
+      for (Conn& c : conns_)
+        if (c.fd >= 0) ::shutdown(c.fd, SHUT_RDWR);
+      conns.swap(conns_);
     }
-    std::vector<std::thread> workers;
-    {
-      std::lock_guard<std::mutex> lk(workers_mu_);
-      workers.swap(workers_);
-    }
-    for (auto& t : workers)
-      if (t.joinable()) t.join();
+    for (Conn& c : conns) c.th.join();
   }
 
   ~StoreServer() { Stop(); }
 
  private:
+  // One accepted connection and the thread that serves it.  `fd` and `done`
+  // belong to conns_mu_; std::list keeps the address stable for the worker.
+  struct Conn {
+    int fd = -1;
+    bool done = false;
+    std::thread th;
+  };
+
   void AcceptLoop() {
     while (true) {
       int fd = ::accept(listen_fd_, nullptr, nullptr);
-      if (fd < 0) return;  // closed
+      if (fd < 0) {
+        int err = errno;
+        if (stopping_ || err == EBADF || err == EINVAL || err == ENOTSOCK)
+          return;  // Stop() shut the listener down, or it is gone
+        // EINTR, ECONNABORTED (peer reset before accept), EPROTO...: the
+        // listener still completes handshakes, so it must still be served.
+        // Out of fds/memory the queued connection stays queued: don't spin.
+        if (err == EMFILE || err == ENFILE || err == ENOBUFS || err == ENOMEM)
+          std::this_thread::sleep_for(std::chrono::milliseconds(10));
+        continue;
+      }
       int one = 1;
       ::setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
-      std::lock_guard<std::mutex> lk(workers_mu_);
       // reap finished workers so a long-lived server doesn't accumulate
-      // one joinable thread (and its retained stack) per past connection
-      for (auto it = workers_.begin(); it != workers_.end();) {
-        if (done_ids_.count(it->get_id())) {
-          it->join();
-          done_ids_.erase(it->get_id());
-          it = workers_.erase(it);
-        } else {
-          ++it;
+      // one joinable thread (and its retained stack) per past connection;
+      // `done` is the worker's own flag (a thread id is reused as soon as
+      // its thread is joined and says nothing about a later worker)
+      std::list<Conn> finished;
+      {
+        std::lock_guard<std::mutex> lk(conns_mu_);
+        for (auto it = conns_.begin(); it != conns_.end();) {
+          auto cur = it++;
+          if (cur->done) finished.splice(finished.end(), conns_, cur);
+        }
+        conns_.emplace_back();
+        Conn* c = &conns_.back();
+        c->fd = fd;
+        try {
+          c->th = std::thread([this, c] { Serve(c); });
+        } catch (const std::system_error&) {  // no thread to be had
+          ::close(fd);
+          conns_.pop_back();
         }
       }
-      live_fds_.insert(fd);
-      workers_.emplace_back([this, fd] { Serve(fd); });
+      for (Conn& c : finished) c.th.join();  // past `done`: returns at once
     }
   }
 
-  void Serve(int fd) {
+  void Serve(Conn* c) {
+    const int fd = c->fd;
     std::vector<std::string> req;
     while (read_msg(fd, &req)) {
       if (req.empty()) break;
@@ -223,10 +255,12 @@ class StoreServer {
       }
       if (!write_msg(fd, resp)) break;
     }
+    // close under the mutex: once the number is free accept() may hand it
+    // to a new connection, and Stop() must never shut that one down for this
+    std::lock_guard<std::mutex> lk(conns_mu_);
     ::close(fd);
-    std::lock_guard<std::mutex> lk(workers_mu_);
-    live_fds_.erase(fd);
-    done_ids_.insert(std::this_thread::get_id());
+    c->fd = -1;
+    c->done = true;
   }
 
   std::vector<std::string> Dispatch(const std::vector<std::string>& req) {
@@ -296,12 +330,10 @@ class StoreServer {
 
   int listen_fd_ = -1;
   int bound_port_ = -1;
-  bool stopping_ = false;
+  std::atomic<bool> stopping_{false};
   std::thread accept_thread_;
-  std::vector<std::thread> workers_;
-  std::set<int> live_fds_;
-  std::set<std::thread::id> done_ids_;
-  std::mutex workers_mu_;
+  std::list<Conn> conns_;
+  std::mutex conns_mu_;
   std::map<std::string, std::string> kv_;
   std::mutex mu_;
   std::condition_variable cv_;

@@ -15,7 +15,7 @@ def _run(script, *argv):
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", script), *argv],
-        env=env, capture_output=True, text=True, timeout=600)
+        env=env, capture_output=True, text=True, timeout=180)
     assert p.returncode == 0, p.stdout + p.stderr
     assert "done" in p.stdout
     return p.stdout

@@ -504,15 +504,6 @@ class TestBenchPlumbing:
         if tools not in sys.path:
             sys.path.insert(0, tools)
 
-    def test_op_benchmark_rows_present(self):
-        import importlib
-        self._tools()
-        ob = importlib.import_module("op_benchmark")
-        rows = ob._fused_ops()
-        for op in ob.FUSED_PAIRS:
-            assert f"fused_{op}" in rows
-            assert f"unfused_{op}" in rows
-
     def test_telemetry_report_folds_fused(self):
         import importlib
         self._tools()

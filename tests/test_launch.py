@@ -25,6 +25,16 @@ from paddle_tpu.launch.store import free_port
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(params=[True, False], ids=["native", "python"])
+def native(request):
+    """Which store server a test hosts: the C++ one or socketserver's."""
+    if request.param:
+        from paddle_tpu import runtime_native
+        if not runtime_native.available():
+            pytest.skip("libpdtpu_native.so is not built")
+    return request.param
+
+
 class TestTCPStore:
     def test_set_get_add_delete(self):
         s = TCPStore(f"127.0.0.1:{free_port()}", is_master=True)
@@ -128,6 +138,101 @@ class TestTCPStore:
             client.close()
             master.close()
 
+    def test_reconnect_stress_loses_no_reply(self, native):
+        """The scenario above as a stress: six clients of one master,
+        each killing its socket before every op, so every op ends one
+        server worker and starts another while five neighbours do the
+        same.  Each client's socket timeout is 2 s, so a request the
+        server never answers is a failure naming the op within seconds
+        (four attempts), not a minute's wait per attempt."""
+        from paddle_tpu.resilience.retry import RetryPolicy
+        master = TCPStore(f"127.0.0.1:{free_port()}", is_master=True,
+                          native=native)
+        lost = []
+
+        def churn(i):
+            c = TCPStore(master.endpoint, timeout=2.0,
+                         retry=RetryPolicy(max_attempts=4, backoff_s=0.001))
+            ops = {"add": lambda: c.add(f"ctr{i}", 1),
+                   "compare_set": lambda: c.compare_set(f"c{i}", b"", b"1"),
+                   "keys": lambda: c.keys(f"c{i}"),
+                   "delete": lambda: c.delete(f"c{i}")}
+            try:
+                for k in range(50):
+                    for name, op in ops.items():
+                        c._sock.close()     # the restart: next send dies
+                        op()
+                assert c.add(f"ctr{i}", 0) >= 50
+            except Exception as e:  # noqa: BLE001
+                lost.append(f"client {i}, round {k}, {name}: {e!r}")
+            finally:
+                c._sock.close()
+
+        ts = [threading.Thread(target=churn, args=(i,)) for i in range(6)]
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)     # more interleavings of the clients
+        try:
+            [t.start() for t in ts]
+            [t.join(timeout=120) for t in ts]
+        finally:
+            sys.setswitchinterval(switch)
+        assert not lost and not any(t.is_alive() for t in ts), lost
+        master.close()      # left open on failure: a wedged server's
+        #                     close() would wait instead of reporting
+
+    def test_close_hangs_up_on_a_connected_client(self, native):
+        """A stopped server answers nobody and waits for nobody: with a
+        client still connected (and a few workers already come and
+        gone), ``close()`` returns within a second and the client's
+        next op fails at once instead of being served by a leftover
+        worker."""
+        from paddle_tpu.resilience.retry import RetryPolicy
+        master = TCPStore(f"127.0.0.1:{free_port()}", is_master=True,
+                          native=native)
+        client = TCPStore(master.endpoint, timeout=2.0)
+        churn = TCPStore(master.endpoint, timeout=2.0,
+                         retry=RetryPolicy(max_attempts=2, backoff_s=0.001))
+        try:
+            for _ in range(8):
+                churn._sock.close()
+                churn.set("k", b"v")
+            assert client.get("k") == b"v"
+            t0 = time.monotonic()
+            master.close()
+            assert time.monotonic() - t0 < 1.0
+            assert client._sock.recv(1) == b""      # hung up, at once
+            assert time.monotonic() - t0 < 1.0
+        finally:
+            churn._sock.close()
+            client._sock.close()
+
+    def test_accept_loop_survives_connections_reset_before_accept(
+            self, native):
+        """A peer that connects and resets while its connection still
+        waits in the backlog must cost the server nothing: the accept
+        loop goes on and the next client is served."""
+        import socket
+        import struct
+        master = TCPStore(f"127.0.0.1:{free_port()}", is_master=True,
+                          native=native)
+        host, port = master.endpoint.rsplit(":", 1)
+        try:
+            t0 = time.monotonic()
+            for _ in range(64):
+                s = socket.create_connection((host, int(port)), timeout=2.0)
+                s.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                             struct.pack("ii", 1, 0))    # close() sends RST
+                s.close()
+            # a full backlog drops the SYN and the client waits 1 s for
+            # its retransmit: the Python server's backlog of 5 cost 10 s
+            assert time.monotonic() - t0 < 5.0
+            late = TCPStore(master.endpoint, timeout=2.0)
+            late.set("k", b"v")
+            assert late.get("k") == b"v"
+            late.close()
+        finally:
+            master.close()
+
     def test_no_retry_policy_still_surfaces_socket_death(self):
         """Without a policy the store keeps its fail-fast contract —
         the reconnect-with-backoff behaviour is strictly opt-in."""
@@ -212,10 +317,17 @@ class TestRendezvous:
             m.generation = 0
             m.store = TCPStore(f"127.0.0.1:{port}", is_master=is_first,
                                timeout=10)
-            r, eps = m.rendezvous()
-            results[rank_hint] = (r, eps)
-            m.store.close()
+            try:
+                r, eps = m.rendezvous()
+                results[rank_hint] = (r, eps)
+            finally:
+                # the first node hosts the store: it must outlive the
+                # second node's last read (a closing server hangs up on
+                # whoever is still connected, reply sent or not)
+                done.wait(timeout=15)
+                m.store.close()
 
+        done = threading.Barrier(2)
         t0 = threading.Thread(target=node, args=(0, True))
         t1 = threading.Thread(target=node, args=(1, False))
         t0.start(); time.sleep(0.1); t1.start()

@@ -6,7 +6,11 @@ tests cannot reach: ``paddle_tpu.launch`` → per-process env protocol →
 collectives (gloo on CPU, ICI/DCN on TPU) → joint training.  SURVEY §4
 patterns 2-3, §5.3, §5.8.
 
-Three contracts:
+Three contracts (the elastic ones in test_multiprocess_elastic_shrink.py
+and test_multiprocess_elastic_grow.py: xdist's ``--dist loadfile`` gives a
+file to one worker and starts the files with few tests last, so the six
+tests in one file, ~300 s of waiting on elastic timeouts, were the tail
+of every run):
 - cluster parity: 2 OS processes × 4 virtual CPU devices each train dp=8
   jointly and reproduce the single-process 8-device loss trajectory.
 - elastic shrink-resume: kill one node mid-run → the surviving node detects
@@ -54,7 +58,7 @@ def _run_single_reference(tmp_path, steps):
               "PDTPU_TEST_STEP_SLEEP"):
         env.pop(k, None)
     r = subprocess.run([sys.executable, WORKER], env=env,
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stdout + r.stderr
     (rec,) = _read_records(out)
     return rec
@@ -80,240 +84,6 @@ class TestClusterParity:
         single = _run_single_reference(tmp_path, self.STEPS)
         a = [cluster["losses"][str(i)] for i in range(self.STEPS)]
         b = [single["losses"][str(i)] for i in range(self.STEPS)]
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-
-class TestElasticShrinkResume:
-    STEPS = 10
-    KILL_AFTER = 5  # kill node 1 once the step_5 checkpoint is complete
-
-    def test_kill_node_shrink_world_resume_from_ckpt(self, tmp_path,
-                                                     monkeypatch):
-        out = str(tmp_path / "elastic.jsonl")
-        ckpt_dir = str(tmp_path / "ckpt")
-        port = free_port()
-        master = f"127.0.0.1:{port}"
-
-        monkeypatch.setenv("PDTPU_REPO", REPO)
-        monkeypatch.setenv("PDTPU_TEST_DEVICES", "4")
-        monkeypatch.setenv("PDTPU_TEST_STEPS", str(self.STEPS))
-        monkeypatch.setenv("PDTPU_TEST_OUT", out)
-        monkeypatch.setenv("PDTPU_TEST_CKPT_DIR", ckpt_dir)
-        # node death: node B's worker (global rank 1) SIGKILLs itself right
-        # after checkpointing step KILL_AFTER, and node B's controller gives
-        # up (--max_restarts 0) — the node is gone, exactly like a host
-        # failure mid-job
-        monkeypatch.setenv("PDTPU_TEST_KILL_RANK", "1")
-        monkeypatch.setenv("PDTPU_TEST_KILL_STEP", str(self.KILL_AFTER))
-
-        env_b = {**os.environ, "PYTHONPATH": REPO}
-        node_b = subprocess.Popen(
-            [sys.executable, "-m", "paddle_tpu.launch",
-             "--nnodes", "1:2", "--rank", "1", "--master", master,
-             "--nproc_per_node", "1", "--elastic_level", "1",
-             "--elastic_timeout", "4", "--max_restarts", "0",
-             "--job_id", "mpc2",
-             "--log_dir", str(tmp_path / "log_b"), WORKER],
-            env=env_b, cwd=REPO, start_new_session=True,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-
-        # node A: the surviving node, driven in the main thread (signal
-        # handlers require it); hosts the rendezvous store (rank 0); its
-        # worker must NOT kill itself (it is rank 0)
-        ctx = parse_args(["--nnodes", "1:2", "--rank", "0",
-                          "--master", master, "--nproc_per_node", "1",
-                          "--elastic_level", "1", "--elastic_timeout", "4",
-                          "--job_id", "mpc2",
-                          "--log_dir", str(tmp_path / "log_a"), WORKER])
-        try:
-            rc = CollectiveController(ctx).run()
-        finally:
-            try:
-                os.killpg(node_b.pid, signal.SIGKILL)
-            except ProcessLookupError:
-                pass
-            node_b.wait(timeout=30)
-
-        assert rc == 0
-        records = _read_records(out)
-        # generation 0 died before rank 0 finished → only the resumed
-        # (shrunk) generation reports
-        final = records[-1]
-        assert final["world"] == 1 and final["devices"] == 4
-        assert final["resumed_from"] is not None
-        # resumed from the kill-point checkpoint (or at worst one step
-        # earlier, if the survivor was torn down mid-save)
-        assert self.KILL_AFTER - 1 <= final["start"] <= self.KILL_AFTER
-
-        single = _run_single_reference(tmp_path, self.STEPS)
-        steps = sorted(int(s) for s in final["losses"])
-        assert steps[-1] == self.STEPS - 1
-        a = [final["losses"][str(i)] for i in steps]
-        b = [single["losses"][str(i)] for i in steps]
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-
-class TestElasticShrinkResumeSharded:
-    """Shrink across a SHARDED (dp, sharding=2) ZeRO-2 topology: the
-    relaunch must reshard-on-load partitioned optimizer moments (8-device
-    (4,2) mesh -> 4-device (2,2) mesh), not just redistribute dp data."""
-
-    STEPS = 10
-    KILL_AFTER = 5
-
-    def test_kill_node_shrink_sharded_state(self, tmp_path, monkeypatch):
-        out = str(tmp_path / "elastic_sharded.jsonl")
-        ckpt_dir = str(tmp_path / "ckpt")
-        port = free_port()
-        master = f"127.0.0.1:{port}"
-
-        monkeypatch.setenv("PDTPU_REPO", REPO)
-        monkeypatch.setenv("PDTPU_TEST_DEVICES", "4")
-        monkeypatch.setenv("PDTPU_TEST_STEPS", str(self.STEPS))
-        monkeypatch.setenv("PDTPU_TEST_OUT", out)
-        monkeypatch.setenv("PDTPU_TEST_CKPT_DIR", ckpt_dir)
-        monkeypatch.setenv("PDTPU_TEST_TOPO", "zero")
-        monkeypatch.setenv("PDTPU_TEST_DIM", "64")
-        monkeypatch.setenv("PDTPU_TEST_KILL_RANK", "1")
-        monkeypatch.setenv("PDTPU_TEST_KILL_STEP", str(self.KILL_AFTER))
-
-        env_b = {**os.environ, "PYTHONPATH": REPO}
-        node_b = subprocess.Popen(
-            [sys.executable, "-m", "paddle_tpu.launch",
-             "--nnodes", "1:2", "--rank", "1", "--master", master,
-             "--nproc_per_node", "1", "--elastic_level", "1",
-             "--elastic_timeout", "4", "--max_restarts", "0",
-             "--job_id", "mpc4",
-             "--log_dir", str(tmp_path / "log_b"), WORKER],
-            env=env_b, cwd=REPO, start_new_session=True,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-
-        ctx = parse_args(["--nnodes", "1:2", "--rank", "0",
-                          "--master", master, "--nproc_per_node", "1",
-                          "--elastic_level", "1", "--elastic_timeout", "4",
-                          "--job_id", "mpc4",
-                          "--log_dir", str(tmp_path / "log_a"), WORKER])
-        try:
-            rc = CollectiveController(ctx).run()
-        finally:
-            try:
-                os.killpg(node_b.pid, signal.SIGKILL)
-            except ProcessLookupError:
-                pass
-            node_b.wait(timeout=30)
-
-        assert rc == 0
-        final = _read_records(out)[-1]
-        assert final["world"] == 1 and final["devices"] == 4
-        assert final["resumed_from"] is not None
-        assert self.KILL_AFTER - 1 <= final["start"] <= self.KILL_AFTER
-
-        single = _run_single_reference(tmp_path, self.STEPS)
-        steps = sorted(int(s) for s in final["losses"])
-        assert steps[-1] == self.STEPS - 1
-        a = [final["losses"][str(i)] for i in steps]
-        b = [single["losses"][str(i)] for i in steps]
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-
-class TestElasticGrowResume:
-    """Scale-UP: node B joins a healthy world-1 job mid-run."""
-
-    def test_node_join_grows_world_resume_from_ckpt(self, tmp_path,
-                                                    monkeypatch):
-        final, steps_total = _run_grow_e2e(tmp_path, monkeypatch,
-                                           job_id="mpc3", out_name="grow")
-        # the job finished at the GROWN world, resumed from a checkpoint
-        # taken while running alone
-        assert final["world"] == 2 and final["devices"] == 8
-        assert final["resumed_from"] is not None
-        assert 1 <= final["start"] <= steps_total - 1
-
-        single = _run_single_reference(tmp_path, steps_total)
-        steps = sorted(int(s) for s in final["losses"])
-        assert steps[-1] == steps_total - 1
-        a = [final["losses"][str(i)] for i in steps]
-        b = [single["losses"][str(i)] for i in steps]
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-
-def _run_grow_e2e(tmp_path, monkeypatch, job_id, out_name, steps=12,
-                  join_delay=22, elastic_timeout=3, extra_env=None):
-    """Shared elastic scale-UP choreography: node A boots alone (gen-0
-    settle admits a 1-node quorum), trains with per-step checkpoints, and
-    node B's delayed join grows the world mid-run.  join_delay must exceed
-    A's settle window (elastic_timeout + 15s) plus a couple of steps; the
-    2.5 s/step sleep stretches training so the join lands mid-run."""
-    out = str(tmp_path / f"{out_name}.jsonl")
-    ckpt_dir = str(tmp_path / "ckpt")
-    master = f"127.0.0.1:{free_port()}"
-
-    monkeypatch.setenv("PDTPU_REPO", REPO)
-    monkeypatch.setenv("PDTPU_TEST_DEVICES", "4")
-    monkeypatch.setenv("PDTPU_TEST_STEPS", str(steps))
-    monkeypatch.setenv("PDTPU_TEST_OUT", out)
-    monkeypatch.setenv("PDTPU_TEST_CKPT_DIR", ckpt_dir)
-    monkeypatch.setenv("PDTPU_TEST_STEP_SLEEP", "2.5")
-    monkeypatch.delenv("PDTPU_TEST_KILL_RANK", raising=False)
-    monkeypatch.delenv("PDTPU_TEST_KILL_STEP", raising=False)
-    for k, v in (extra_env or {}).items():
-        monkeypatch.setenv(k, v)
-
-    common = ["--nnodes", "1:2", "--master", master,
-              "--nproc_per_node", "1", "--elastic_level", "1",
-              "--elastic_timeout", str(elastic_timeout),
-              "--max_restarts", "2", "--job_id", job_id]
-    env_b = {**os.environ, "PYTHONPATH": REPO}
-    cmd_b = " ".join(
-        [sys.executable, "-m", "paddle_tpu.launch", "--rank", "1",
-         "--log_dir", str(tmp_path / "log_b")] + common + [WORKER])
-    node_b = subprocess.Popen(
-        ["/bin/sh", "-c", f"sleep {join_delay} && exec {cmd_b}"],
-        env=env_b, cwd=REPO, start_new_session=True,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-
-    ctx = parse_args(["--rank", "0",
-                      "--log_dir", str(tmp_path / "log_a")]
-                     + common + [WORKER])
-    try:
-        rc = CollectiveController(ctx).run()
-    finally:
-        try:
-            os.killpg(node_b.pid, signal.SIGKILL)
-        except ProcessLookupError:
-            pass
-        node_b.wait(timeout=30)
-
-    assert rc == 0
-    return _read_records(out)[-1], steps
-
-
-class TestElasticGrowResumeSharded:
-    """Scale-UP into a SHARDED topology (VERDICT r4 #5b): node B joins a
-    healthy world-1 ZeRO-2 job; the relaunch lands on sharding=4 (was 2),
-    so every previously-held partitioned moment must SPLIT across twice
-    as many devices on reshard-on-load — the direction a recovering
-    preemptible fleet executes."""
-
-    def test_node_join_grow_splits_sharded_state(self, tmp_path,
-                                                 monkeypatch):
-        final, steps_total = _run_grow_e2e(
-            tmp_path, monkeypatch, job_id="mpc5", out_name="grow_sharded",
-            extra_env={"PDTPU_TEST_TOPO": "zero_scale",
-                       "PDTPU_TEST_DIM": "64"})
-        # finished at the grown world: 8 devices, sharding=4 (split from 2)
-        assert final["world"] == 2 and final["devices"] == 8
-        assert final["resumed_from"] is not None
-        assert 1 <= final["start"] <= steps_total - 1
-
-        # reference inherits TOPO=zero_scale (8 devices -> (2,4) mesh),
-        # matching the sharded-shrink test's pattern: ZeRO partitioning
-        # must not change numerics at any world size
-        single = _run_single_reference(tmp_path, steps_total)
-        steps = sorted(int(s) for s in final["losses"])
-        assert steps[-1] == steps_total - 1
-        a = [final["losses"][str(i)] for i in steps]
-        b = [single["losses"][str(i)] for i in steps]
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
@@ -422,19 +192,27 @@ class TestClusterServing:
                 wid = f"w{i}-{role}"
                 procs[wid] = self._spawn(store.endpoint, wid, role, env)
             ctl = serving.ClusterController(store, lease_deadline_s=6.0)
-            deadline = time.time() + 300
+            # a worker registers 10 s after its start on a cold compile
+            # cache (PR 27); one that has not after 90 s will not
+            deadline = time.time() + 90
             while True:
                 self._assert_alive(procs)
                 try:
                     ctl.wait_for_workers(4, timeout_s=2.0)
                     break
                 except TimeoutError:
-                    if time.time() > deadline:
-                        raise
+                    if time.time() < deadline:
+                        continue
+                    for p in procs.values():
+                        p.kill()
+                    raise AssertionError(
+                        "four workers never registered\n" + "\n".join(
+                            f"{wid} stderr:\n{p.communicate()[1][-2000:]}"
+                            for wid, p in procs.items())) from None
 
             # phase 1: disagg fleet serves token-identically
             rids = [ctl.submit(p, max_new_tokens=8) for p in prompts]
-            self._pump_until(ctl, procs, rids, timeout_s=180)
+            self._pump_until(ctl, procs, rids, timeout_s=60)
             assert [ctl.outputs[r]["tokens"] for r in rids] == ref
 
             # phase 2: SIGKILL a decode worker the moment it owns an
@@ -443,7 +221,7 @@ class TestClusterServing:
             # model); lease-expiry evacuation re-delivers every wave
             # token-identically
             victim, rids = None, []
-            deadline = time.time() + 120
+            deadline = time.time() + 60
             while victim is None and time.time() < deadline:
                 rids += [ctl.submit(p, max_new_tokens=24)
                          for p in prompts]
@@ -458,7 +236,7 @@ class TestClusterServing:
                             break
             assert victim, "no decode worker ever owned an assignment"
             procs[victim].kill()
-            self._pump_until(ctl, procs, rids, timeout_s=180,
+            self._pump_until(ctl, procs, rids, timeout_s=60,
                              may_exit=(victim,))
             for i, r in enumerate(rids):
                 assert ctl.outputs[r]["tokens"] == ref24[i % len(ref24)]
@@ -473,7 +251,7 @@ class TestClusterServing:
                 ctl.pump()
                 time.sleep(0.01)
             procs["w1-prefill"].send_signal(signal.SIGTERM)
-            self._pump_until(ctl, procs, rids, timeout_s=180,
+            self._pump_until(ctl, procs, rids, timeout_s=60,
                              may_exit=(victim, "w1-prefill"))
             assert [ctl.outputs[r]["tokens"] for r in rids] == ref
             rep = self._report(procs["w1-prefill"])

@@ -1,17 +1,15 @@
 #!/usr/bin/env python
 """Standing CI gates — the single entry point the test suite invokes
-(tests/test_ci_gates.py), so a public-API removal, a hot-op perf
-regression, or a sharding-memory regression fails ``pytest`` instead of
-waiting for a user (or a real pod OOM) to notice.
+(tests/test_ci_gates.py), so a public-API removal or a sharding-memory
+regression fails ``pytest`` instead of waiting for a user (or a real pod
+OOM) to notice.
 
 Reference: the reference repo's CI stack (SURVEY §2.8 — API-approval diff
-job, op-benchmark job, model memory checks) — here collapsed into four
-in-repo gates over artifacts committed alongside the code:
+job, model memory checks; its op-benchmark job has no gate here: kernel
+numbers come from the chip, benchmark/metrics/*_roofline.py) — here
+collapsed into in-repo gates over artifacts committed alongside the code:
 
   api-compat      tools/check_api_compat.py vs tools/api_spec.txt
-  op-benchmark    tools/op_benchmark.py vs tools/op_baseline.json
-                  (loose tolerance: catches order-of-magnitude regressions
-                  like an op falling off its compiled path, not CI noise)
   memproof-lite   cheap re-check of the 13B hybrid sharding from
                   docs/memproof.json: rebuild the abstract train state on
                   the deviceless v5e:8x8 topology and recompute per-chip
@@ -69,7 +67,7 @@ in-repo gates over artifacts committed alongside the code:
                   all blocks reclaimed on every replica
 
 Run all:  python tools/ci.py            (exit 0 = all gates pass)
-One:      python tools/ci.py --only api-compat|op-benchmark|memproof-lite|telemetry-overhead|chaos|serving-smoke|chaos-serving|serving-dist|lint
+One:      python tools/ci.py --only api-compat|memproof-lite|telemetry-overhead|chaos|serving-smoke|chaos-serving|serving-dist|lint
 """
 
 from __future__ import annotations
@@ -106,24 +104,6 @@ def gate_api_compat() -> int:
     sys.argv = ["check_api_compat.py"]
     import check_api_compat
     return check_api_compat.main()
-
-
-def gate_op_benchmark(tolerance: float = 1.5) -> int:
-    """Subprocess, pinned to the CPU backend: the standing gate compares
-    the deterministic CPU baseline entries only (``--fast`` runs a tenth
-    of the iterations, too few to judge a chip by).  TPU baselines are
-    checked by explicit full runs of tools/op_benchmark.py on the chip."""
-    # PREPEND to PYTHONPATH: the caller's entries stay importable
-    pp = os.environ.get("PYTHONPATH")
-    env = {**os.environ,
-           "PYTHONPATH": REPO + (os.pathsep + pp if pp else "")}
-    r = subprocess.run(
-        [sys.executable, os.path.join(HERE, "op_benchmark.py"),
-         "--tolerance", str(tolerance), "--fast", "--platform", "cpu"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=1800)
-    sys.stdout.write(r.stdout)
-    sys.stderr.write(r.stderr)
-    return r.returncode
 
 
 def _shard_bytes(leaf) -> int:
@@ -1459,7 +1439,10 @@ def gate_chaos_serving(max_batch: int = 4) -> int:
                                              size=4).astype(np.int32), 4),
                         rng.integers(0, model.cfg.vocab_size,
                                      size=17).astype(np.int32)]
-        spec_budgets = (8, 5, 10, 6)
+        # long enough for a random tiny model's greedy output to fall into
+        # a cycle: the drafts it accepts are of its own loop, the motifs
+        # owe it none (at 5-10 tokens nothing was ever accepted)
+        spec_budgets = (16, 16, 16, 16)
 
         def spec_scenario(spec, tag):
             rs.clear_faults()
@@ -2210,6 +2193,10 @@ def gate_serving_cluster(n_prefill: int = 2, n_decode: int = 2) -> int:
            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+           # the workers' stderr is a pipe nobody reads until they exit;
+           # on a warm compile cache every hit logs ~4 KB of
+           # cpu_aot_loader lines and a full pipe (64 KiB) stops a worker
+           "TF_CPP_MIN_LOG_LEVEL": "3",
            # transient control-plane faults in EVERY worker: a retried
            # register, a retried lease renew, a requeued first command
            "PDTPU_FAULTS": ("cluster.register@1;"
@@ -2833,7 +2820,6 @@ def gate_lint(timeout_s: float = 120.0) -> int:
 GATES = {
     "api-compat": gate_api_compat,
     "lint": gate_lint,
-    "op-benchmark": gate_op_benchmark,
     "memproof-lite": gate_memproof_lite,
     "telemetry-overhead": gate_telemetry_overhead,
     "chaos": gate_chaos,
