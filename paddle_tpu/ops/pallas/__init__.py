@@ -142,12 +142,13 @@ dispatch.register("fused_rms_rope_qkv", _fused_rms_rope_qkv_dispatch,
 
 
 def _fused_adamw_dispatch(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
-                          wd):
-    if _active_mesh() is not None or not _fadamw.eligible(p):
+                          wd, low_dtype=None):
+    if _active_mesh() is not None \
+            or not _fadamw.eligible(p, g, low_dtype):
         return None
     return _fadamw.fused_adamw_update(p, g, m, v, lr, c1, c2,
                                       beta1=beta1, beta2=beta2, eps=eps,
-                                      wd=wd)
+                                      wd=wd, low_dtype=low_dtype)
 
 
 dispatch.register("fused_adamw", _fused_adamw_dispatch, platform="tpu")

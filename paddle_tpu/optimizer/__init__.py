@@ -86,6 +86,18 @@ class Optimizer:
     def _update_one(self, name, p, g, lr, state_slots, step):
         raise NotImplementedError
 
+    def _update_leaf(self, name, p, g, lr, state_slots, step, wd, dtype):
+        """One dense leaf as ``apply`` holds it: ``p`` the float32
+        parameter or master, ``g`` the gradient in the dtype it arrived
+        in, ``dtype`` the dtype the model keeps the parameter in.  Returns
+        (new float32 ``p``, the same in ``dtype``, new slots).  An
+        optimizer whose kernel reads the gradient and writes the
+        low-precision copy itself overrides this; the rule is
+        ``_update_one``, in float32."""
+        new_p, new_slots = self._update_one(
+            name, p, g.astype(jnp.float32), lr, state_slots, step, wd)
+        return new_p, new_p.astype(dtype), new_slots
+
     def _decay_mask(self, params: Dict[str, jax.Array]) -> Dict[str, bool]:
         if self.apply_decay_param_fun is None:
             return {k: True for k in params}
@@ -135,14 +147,12 @@ class Optimizer:
             if self._l1_coeff and decay_mask.get(name, True):
                 # L1Decay: subgradient of coeff*|w| added to the grad
                 g = g + self._l1_coeff * jnp.sign(p_compute)
-            new_p, new_slots = self._update_one(
-                name, p_compute.astype(jnp.float32), g.astype(jnp.float32),
-                lr, slots, step, wd)
+            new_p, new_low, new_slots = self._update_leaf(
+                name, p_compute.astype(jnp.float32), g, lr, slots, step, wd,
+                p.dtype)
             if master is not None:
                 new_state["master"][name] = new_p
-                new_params[name] = new_p.astype(p.dtype)
-            else:
-                new_params[name] = new_p.astype(p.dtype)
+            new_params[name] = new_low
             for k, v in new_slots.items():
                 new_state[k][name] = v
         for name, rg in rows_grads.items():
@@ -331,11 +341,13 @@ class AdamW(Adam):
     """Decoupled weight decay (reference: AdamwDenseKernel).
 
     ``use_fused``: route eligible parameter updates through the fused
-    Pallas AdamW kernel (ops/pallas/fused_adamw.py) — moments + param in
-    one elementwise pass over aliased buffers on TPU.  ``None`` (auto)
-    uses the kernel wherever its dispatch serves (TPU backend, f32
-    lane-aligned params); ``False`` pins the XLA composition.  Both
-    compute the same formula (tests/test_fused_kernels.py)."""
+    Pallas AdamW kernel (ops/pallas/fused_adamw.py) — moments, parameter
+    and its low-precision copy in one elementwise pass over aliased
+    buffers on TPU, on the leaf in the shape the step holds it.  ``None``
+    (auto) uses the kernel wherever its dispatch serves (TPU backend, no
+    mesh, float32 state of two or more dimensions on whole tiles);
+    ``False`` pins the XLA composition.  Both compute the same formula
+    (tests/test_fused_kernels.py)."""
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8,
                  parameters=None, weight_decay=0.01, grad_clip=None,
@@ -346,21 +358,26 @@ class AdamW(Adam):
         self.apply_decay_param_fun = apply_decay_param_fun
         self.use_fused = use_fused
 
-    def _update_one(self, name, p, g, lr, slots, step, wd):
+    def _update_leaf(self, name, p, g, lr, slots, step, wd, dtype):
         if self.use_fused is not False:
             from ..ops import dispatch
             impl = dispatch.get("fused_adamw")
             if impl is not None:
                 t = (step + 1).astype(jnp.float32)
+                low = None if dtype == p.dtype else dtype
                 out = impl(p, g, slots["moment1"], slots["moment2"],
                            jnp.asarray(lr, jnp.float32),
                            1.0 / (1.0 - self.beta1 ** t),
                            1.0 / (1.0 - self.beta2 ** t),
                            beta1=self.beta1, beta2=self.beta2,
-                           eps=self.epsilon, wd=float(wd))
+                           eps=self.epsilon, wd=float(wd), low_dtype=low)
                 if out is not None:
-                    new_p, m, v = out
-                    return new_p, {"moment1": m, "moment2": v}
+                    new_p, m, v = out[:3]
+                    return (new_p, new_p if low is None else out[3],
+                            {"moment1": m, "moment2": v})
+        return super()._update_leaf(name, p, g, lr, slots, step, wd, dtype)
+
+    def _update_one(self, name, p, g, lr, slots, step, wd):
         new_p, m, v = self._adam_core(p, g, lr, slots["moment1"], slots["moment2"],
                                       step, wd, decoupled=True)
         return new_p, {"moment1": m, "moment2": v}

@@ -252,7 +252,14 @@ class TestInt8MatmulKernel:
 
 
 class TestFusedAdamWKernel:
-    def _legs(self, p, g, m, v, step, wd):
+    def _state(self, shape, g_dtype):
+        p = jnp.asarray(R.normal(size=shape), jnp.float32)
+        g = jnp.asarray(R.normal(size=shape), g_dtype)
+        m = jnp.asarray(R.normal(size=shape) * 0.1, jnp.float32)
+        v = jnp.asarray(np.abs(R.normal(size=shape)) * 0.01, jnp.float32)
+        return p, g, m, v
+
+    def _legs(self, p, g, m, v, step, wd, low_dtype=None):
         from paddle_tpu import optimizer as opt
         aw = opt.AdamW(learning_rate=1e-3, weight_decay=wd,
                        use_fused=False)
@@ -262,31 +269,173 @@ class TestFusedAdamWKernel:
         c2 = 1.0 / (1.0 - 0.999 ** t)
         got = FA.fused_adamw_update(p, g, m, v, lr, c1, c2, beta1=0.9,
                                     beta2=0.999, eps=1e-8, wd=wd,
-                                    interpret=True)
+                                    low_dtype=low_dtype, interpret=True)
         want_p, slots = aw._update_one(
-            "w", p, g, lr, {"moment1": m, "moment2": v},
-            jnp.int32(step), wd)
+            "w", p, g.astype(jnp.float32), lr,
+            {"moment1": m, "moment2": v}, jnp.int32(step), wd)
         return got, (want_p, slots["moment1"], slots["moment2"])
 
+    # native blocks over the leaf's own trailing dimensions, more than one
+    # block each way and not square: (shape, gradient dtype, grid)
+    NATIVE = [((64, 384), jnp.float32, (8, 3)),
+              ((24, 256), jnp.float32, (3, 2)),
+              ((2, 16, 256), jnp.float32, (4, 2)),      # 3-D: rows fold
+              ((64, 256), jnp.bfloat16, (4, 2))]
+
     @pytest.mark.parametrize("wd", [0.0, 0.01])
-    @pytest.mark.parametrize("shape", [(16, 128), (1024,)])
-    def test_kernel_matches_adam_core(self, wd, shape):
-        p = jnp.asarray(R.normal(size=shape), jnp.float32)
-        g = jnp.asarray(R.normal(size=shape), jnp.float32)
-        m = jnp.asarray(R.normal(size=shape) * 0.1, jnp.float32)
-        v = jnp.asarray(np.abs(R.normal(size=shape)) * 0.01, jnp.float32)
+    @pytest.mark.parametrize("shape,g_dtype,grid", NATIVE, ids=[
+        "64x384", "24x256", "2x16x256", "64x256-bf16grad"])
+    def test_kernel_matches_adam_core(self, wd, shape, g_dtype, grid,
+                                      monkeypatch):
+        sub = FA._sublanes(g_dtype)
+        monkeypatch.setattr(FA, "BLOCK_COLS", FA.LANES)          # one tile
+        monkeypatch.setattr(FA, "BLOCK_ELEMS", sub * FA.LANES)
+        rows, cols = FA._view(shape, sub)
+        br, bc = FA._block(rows, cols, sub)
+        assert (rows // br, cols // bc) == grid
+        p, g, m, v = self._state(shape, g_dtype)
         got, want = self._legs(p, g, m, v, step=7, wd=wd)
+        assert len(got) == 3
         for a, b in zip(got, want):
-            assert a.shape == shape
+            assert a.shape == shape and a.dtype == jnp.float32
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-6, atol=1e-6)
 
-    def test_eligibility(self):
-        assert FA.eligible(jnp.zeros((8, 128), jnp.float32))
-        assert FA.eligible(jnp.zeros((1024,), jnp.float32))
-        assert not FA.eligible(jnp.zeros((100,), jnp.float32))   # ragged
-        assert not FA.eligible(jnp.zeros((8, 128), jnp.bfloat16))
-        assert not FA.eligible(jnp.zeros((512,), jnp.float32))   # < 1024
+    @pytest.mark.parametrize("wd", [0.0, 0.01])
+    def test_low_precision_copy_is_the_cast_of_the_master(self, wd,
+                                                          monkeypatch):
+        """amp O2: bfloat16 gradient in, and the bfloat16 parameter out
+        equal bit for bit to ``new_p.astype(bfloat16)``."""
+        monkeypatch.setattr(FA, "BLOCK_COLS", FA.LANES)
+        monkeypatch.setattr(FA, "BLOCK_ELEMS", 16 * FA.LANES)
+        p, g, m, v = self._state((32, 384), jnp.bfloat16)
+        got, want = self._legs(p, g, m, v, step=3, wd=wd,
+                               low_dtype=jnp.bfloat16)
+        new_p, _, _, low = got
+        assert low.dtype == jnp.bfloat16 and low.shape == (32, 384)
+        np.testing.assert_array_equal(
+            np.asarray(low.astype(jnp.float32)),
+            np.asarray(new_p.astype(jnp.bfloat16).astype(jnp.float32)))
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("dims", [
+        (4096, 14336), (14336, 4096), (4096, 1024), (32000, 4096),
+        (4096, 32000), (4096, 12288), (50304, 4096), (2048, 4096),
+        (4096, 11008), (11008, 4096), (16, 128), (8, 4096, 1024)],
+        ids=lambda d: "x".join(map(str, d)))
+    def test_block_divides_the_leaf(self, dims):
+        """The benchmark's and the smoke's leaves: the block divides the
+        leaf, sits on whole tiles and inside the VMEM budget."""
+        for sub in (8, 16):
+            rows, cols = FA._view(dims, sub)
+            br, bc = FA._block(rows, cols, sub)
+            assert rows % br == 0 and cols % bc == 0
+            assert br % sub == 0 and bc % FA.LANES == 0
+            assert br * bc <= FA.BLOCK_ELEMS
+
+    F32, BF16 = jnp.float32, jnp.bfloat16
+
+    @pytest.mark.parametrize("dims,dtype,g_dtype,low,ok", [
+        ((8, 128), F32, F32, None, True),
+        ((8, 128), F32, BF16, None, False),     # a bf16 tile is 16 rows
+        ((16, 128), F32, BF16, BF16, True),
+        ((8, 128), F32, F32, BF16, False),
+        ((8, 128), BF16, F32, None, False),     # the state is float32
+        ((1024,), F32, F32, None, False),       # one-dimensional
+        ((100, 128), F32, F32, None, False),    # rows off the tile
+        ((64, 100), F32, F32, None, False),     # lanes off the tile
+        ((8, 3, 3, 16), F32, F32, None, False),     # a conv kernel
+        ((4, 16, 256), F32, BF16, BF16, True),  # leading dims fold
+        ((4, 8, 256), F32, BF16, BF16, False),  # ... only on whole tiles
+        ((0, 128), F32, F32, None, False),
+    ])
+    def test_eligibility(self, dims, dtype, g_dtype, low, ok):
+        p = jax.ShapeDtypeStruct(dims, dtype)
+        g = jax.ShapeDtypeStruct(dims, g_dtype)
+        assert FA.eligible(p, g, low) == ok
+
+    def _as_tpu(self, monkeypatch, calls):
+        """The registry answers as on the chip; the kernel it hands out
+        runs in interpret mode and notes each leaf it was given."""
+        from paddle_tpu.ops import dispatch
+        monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+        real = FA.fused_adamw_update
+
+        def interpreted(p, *a, **k):
+            calls.append(tuple(p.shape))
+            return real(p, *a, interpret=True, **k)
+        monkeypatch.setattr(FA, "fused_adamw_update", interpreted)
+
+    @pytest.mark.parametrize("shape", [(1024,), (8, 3, 3, 16), (100, 128)],
+                             ids=["1d", "conv", "ragged-rows"])
+    def test_declined_shape_takes_the_xla_composition(self, shape,
+                                                      monkeypatch):
+        from paddle_tpu import optimizer as opt
+        calls = []
+        self._as_tpu(monkeypatch, calls)
+        p, g, m, v = self._state(shape, jnp.bfloat16)
+        lr, step = jnp.float32(1e-3), jnp.int32(4)
+        outs = []
+        for fused in (None, False):
+            aw = opt.AdamW(learning_rate=1e-3, weight_decay=0.01,
+                           use_fused=fused)
+            outs.append(aw._update_leaf(
+                "w", p, g, lr, {"moment1": m, "moment2": v}, step, 0.01,
+                jnp.bfloat16))
+        assert not calls
+        want = aw._adam_core(p, g.astype(jnp.float32), lr, m, v, step, 0.01,
+                             decoupled=True)
+        for (new_p, low, slots) in outs:
+            assert low.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(np.asarray(new_p),
+                                          np.asarray(want[0]))
+            np.testing.assert_array_equal(
+                np.asarray(low.astype(jnp.float32)),
+                np.asarray(want[0].astype(jnp.bfloat16).astype(jnp.float32)))
+            np.testing.assert_array_equal(np.asarray(slots["moment1"]),
+                                          np.asarray(want[1]))
+            np.testing.assert_array_equal(np.asarray(slots["moment2"]),
+                                          np.asarray(want[2]))
+
+    @pytest.mark.parametrize("master", [True, False],
+                             ids=["amp-O2", "float32"])
+    def test_apply_routes_matrices_through_the_kernel(self, master,
+                                                      monkeypatch):
+        """``Optimizer.apply`` as the train step calls it: the matrices
+        take the kernel (gradient as it arrives, low-precision copy out
+        under amp O2), the norm weight the XLA composition, and the
+        result is ``use_fused=False``'s."""
+        from paddle_tpu import nn, optimizer as opt
+        calls = []
+        self._as_tpu(monkeypatch, calls)
+        dt = jnp.bfloat16 if master else jnp.float32
+        params = {"w": jnp.asarray(R.normal(size=(32, 256)), dt),
+                  "e": jnp.asarray(R.normal(size=(2, 16, 128)), dt),
+                  "norm": jnp.asarray(R.normal(size=(256,)), dt)}
+        grads = {k: jnp.asarray(R.normal(size=v.shape), dt)
+                 for k, v in params.items()}
+        outs = []
+        for fused in (None, False):
+            aw = opt.AdamW(learning_rate=1e-2, weight_decay=0.1,
+                           grad_clip=nn.ClipGradByGlobalNorm(1.0),
+                           multi_precision=master, use_fused=fused)
+            state = aw.init(params)
+            new_params, state = aw.apply(grads, state, params)
+            outs.append(aw.apply(grads, state, new_params))
+        assert sorted(calls) == [(2, 16, 128), (2, 16, 128),
+                                 (32, 256), (32, 256)]
+        (p_k, s_k), (p_x, s_x) = outs
+        for k in params:
+            assert p_k[k].dtype == dt
+            np.testing.assert_allclose(
+                np.asarray(p_k[k].astype(jnp.float32)),
+                np.asarray(p_x[k].astype(jnp.float32)), rtol=1e-6, atol=1e-6)
+            for slot in ("moment1", "moment2") + (("master",) * master):
+                np.testing.assert_allclose(np.asarray(s_k[slot][k]),
+                                           np.asarray(s_x[slot][k]),
+                                           rtol=1e-6, atol=1e-6)
 
     def test_adamw_use_fused_kwarg_cpu_noop(self):
         """On CPU the dispatch declines and use_fused falls back to the
@@ -300,8 +449,8 @@ class TestFusedAdamWKernel:
         for fused in (None, False):
             aw = opt.AdamW(learning_rate=1e-3, weight_decay=0.01,
                            use_fused=fused)
-            outs.append(aw._update_one("w", p, g, lr, dict(slots),
-                                       jnp.int32(0), 0.01))
+            outs.append(aw._update_leaf("w", p, g, lr, dict(slots),
+                                        jnp.int32(0), 0.01, jnp.float32))
         np.testing.assert_array_equal(np.asarray(outs[0][0]),
                                       np.asarray(outs[1][0]))
 

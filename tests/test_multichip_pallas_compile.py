@@ -21,7 +21,9 @@ worker that is handed this file loads libtpu, and it skips where the
 topology cannot be described.  Keep every such test in THIS file.
 """
 
+import math
 import os
+import re
 
 import pytest
 
@@ -264,15 +266,21 @@ def _case_paged_attention(shape):
     return m.paged_attention, args, ["paged_attention"]
 
 
-def _case_fused_adamw(shape):
-    from paddle_tpu.ops.pallas import fused_adamw as m
-    p, c = shape((H, I), F32), shape((), F32)
-    assert m.eligible(p)
+def _case_fused_adamw(dims=(H, I), g_dtype=BF16):
+    """float32 master and moments, the gradient as it arrives; under amp
+    O2 (a bfloat16 gradient) the bfloat16 copy out."""
+    def case(shape):
+        from paddle_tpu.ops.pallas import fused_adamw as m
+        low = None if g_dtype == F32 else BF16
+        p, g, c = shape(dims, F32), shape(dims, g_dtype), shape((), F32)
+        assert m.eligible(p, g, low)
 
-    def update(p, g, mom, v, lr, c1, c2):
-        return m.fused_adamw_update(p, g, mom, v, lr, c1, c2, beta1=0.9,
-                                    beta2=0.999, eps=1e-8, wd=0.1)
-    return update, (p, p, p, p, c, c, c), ["fused_adamw"]
+        def update(p, g, mom, v, lr, c1, c2):
+            return m.fused_adamw_update(p, g, mom, v, lr, c1, c2, beta1=0.9,
+                                        beta2=0.999, eps=1e-8, wd=0.1,
+                                        low_dtype=low)
+        return update, (p, g, p, p, c, c, c), ["fused_adamw"]
+    return case
 
 
 def _case_int8_matmul(shape):
@@ -330,7 +338,7 @@ KERNEL_CASES = {
     "ragged_paged_attention-mistral-gqa8": _case_ragged(
         16, kv_heads=8, batch=32, ctx=4096),
     "paged_attention-7b": _case_paged_attention,
-    "fused_adamw-7b": _case_fused_adamw,
+    "fused_adamw-7b": _case_fused_adamw(),
     "int8_matmul-7b": _case_int8_matmul,
     "int4_matmul-7b": _case_int4_matmul,
     "lora_bgmv-7b": _case_lora_bgmv,
@@ -347,6 +355,52 @@ def test_kernel_compiles_for_v5e(case, shape, as_tpu, smoke):
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     found = smoke.pallas_kernels(hlo)
     assert all(n in found for n in names), (names, found)
+
+
+# every two-dimensional leaf the benchmark's two training cells and
+# chip_smoke.py train (PERF.md section 4), and a stacked 3-D leaf
+ADAMW_LEAVES = [
+    (4096, 14336), (14336, 4096), (4096, 4096), (4096, 1024),
+    (32000, 4096), (4096, 32000),                         # mistral-7b
+    (4096, 12288), (4096, 16384), (16384, 4096), (50304, 4096),
+    (2048, 4096),                                         # gpt3-6.7b
+    (H, I), (I, H),                                       # llama2-7b
+    (8, 4096, 1024),
+]
+
+
+def _sized(hlo: str, sizes) -> list:
+    """(name, opcode, line) of the entry computation's instructions whose
+    result, or one element of a tuple result, has as many elements as
+    ``sizes`` holds."""
+    out = []
+    for line in hlo[hlo.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m and any(math.prod(map(int, dims.split(","))) in sizes
+                     for dims in re.findall(r"\w+\[([\d,]+)\]",
+                                            m.group(2))):
+            out.append((m.group(1), m.group(3), line.strip()))
+    return out
+
+
+@pytest.mark.parametrize("g_dtype", [BF16, F32], ids=["bf16grad", "f32grad"])
+@pytest.mark.parametrize("dims", ADAMW_LEAVES,
+                         ids=["x".join(map(str, d)) for d in ADAMW_LEAVES])
+def test_fused_adamw_takes_the_leaf_as_it_is(dims, g_dtype, shape, as_tpu):
+    """State donated: nothing of the leaf's size is in the program but
+    the kernel (and the views of its operands), and nothing is kept
+    beside the state.  With ``(n/128, 128)`` operands this read seven
+    ``reshape`` copies and 704,772,096 temporary bytes at ``(H, I)``."""
+    update, args, _ = _case_fused_adamw(dims, g_dtype)(shape)
+    compiled = jax.jit(update, donate_argnums=(0, 2, 3)).lower(
+        *args).compile()
+    moved = [name for name, opcode, _ in _sized(compiled.as_text(),
+                                                {math.prod(dims)})
+             if opcode not in ("parameter", "bitcast", "get-tuple-element",
+                               "tuple")
+             and not name.startswith("fused_adamw")]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_gates_decline_what_mosaic_refuses(as_tpu):
@@ -403,8 +457,18 @@ def test_smoke_train_step_compiles_for_v5e(topo, as_tpu, smoke):
     assert found.get("flash_attention_fwd") == layers, found
     assert found.get("flash_attention_bwd") == layers, found
     assert found.get("fused_swiglu_mlp") == layers, found
-    assert found.get("fused_adamw", 0) > 0, found
+    # every matrix goes through the kernel: q, k, v, o, gate, up, down a
+    # layer, the embedding and the head; the norm weights do not
+    assert found.get("fused_adamw") == 7 * layers + 2, found
     assert "fused_rms_rope_qkv" not in found, found
+    # the kernel takes each leaf in the shape the step holds it: nothing
+    # of a trained leaf's size is copied or reshaped around it
+    sizes = {math.prod(s.shape) for s in jax.tree.leaves(
+        step.abstract_state()["params"]) if len(s.shape) > 1}
+    moved = [name for name, opcode, line in _sized(compiled.as_text(), sizes)
+             if opcode in ("reshape", "copy", "transpose")
+             and "/optimizer/" in line]
+    assert not moved, moved
     # state + temporaries leave room on a 16 GiB chip for the phases
     # that follow in the same process
     ma = compiled.memory_analysis()

@@ -419,8 +419,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="llama-350m")
     ap.add_argument("--ops", default="all",
-                    help="comma list of: fusion, blocks, mega, serving, "
-                         "adamw")
+                    help="comma list of: fusion, blocks, mega, serving")
     ap.add_argument("--tokens", type=int, default=None,
                     help="token count for the op sweeps (default: 2048 "
                          "on TPU, 256 on CPU)")
@@ -433,7 +432,7 @@ def main():
     t = args.tokens or (2048 if on_tpu else 256)
     iters = args.iters or (20 if on_tpu else 5)
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
-    wanted = (("fusion", "blocks", "mega", "serving", "adamw")
+    wanted = (("fusion", "blocks", "mega", "serving")
               if args.ops == "all" else tuple(args.ops.split(",")))
     preset = args.preset
 
@@ -444,23 +443,6 @@ def main():
         _merge(results, "_", sweep_blocks(preset, t, dtype, iters))
     if "mega" in wanted:
         _merge(results, "_", sweep_mega(preset, dtype, iters))
-    if "adamw" in wanted and on_tpu:
-        from paddle_tpu.ops.pallas import fused_adamw as FA
-        r = np.random.default_rng(0)
-        p = jnp.asarray(r.normal(size=(4096, 1024)), jnp.float32)
-        g, m, v = p * 0.01, p * 0.0, p * 0.0
-        best = (float("inf"), None)
-        for br in (256, 512, 1024):
-            # pdtpu-lint: disable=retrace-hazard — one compile per swept config, by design
-            ms = _time(jax.jit(lambda *a, _br=br: FA.fused_adamw_update(
-                *a, beta1=0.9, beta2=0.999, eps=1e-8, wd=0.01,
-                block_rows=_br)),
-                p, g, m, v, jnp.float32(1e-3), jnp.float32(10.0),
-                jnp.float32(1000.0), iters=iters)
-            print(f"# fused_adamw rows={br}: {ms:.3f} ms")
-            best = min(best, (ms, br))
-        _merge(results, "_",
-               {"fused_adamw": {"default": {"block_rows": best[1]}}})
     if "serving" in wanted:
         _merge(results, "_", sweep_serving(preset, on_tpu))
 
