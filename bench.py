@@ -54,15 +54,14 @@ def provenance(fused_ops="auto") -> dict:
 
 
 def measure(preset, batch_size, seq_len, steps, windows, remat=False,
-            loss_chunks=1, fuse=False, remat_layers=None,
+            loss_chunks=1, remat_layers=None,
             fused_ops="auto"):
     """One full measurement: build model+step, warm up, time `windows`
     independent windows of `steps` steps.  Returns (mfu, stats dict).
 
     ``fused_ops`` routes the model through the fused-kernel library
     (docs/KERNELS.md): "on"/"off"/"auto" — the one-flag MFU A/B
-    (``--fused`` on the CLI).  ``fuse`` is the older trace-time
-    weight-concat knob, kept for tune_sweep compatibility."""
+    (``--fused`` on the CLI)."""
     import gc
 
     import paddle_tpu as pt
@@ -73,7 +72,7 @@ def measure(preset, batch_size, seq_len, steps, windows, remat=False,
     pt.seed(0)
     model = llama(preset, max_position_embeddings=seq_len,
                   use_recompute=remat, loss_seq_chunks=loss_chunks,
-                  fuse_qkv_mlp=fuse, recompute_num_layers=remat_layers,
+                  recompute_num_layers=remat_layers,
                   fused_ops=fused_ops)
     cfg = model.cfg
     opt = optimizer.AdamW(learning_rate=3e-4, weight_decay=0.1,
@@ -134,21 +133,11 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     # the one-flag fused-kernel A/B (docs/KERNELS.md): --fused off is
     # the pre-fusion baseline, --fused on forces the fused entry points
-    # everywhere, auto (default) fuses where a kernel serves, mega
-    # additionally collapses each cached decoder layer into the
-    # one-dispatch megakernel ("Decode megakernel").  Env
-    # PDTPU_BENCH_FUSED_OPS backs the flag for driver scripts.
-    ap.add_argument("--fused", choices=("on", "off", "auto", "mega"),
-                    default=os.environ.get("PDTPU_BENCH_FUSED_OPS",
-                                           "auto"))
+    # everywhere, auto (default) fuses where a kernel serves
+    ap.add_argument("--fused", choices=("on", "off", "auto"),
+                    default="auto")
     args, _ = ap.parse_known_args()
     fused_ops = args.fused
-    if fused_ops not in ("on", "off", "auto", "mega"):
-        # argparse only validates choices for EXPLICIT flags — a typo'd
-        # env default would otherwise die mid-trace, long after telemetry
-        # already recorded the bogus mode
-        ap.error(f"PDTPU_BENCH_FUSED_OPS={fused_ops!r}: expected "
-                 "on|off|auto|mega")
     on_tpu = jax.default_backend() != "cpu"
     preset = os.environ.get("PDTPU_BENCH_PRESET",
                             "llama-350m" if on_tpu else "tiny")
@@ -180,12 +169,11 @@ def main():
     # default stays bs4 + unchunked: 0.437 vs 0.435 chunked, sweep
     # 2026-07-30) — the knob exists for memory-tight configs
     loss_chunks = int(os.environ.get("PDTPU_BENCH_LOSS_CHUNKS", 1))
-    fuse = os.environ.get("PDTPU_BENCH_FUSE", "0") == "1"
     windows = max(1, int(os.environ.get("PDTPU_BENCH_WINDOWS",
                                         2 if on_tpu else 1)))
 
     mfu, stats = measure(preset, batch_size, seq_len, steps, windows,
-                         remat=remat, loss_chunks=loss_chunks, fuse=fuse,
+                         remat=remat, loss_chunks=loss_chunks,
                          fused_ops=fused_ops)
     extra = {**stats,
              "backend": jax.default_backend(),
@@ -427,32 +415,6 @@ def main():
                                   "active_adapters")}
         except Exception as e:  # noqa: BLE001
             extra["serve_lora_error"] = f"{type(e).__name__}: {e}"[:300]
-
-        # decode megakernel (docs/KERNELS.md "Decode megakernel"): bs=1
-        # paged decode with the whole decoder layer in ONE dispatch
-        # (fused_ops="mega") vs the per-stage fused path.  Rows are
-        # backend-tagged (serve_mega vs serve_mega_cpu) so TPU numbers
-        # never gate against the CPU baseline; off the chip the Pallas
-        # kernel declines and the honest signal is the recorded
-        # dispatches-per-step delta, not the tok/s ratio.
-        try:
-            from decode_bench import bench_decode_mega
-            with contextlib.redirect_stdout(sys.stderr):
-                if on_tpu:
-                    r = bench_decode_mega()
-                else:
-                    r = bench_decode_mega(preset="tiny", prefill=16,
-                                          max_new=24, repeats=2)
-            pre = "serve_mega" if on_tpu else "serve_mega_cpu"
-            extra[f"{pre}_tok_s"] = r["mega_tok_s"]
-            extra[f"{pre}_vs_fused_on"] = r["vs_fused_on"]
-            extra[f"{pre}_dispatches_per_step"] = \
-                r["mega_dispatches_per_step"]
-            extra[f"{pre}_detail"] = {
-                k: r[k] for k in ("preset", "prefill", "max_new_tokens",
-                                  "on_tok_s", "on_dispatches_per_step")}
-        except Exception as e:  # noqa: BLE001
-            extra["serve_mega_error"] = f"{type(e).__name__}: {e}"[:300]
 
         # sharded serving (docs/SERVING.md "Sharded serving"): the
         # TP-partitioned engine and the DP replica router need >= 2

@@ -153,46 +153,6 @@ def _fused_swiglu_mlp_bwd(res, ct):
 fused_swiglu_mlp.defvjp(_fused_swiglu_mlp_fwd, _fused_swiglu_mlp_bwd)
 
 
-def _fused_gelu_mlp_ref(x, w1, b1, w2, b2):
-    p = _prec(x.dtype)
-    h1 = jax.lax.dot(x, w1.astype(x.dtype), precision=p,
-                     preferred_element_type=jnp.float32)
-    h1 = h1 + b1.astype(jnp.float32)
-    h = jax.nn.gelu(h1, approximate=False).astype(x.dtype)
-    y = jax.lax.dot(h, w2.astype(x.dtype), precision=p,
-                    preferred_element_type=jnp.float32)
-    return (y + b2.astype(jnp.float32)).astype(x.dtype)
-
-
-def _fused_gelu_mlp_impl(x, w1, b1, w2, b2):
-    from ...ops import dispatch as _dispatch
-    kernel = _dispatch.get("fused_gelu_mlp")
-    if kernel is not None:
-        out = kernel(x, w1.astype(x.dtype), b1, w2.astype(x.dtype), b2)
-        if out is not None:
-            return out
-    return _fused_gelu_mlp_ref(x, w1, b1, w2, b2)
-
-
-@jax.custom_vjp
-def fused_gelu_mlp(x, w1, b1, w2, b2):
-    """``gelu(x @ W1 + b1) @ W2 + b2`` in one pass (the GPT 4h FFN
-    analogue of :func:`fused_swiglu_mlp`)."""
-    return _fused_gelu_mlp_impl(x, w1, b1, w2, b2)
-
-
-def _fused_gelu_mlp_fwd(x, w1, b1, w2, b2):
-    return _fused_gelu_mlp_impl(x, w1, b1, w2, b2), (x, w1, b1, w2, b2)
-
-
-def _fused_gelu_mlp_bwd(res, ct):
-    _, vjp = jax.vjp(_fused_gelu_mlp_ref, *res)
-    return vjp(ct)
-
-
-fused_gelu_mlp.defvjp(_fused_gelu_mlp_fwd, _fused_gelu_mlp_bwd)
-
-
 def _fused_rms_rope_qkv_ref(x, norm_weight, w_q, w_k, w_v, cos, sin,
                             head_dim, eps):
     """XLA composition mirroring the fused_norm_qkv kernel: rms-norm in
@@ -583,55 +543,6 @@ def _attend_dense_gqa(q, k, v, context_lens, scale):
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_decode_attend(cache, q, new_k, new_v, block_tables, write_pos,
-                        scale: Optional[float] = None):
-    """One decode step against PAGED pools — the serving analogue of
-    :func:`decode_attend_cache`, sharing its cache-arity dispatch.
-
-    ``cache`` is the per-layer pool tuple: fp ``(k, v)`` with shape
-    ``(num_blocks, page, H_kv, D)``, or int8-quantized
-    ``(k_i8, v_i8, k_scale, v_scale)`` with ``(num_blocks, page, H_kv)``
-    f32 scales (the :func:`quantize_kv` formula, same as the dense
-    4-tuple caches).  ``write_pos`` (B,) is the new token's position —
-    i.e. the number of tokens already cached; the step writes this
-    token's ``(B, H_kv, D)`` k/v at that position and attends over
-    ``write_pos + 1`` tokens.
-
-    A slot whose block-table entries are out of range (the serving
-    scheduler's inactive-slot sentinel) drops its write (out-of-bounds
-    scatter) and produces a garbage-but-finite output the caller
-    discards — nothing a dead slot does can corrupt live blocks.
-
-    Returns ``(out, new_cache)``.
-    """
-    bs = cache[0].shape[1]
-    d = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    ctx = write_pos + 1
-    if len(cache) == 4:
-        kc, vc, ks, vs = cache
-        blk = jnp.take_along_axis(block_tables, (write_pos // bs)[:, None],
-                                  axis=1)[:, 0]
-        off = write_pos % bs
-        k_q, ks_new = quantize_kv(new_k)
-        v_q, vs_new = quantize_kv(new_v)
-        kc = kc.at[blk, off].set(k_q)
-        vc = vc.at[blk, off].set(v_q)
-        ks = ks.at[blk, off].set(ks_new)
-        vs = vs.at[blk, off].set(vs_new)
-        # int8 pools attend through the XLA gather+dequant formulation on
-        # every backend: the Pallas kernel is fp-only, and int8 halves
-        # the gathered bytes, which is the traffic that matters
-        kd, vd = _paged_gather_dense(kc, vc, block_tables, ks, vs)
-        out = _attend_dense_gqa(q, kd, vd, ctx, scale)
-        return out, (kc, vc, ks, vs)
-    kc, vc = cache
-    kc, vc = write_paged_kv(kc, vc, new_k.astype(kc.dtype),
-                            new_v.astype(vc.dtype), block_tables, ctx)
-    out = paged_attention(q, kc, vc, block_tables, ctx, scale=scale)
-    return out, (kc, vc)
-
-
 def _paged_span_write(cache, k, v, block_tables, span_starts, span_lens):
     """Scatter a token span ``k``/``v`` (B, C, H_kv, D) into the paged
     pools at positions ``[span_starts, span_starts + span_lens)`` of each
@@ -657,17 +568,6 @@ def _paged_span_write(cache, k, v, block_tables, span_starts, span_lens):
     kc, vc = cache
     return (kc.at[blk, off].set(k.astype(kc.dtype)),
             vc.at[blk, off].set(v.astype(vc.dtype)))
-
-
-def paged_prefill_write(cache, k, v, block_tables, prompt_lens):
-    """Scatter a prefill chunk ``k``/``v`` (B, S, H_kv, D) into the paged
-    pools at positions ``[0, prompt_lens)`` of each sequence — the
-    span write with every span starting at position 0 (the legacy
-    bucket-prefill path; the ragged serving step uses
-    :func:`ragged_paged_attend`)."""
-    b = k.shape[0]
-    return _paged_span_write(cache, k, v, block_tables,
-                             jnp.zeros((b,), jnp.int32), prompt_lens)
 
 
 def _ragged_attend_dense(q, k, v, span_starts, scale):
@@ -696,9 +596,9 @@ def _ragged_attend_dense(q, k, v, span_starts, scale):
 
 def ragged_paged_attend(cache, q, new_k, new_v, block_tables, span_starts,
                         span_lens, scale: Optional[float] = None):
-    """ONE serving step for a ragged batch of token spans — the unified
-    replacement for the separate :func:`paged_decode_attend` /
-    bucket-prefill dispatches (PAPERS.md "Ragged Paged Attention").
+    """ONE serving step for a ragged batch of token spans: prefill
+    chunks and decode tokens in one dispatch (PAPERS.md "Ragged Paged
+    Attention").
 
     Each slot ``b`` carries a span of ``span_lens[b]`` tokens starting at
     pool position ``span_starts[b]``: a chunked-prefill segment
@@ -718,6 +618,10 @@ def ragged_paged_attend(cache, q, new_k, new_v, block_tables, span_starts,
 
     Returns ``(out (B, C, H, D), new_cache)``.
     """
+    if span_starts is None:
+        raise ValueError(
+            "paged KV pools (block_tables) are served by the ragged step "
+            "only: pass span_starts with them")
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     new_cache = _paged_span_write(cache, new_k, new_v, block_tables,
@@ -737,114 +641,6 @@ def ragged_paged_attend(cache, q, new_k, new_v, block_tables, span_starts,
             return out, new_cache
     kd, vd = _paged_gather_dense(kc, vc, block_tables)
     return _ragged_attend_dense(q, kd, vd, span_starts, scale), new_cache
-
-
-def _mega_decode_layer_ref(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
-                           cache, block_tables, span_starts, span_lens,
-                           head_dim, eps, scale):
-    """THE megakernel's numerical contract: the existing fused
-    entry points chained — :func:`fused_rms_rope_qkv` →
-    :func:`ragged_paged_attend` → O-proj (f32 accumulation, x.dtype
-    rounding exactly where the kernel rounds) → residual add.  This is
-    what runs on CPU, under meshes, for int8 KV pools (whose
-    gather+dequant lives inside :func:`ragged_paged_attend`), and
-    wherever ``mega_decode.supported()`` declines; the interpret-mode
-    equivalence tests (tests/test_mega_decode.py) pin the Pallas kernel
-    to it."""
-    b, c, h = x.shape
-    q, k, v = fused_rms_rope_qkv(
-        x.reshape(b * c, h), norm_weight, w_q, w_k, w_v,
-        cos.reshape(b * c, head_dim), sin.reshape(b * c, head_dim),
-        head_dim, eps)
-    nh = q.shape[-1] // head_dim
-    nkh = k.shape[-1] // head_dim
-    attn, new_cache = ragged_paged_attend(
-        cache, q.reshape(b, c, nh, head_dim),
-        k.reshape(b, c, nkh, head_dim), v.reshape(b, c, nkh, head_dim),
-        block_tables, span_starts, span_lens, scale=scale)
-    p = _prec(x.dtype)
-    y = jax.lax.dot(attn.reshape(b * c, nh * head_dim),
-                    w_o.astype(x.dtype), precision=p,
-                    preferred_element_type=jnp.float32)
-    return x + y.astype(x.dtype).reshape(b, c, h), new_cache
-
-
-def _mega_decode_layer_impl(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
-                            cache, block_tables, span_starts, span_lens,
-                            head_dim, eps, scale):
-    from ...ops import dispatch as _dispatch
-    kernel = _dispatch.get("mega_decode_layer")
-    if kernel is not None and len(cache) == 2:
-        xd = x.dtype
-        res = kernel(x, norm_weight, w_q.astype(xd), w_k.astype(xd),
-                     w_v.astype(xd), w_o.astype(xd), cos, sin,
-                     cache[0], cache[1], block_tables, span_starts,
-                     span_lens, head_dim, eps, scale=scale)
-        if res is not None:
-            out, k_new, v_new = res
-            # the pool scatter stays the ONE shared _paged_span_write
-            # (same OOB dead-slot drop, same dtype rounding) — the
-            # kernel only computes the span k/v, it never touches the
-            # pools' write path
-            nkh = k_new.shape[-1] // head_dim
-            b, c = k_new.shape[:2]
-            new_cache = _paged_span_write(
-                cache, k_new.reshape(b, c, nkh, head_dim),
-                v_new.reshape(b, c, nkh, head_dim), block_tables,
-                span_starts, span_lens)
-            return out, new_cache
-    return _mega_decode_layer_ref(x, norm_weight, w_q, w_k, w_v, w_o,
-                                  cos, sin, cache, block_tables,
-                                  span_starts, span_lens, head_dim, eps,
-                                  scale)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(12, 13, 14))
-def mega_decode_layer(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
-                      cache, block_tables, span_starts, span_lens,
-                      head_dim, eps=1e-5, scale=None):
-    """One decoder layer's whole ragged attention block —
-    rms_norm → q/k/v projections → rotate-half rope → ragged paged
-    attention (span write included) → O-proj → residual — as ONE entry
-    point, dispatching to the decode megakernel on TPU
-    (ops/pallas/mega_decode.py: one Pallas dispatch per layer,
-    activations VMEM-resident between stages) and running the pinned
-    XLA composition everywhere else.
-
-    x: (B, C, H) residual-stream span batch (UN-normed; the rms-norm
-    happens inside); norm_weight: (H,); w_q: (H, Nq); w_k/w_v: (H, Nk);
-    w_o: (Nq, H); cos/sin: (B, C, head_dim) per-slot rope tables;
-    ``cache``/``block_tables``/``span_starts``/``span_lens`` exactly as
-    :func:`ragged_paged_attend`.  Returns ``(x + o_proj(attend),
-    new_cache)``.  The custom VJP recomputes through the composition
-    (the library's remat recipe) — and makes the whole block ONE closed
-    call in the traced step, which is what the Engine's
-    ``dispatches_per_step`` gauge counts.
-    """
-    return _mega_decode_layer_impl(x, norm_weight, w_q, w_k, w_v, w_o,
-                                   cos, sin, cache, block_tables,
-                                   span_starts, span_lens, head_dim, eps,
-                                   scale)
-
-
-def _mega_decode_layer_fwd(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
-                           cache, block_tables, span_starts, span_lens,
-                           head_dim, eps, scale):
-    out = _mega_decode_layer_impl(x, norm_weight, w_q, w_k, w_v, w_o,
-                                  cos, sin, cache, block_tables,
-                                  span_starts, span_lens, head_dim, eps,
-                                  scale)
-    return out, (x, norm_weight, w_q, w_k, w_v, w_o, cos, sin, cache,
-                 block_tables, span_starts, span_lens)
-
-
-def _mega_decode_layer_bwd(head_dim, eps, scale, res, ct):
-    _, vjp = jax.vjp(
-        lambda *a: _mega_decode_layer_ref(*a, head_dim, eps, scale), *res)
-    return vjp(ct)
-
-
-mega_decode_layer.defvjp(_mega_decode_layer_fwd, _mega_decode_layer_bwd)
 
 
 def paged_copy_blocks(cache, src_blocks, dst_blocks):

@@ -54,12 +54,6 @@ class GPTConfig:
     pipeline_stages: int = 1
     num_microbatches: Optional[int] = None
     virtual_pp_degree: int = 1
-    # fused-kernel library (docs/KERNELS.md): GPT's qkv is already one
-    # matmul and its norm is LayerNorm (no fused-rms op applies), so the
-    # flag routes the 4h GELU FFN through incubate.fused_gelu_mlp — the
-    # Pallas fused-MLP kernel on TPU, the same-numerics XLA composition
-    # elsewhere.  "auto" fuses only where a kernel will serve.
-    fused_ops: str = "auto"
     dtype: str = "float32"
 
     @property
@@ -151,28 +145,9 @@ class GPTAttention(Layer):
         b, s = q.shape[:2]
         if cache is not None and block_tables is not None:
             # paged KV pools (serving.Engine) — see LlamaAttention
-            from ..incubate.nn.functional import (paged_decode_attend,
-                                                  paged_prefill_write,
-                                                  ragged_paged_attend)
-            if span_starts is not None:
-                # unified ragged step — see LlamaAttention
-                out, new_cache = ragged_paged_attend(
-                    cache, q, k, v, block_tables, span_starts, seq_lens)
-                out = out.reshape(b, s, cfg.hidden_size)
-                return out, new_cache
-            if s == 1 and seq_lens is not None:
-                out, new_cache = paged_decode_attend(
-                    cache, q[:, 0], k[:, 0], v[:, 0], block_tables,
-                    seq_lens)
-                out = out[:, None].reshape(b, s, cfg.hidden_size)
-                return out, new_cache
-            plens = seq_lens if seq_lens is not None else \
-                jnp.full((b,), s, jnp.int32)
-            new_cache = paged_prefill_write(cache, k, v, block_tables,
-                                            plens)
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True,
-                dropout_p=cfg.attention_dropout, training=self.training)
+            from ..incubate.nn.functional import ragged_paged_attend
+            out, new_cache = ragged_paged_attend(
+                cache, q, k, v, block_tables, span_starts, seq_lens)
             out = out.reshape(b, s, cfg.hidden_size)
             return out, new_cache
         if cache is not None and s == 1 and seq_lens is not None:
@@ -218,10 +193,6 @@ class GPTMLP(Layer):
             return self._forward(x, lora)
 
     def _forward(self, x, lora):
-        cfg = self.cfg
-        from .llama import _use_fused
-        from ..ops.tuning import geom_key
-
         if lora is not None:
             # multi-LoRA: the fc_out delta needs the GELU intermediate,
             # so the LoRA path pins the unfused FFN composition
@@ -236,24 +207,6 @@ class GPTMLP(Layer):
             d2 = lora_delta(lora, h, "mlp.fc_out")
             return self.dropout(y if d2 is None else y + d2)
 
-        def _kernel_serves():
-            from ..ops.pallas import fused_mlp as _fm
-            return _fm.supported(x.reshape(-1, cfg.hidden_size),
-                                 self.fc_in.weight, self.fc_out.weight,
-                                 op="fused_gelu_mlp")
-
-        if _use_fused(cfg, "fused_gelu_mlp",
-                      geom_key(h=cfg.hidden_size, i=cfg.ffn_size),
-                      probe=_kernel_serves,
-                      layers=(self.fc_in, self.fc_out)):
-            # one pass over the FFN weights (incubate fused entry —
-            # Pallas kernel on TPU, XLA composition elsewhere)
-            from ..incubate.nn.functional import fused_gelu_mlp
-            lead = x.shape[:-1]
-            y = fused_gelu_mlp(x.reshape(-1, cfg.hidden_size),
-                               self.fc_in.weight, self.fc_in.bias,
-                               self.fc_out.weight, self.fc_out.bias)
-            return self.dropout(y.reshape(*lead, cfg.hidden_size))
         return self.dropout(self.fc_out(F.gelu(self.fc_in(x))))
 
 

@@ -55,19 +55,10 @@ class LlamaConfig:
     num_microbatches: Optional[int] = None  # default: pipeline_stages
     virtual_pp_degree: int = 1      # interleaved-schedule chunks per stage
     loss_seq_chunks: int = 1        # >1: rematerialized seq-chunked vocab CE
-    fuse_qkv_mlp: bool = False      # trace-time concat of qkv / gate+up kernels
-    # fused-kernel library (docs/KERNELS.md): "on" routes norm+rope+qkv
-    # and the swiglu MLP through incubate's fused entry points (Pallas
-    # kernels on TPU, the equivalent XLA composition elsewhere); "mega"
-    # is "on" plus the decode megakernel — the whole decoder-layer
-    # attention block (norm→qkv→rope→ragged attention→o_proj+residual)
-    # as ONE dispatch on the ragged serving step
-    # (ops/pallas/mega_decode.py; XLA composition off-TPU and wherever
-    # its supported() gate declines); "auto" fuses only where a kernel
-    # will actually serve (TPU, no mesh, not vetoed by
-    # tools/tuned_configs.json) so CPU behavior is unchanged; "off"
-    # keeps the unfused projections.  Takes precedence over
-    # fuse_qkv_mlp where both apply.
+    # fused-kernel library (docs/KERNELS.md): "on" takes incubate's fused
+    # norm+rope+qkv and swiglu-MLP entries everywhere (Pallas kernel on TPU,
+    # same-numerics XLA composition elsewhere); "auto" only where the kernel
+    # will serve (TPU, no mesh, supported(), no tuned veto); "off" never
     fused_ops: str = "auto"
     dtype: str = "float32"
 
@@ -230,19 +221,6 @@ class LlamaAttention(Layer):
             k = k.reshape(b, s, cfg.num_key_value_heads, hd)
             v = v.reshape(b, s, cfg.num_key_value_heads, hd)
             roped = True
-        elif cfg.fuse_qkv_mlp and not cfg.sequence_parallel:
-            # one [h, h+2kv] matmul instead of three — parameters stay
-            # separate (HF import / TP specs untouched); the concat is a
-            # cheap trace-time reshuffle XLA schedules once per step
-            h_out = cfg.num_attention_heads * cfg.head_dim
-            kv = cfg.num_key_value_heads * cfg.head_dim
-            w = jnp.concatenate([self.q_proj.weight, self.k_proj.weight,
-                                 self.v_proj.weight], axis=1)
-            qkv = x @ w.astype(x.dtype)
-            q, k, v = jnp.split(qkv, [h_out, h_out + kv], axis=-1)
-            q = q.reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
-            k = k.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-            v = v.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
         else:
             q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
             if lora is not None:
@@ -276,35 +254,13 @@ class LlamaAttention(Layer):
         if cache is not None and block_tables is not None:
             # paged KV pools (serving.Engine): the cache is the GLOBAL
             # (num_blocks, page, H_kv, D) pool pair (or int8 4-tuple),
-            # addressed through this batch's block tables
-            from ..incubate.nn.functional import (paged_decode_attend,
-                                                  paged_prefill_write,
-                                                  ragged_paged_attend)
-            if span_starts is not None:
-                # unified ragged step: each slot's span (prefill chunk
-                # or decode token) writes at [start, start+len) and
-                # every row attends its causal prefix — one dispatch
-                # for the whole mixed batch
-                out, new_cache = ragged_paged_attend(
-                    cache, q, k, v, block_tables, span_starts, seq_lens)
-                out = out.reshape(
-                    b, s, cfg.num_attention_heads * cfg.head_dim)
-                return out, new_cache
-            if s == 1 and seq_lens is not None:
-                out, new_cache = paged_decode_attend(
-                    cache, q[:, 0], k[:, 0], v[:, 0], block_tables,
-                    seq_lens)
-                out = out[:, None].reshape(
-                    b, s, cfg.num_attention_heads * cfg.head_dim)
-                return out, new_cache
-            # paged prefill: causal attention over the (bucket-padded)
-            # prompt; pages written only at positions < seq_lens, so
-            # padding rows never land in the pool
-            plens = seq_lens if seq_lens is not None else \
-                jnp.full((b,), s, jnp.int32)
-            new_cache = paged_prefill_write(cache, k, v, block_tables,
-                                            plens)
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
+            # addressed through this batch's block tables.  The unified
+            # ragged step: each slot's span (prefill chunk or decode
+            # token) writes at [start, start+len) and every row attends
+            # its causal prefix — one dispatch for the whole mixed batch
+            from ..incubate.nn.functional import ragged_paged_attend
+            out, new_cache = ragged_paged_attend(
+                cache, q, k, v, block_tables, span_starts, seq_lens)
             out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
             return out, new_cache
         if cache is not None and s == 1 and seq_lens is not None:
@@ -403,12 +359,6 @@ class LlamaMLP(Layer):
                                  self.up_proj.weight,
                                  self.down_proj.weight)
             return y.reshape(*lead, cfg.hidden_size)
-        if cfg.fuse_qkv_mlp and not cfg.sequence_parallel:
-            w = jnp.concatenate([self.gate_proj.weight, self.up_proj.weight],
-                                axis=1)
-            gu = x @ w.astype(x.dtype)
-            g, u = jnp.split(gu, 2, axis=-1)
-            return self.down_proj(F.swiglu(g, u))
         return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
 
 
@@ -450,101 +400,32 @@ class LlamaDecoderLayer(Layer):
             return x, self.input_layernorm.weight
         return self.input_layernorm(x), None
 
-    def _use_mega(self, x, cache) -> bool:
-        """Trace-time gate for the decode megakernel (the whole
-        attention block as one dispatch — ops/pallas/mega_decode.py).
-        ``"mega"`` always takes the entry point (which still falls back
-        to its XLA composition where the kernel cannot serve, e.g. int8
-        KV pools); ``"auto"`` takes it only when the kernel will
-        actually run — dispatch live AND ``supported()`` accepting this
-        geometry, pool and VMEM footprint.  Quantized projections and
-        sequence parallel step aside inside ``_use_fused``; the LoRA
-        path never reaches here (the caller pins unfused)."""
-        cfg = self.cfg
-        mode = getattr(cfg, "fused_ops", "off")
-        if mode not in ("mega", "auto"):
-            return False
-        from ..ops.tuning import geom_key
-        hd = cfg.head_dim
-        key = geom_key(h=cfg.hidden_size,
-                       nq=cfg.num_attention_heads * hd,
-                       nk=cfg.num_key_value_heads * hd, hd=hd)
-        attn = self.self_attn
-
-        def _kernel_serves():
-            from ..ops.pallas import mega_decode as _md
-            return _md.supported(x, attn.q_proj.weight,
-                                 attn.k_proj.weight, attn.o_proj.weight,
-                                 hd, cache=cache)
-
-        return _use_fused(cfg, "mega_decode_layer", key,
-                          probe=_kernel_serves,
-                          layers=(attn.q_proj, attn.k_proj, attn.v_proj,
-                                  attn.o_proj))
-
     def forward(self, x, cos, sin, attn_mask=None, cache=None,
                 seq_lens=None, block_tables=None, span_starts=None,
                 lora=None):
-        if cache is not None:
-            if (span_starts is not None and block_tables is not None
-                    and lora is None and self._use_mega(x, cache)):
-                # decode megakernel: the whole attention block — norm →
-                # qkv → rope → ragged paged attention → o_proj +
-                # residual — as ONE entry point (one Pallas dispatch on
-                # TPU, the pinned XLA composition elsewhere)
-                from ..incubate.nn.functional import mega_decode_layer
-                cfg = self.cfg
-                b, s = x.shape[:2]
-                hd = cfg.head_dim
-                if cos.ndim == 2:
-                    cos2 = jnp.broadcast_to(cos[None], (b, s, hd))
-                    sin2 = jnp.broadcast_to(sin[None], (b, s, hd))
-                else:
-                    cos2, sin2 = cos, sin
-                attn = self.self_attn
-                # one dispatch holds norm, projections and the core; the
-                # core (the live pages' read) is what its time follows
-                with region("attn_core"):
-                    x, cache = mega_decode_layer(
-                        x, self.input_layernorm.weight, attn.q_proj.weight,
-                        attn.k_proj.weight, attn.v_proj.weight,
-                        attn.o_proj.weight, cos2, sin2, cache, block_tables,
-                        span_starts, seq_lens, hd, cfg.rms_norm_eps)
-                h = self.mlp(self.post_attention_layernorm(x), lora=lora)
-                with region("mlp"):
-                    x = x + h
-                return x, cache
-            if lora is None:
-                attn_in, nw = self._attn_input(x)
-            else:
-                # LoRA deltas inject pre-RoPE at the projection outputs,
-                # which the fused norm→qkv→rope single pass cannot
-                # expose — the multi-LoRA engine pins the unfused path
-                attn_in, nw = self.input_layernorm(x), None
-            attn, cache = self.self_attn(attn_in, cos, sin,
-                                         attn_mask, cache=cache,
-                                         seq_lens=seq_lens,
-                                         block_tables=block_tables,
-                                         span_starts=span_starts,
-                                         norm_weight=nw, lora=lora)
-            with region("attn_proj"):
-                x = x + attn
-            h = self.mlp(self.post_attention_layernorm(x), lora=lora)
-            with region("mlp"):
-                x = x + h
-            return x, cache
         # regions (observability/regions.py): each block opens its own;
         # the residual adds sit in their block's region too, since XLA
         # fuses them into its last matmul and a fusion carries its root's
         # path
-        attn_in, nw = self._attn_input(x)
-        attn = self.self_attn(attn_in, cos, sin, attn_mask, norm_weight=nw)
+        if lora is None:
+            attn_in, nw = self._attn_input(x)
+        else:
+            # LoRA deltas inject pre-RoPE at the projection outputs,
+            # which the fused norm→qkv→rope single pass cannot
+            # expose — the multi-LoRA engine pins the unfused path
+            attn_in, nw = self.input_layernorm(x), None
+        attn = self.self_attn(attn_in, cos, sin, attn_mask, cache=cache,
+                              seq_lens=seq_lens, block_tables=block_tables,
+                              span_starts=span_starts, norm_weight=nw,
+                              lora=lora)
+        if cache is not None:
+            attn, cache = attn
         with region("attn_proj"):
             x = x + attn
-        h = self.mlp(self.post_attention_layernorm(x))
+        h = self.mlp(self.post_attention_layernorm(x), lora=lora)
         with region("mlp"):
             x = x + h
-        return x
+        return x if cache is None else (x, cache)
 
 
 class LlamaModel(Layer):

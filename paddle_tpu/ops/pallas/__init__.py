@@ -118,16 +118,6 @@ dispatch.register("fused_swiglu_mlp", _fused_swiglu_dispatch,
                   platform="tpu")
 
 
-def _fused_gelu_dispatch(x, w1, b1, w2, b2):
-    if _active_mesh() is not None \
-            or not _fm.supported(x, w1, w2, op="fused_gelu_mlp"):
-        return None
-    return _fm.fused_gelu_mlp(x, w1, b1, w2, b2)
-
-
-dispatch.register("fused_gelu_mlp", _fused_gelu_dispatch, platform="tpu")
-
-
 def _fused_rms_rope_qkv_dispatch(x, norm_weight, w_q, w_k, w_v, cos, sin,
                                  head_dim, eps):
     if _active_mesh() is not None \
@@ -152,24 +142,6 @@ def _fused_adamw_dispatch(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
 
 
 dispatch.register("fused_adamw", _fused_adamw_dispatch, platform="tpu")
-
-from . import mega_decode as _md
-
-
-def _mega_decode_layer_dispatch(x, norm_weight, w_q, w_k, w_v, w_o, cos,
-                                sin, k_pool, v_pool, block_tables, starts,
-                                lens, head_dim, eps, scale=None):
-    if _active_mesh() is not None \
-            or not _md.supported(x, w_q, w_k, w_o, head_dim,
-                                 cache=(k_pool, v_pool)):
-        return None
-    return _md.mega_decode(x, norm_weight, w_q, w_k, w_v, w_o, cos, sin,
-                           k_pool, v_pool, block_tables, starts, lens,
-                           head_dim=head_dim, eps=eps, scale=scale)
-
-
-dispatch.register("mega_decode_layer", _mega_decode_layer_dispatch,
-                  platform="tpu")
 
 from . import lora_matmul as _lora
 

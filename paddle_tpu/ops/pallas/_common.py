@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 # Mosaic grants a kernel 16 MiB of scoped VMEM unless it asks for more;
 # a v5e core has 128 MiB.  The weight-resident kernels (fused_mlp,
-# fused_norm_qkv, mega_decode) ask for VMEM_LIMIT, and their
+# fused_norm_qkv) ask for VMEM_LIMIT, and their
 # ``supported()`` gates admit only block geometries whose estimated
 # allocation fits VMEM_BUDGET — the gap is headroom for the internal
 # scratch the estimates do not model (measured 1-5 MiB by deviceless

@@ -19,9 +19,7 @@ TPU-native design:
   exactly once, the intermediate never leaves VMEM;
 - the x tile's BlockSpec index is constant across the inner axis, so
   Pallas elides its re-fetch (one HBM read of the hidden states per
-  token tile);
-- two variants share the structure: ``swiglu`` (separate gate/up
-  weights, Llama) and ``gelu`` (single weight + bias, GPT's 4h FFN).
+  token tile).
 
 Block shapes come from tools/tuned_configs.json (ops.tuning, resolved at
 trace time) with safe defaults; sweep with ``python tools/autotune.py``.
@@ -73,35 +71,13 @@ def _swiglu_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_scr,
         o_ref[...] = acc_scr[...].astype(out_dtype)
 
 
-def _gelu_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref, acc_scr,
-                 *, i_blocks, out_dtype):
-    ii = pl.program_id(1)
-
-    @pl.when(ii == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    x = x_ref[...]
-    prec = _precision(x.dtype)
-    h1 = jax.lax.dot(x, w1_ref[...], precision=prec,
-                     preferred_element_type=jnp.float32)
-    h1 = h1 + b1_ref[...].astype(jnp.float32)
-    h = jax.nn.gelu(h1, approximate=False).astype(x.dtype)
-    acc_scr[...] += jax.lax.dot(h, w2_ref[...], precision=prec,
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(ii == i_blocks - 1)
-    def _emit():
-        o_ref[...] = (acc_scr[...]
-                      + b2_ref[...].astype(jnp.float32)).astype(out_dtype)
-
-
-def _blocks(t, h, i, block_t, block_i, itemsize, op="fused_swiglu_mlp"):
+def _blocks(t, h, i, block_t, block_i, itemsize):
     """Resolve (bt, bi) — explicit args win, then tuned configs (trace
     time, ops.tuning), then defaults shrunk to the VMEM budget."""
     cfg = {}
     if block_t is None or block_i is None:
-        cfg = tuning.tuned_config(op, tuning.geom_key(h=h, i=i))
+        cfg = tuning.tuned_config("fused_swiglu_mlp",
+                                  tuning.geom_key(h=h, i=i))
     # the token axis is padded up to a block multiple (zeros, sliced off
     # after), so bt only needs sublane alignment — odd T is fine
     bt = max(8, (block_t or cfg.get("block_t", DEFAULT_BLOCK_T)) // 8 * 8)
@@ -170,53 +146,9 @@ def fused_swiglu_mlp(x, w_gate, w_up, w_down, block_t=None, block_i=None,
     return out[:t]
 
 
-def fused_gelu_mlp(x, w1, b1, w2, b2, block_t=None, block_i=None,
-                   interpret: bool = False):
-    """``gelu(x @ W1 + b1) @ W2 + b2`` in one kernel pass (GPT FFN).
-
-    x: (T, H); w1: (H, F); b1: (F,); w2: (F, H); b2: (H,).
-    """
-    t, h = x.shape
-    f = w1.shape[1]
-    bt, bi = _blocks(t, h, f, block_t, block_i, x.dtype.itemsize,
-                     op="fused_gelu_mlp")
-    xp = _pad_tokens(x, bt)
-    tp = xp.shape[0]
-    i_blocks = f // bi
-    out = pl.pallas_call(
-        functools.partial(_gelu_kernel, i_blocks=i_blocks,
-                          out_dtype=x.dtype),
-        grid=(tp // bt, i_blocks),
-        in_specs=[
-            pl.BlockSpec((bt, h), lambda it, ii: (it, 0)),
-            pl.BlockSpec((h, bi), lambda it, ii: (0, ii)),
-            pl.BlockSpec((1, bi), lambda it, ii: (0, ii)),
-            pl.BlockSpec((bi, h), lambda it, ii: (ii, 0)),
-            pl.BlockSpec((1, h), lambda it, ii: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, h), lambda it, ii: (it, 0)),
-        out_shape=jax.ShapeDtypeStruct((tp, h), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bt, h), jnp.float32)],
-        compiler_params=_pcp()(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT),
-        interpret=interpret,
-        name="fused_gelu_mlp",
-    )(xp, w1, b1.reshape(1, f), w2, b2.reshape(1, h))
-    return out[:t]
-
-
-def supported(x, w1, w2, op: str = "fused_swiglu_mlp") -> bool:
-    """Mosaic-shape gate shared by both variants: 128-aligned H/I, fp
-    dtypes, and block geometry inside the VMEM budget.  ``op`` selects
-    whose tuned-config table the block estimate resolves against — the
-    gate must agree with the blocks the kernel will actually use."""
-    if op == "fused_gelu_mlp":
-        # the installed Pallas TPU lowering has no rule for erf/erfc, so
-        # the exact-gelu kernel cannot compile for the chip at any
-        # shape; GPT's FFN keeps the XLA composition (the kernel's
-        # arithmetic stays pinned by its interpret-mode test)
-        return False
+def supported(x, w1, w2) -> bool:
+    """Mosaic-shape gate: 128-aligned H/I, fp dtypes, and block geometry
+    inside the VMEM budget — the blocks the kernel will actually use."""
     if x.ndim != 2 or w1.ndim != 2 or w2.ndim != 2:
         return False
     h, i = w1.shape
@@ -225,5 +157,5 @@ def supported(x, w1, w2, op: str = "fused_swiglu_mlp") -> bool:
     if x.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     bt, bi = _blocks(max(x.shape[0], 8), h, i, None, None,
-                     x.dtype.itemsize, op=op)
+                     x.dtype.itemsize)
     return _vmem_estimate(bt, bi, h, x.dtype.itemsize) <= VMEM_BUDGET

@@ -92,19 +92,16 @@ def fusion_enabled(mode: str, op: str, key: Optional[str] = None) -> bool:
 
     ``"off"`` → never; ``"on"`` → always (the entry point still falls
     back to its XLA composition where the kernel cannot serve);
-    ``"mega"`` → ``"on"`` plus the decode megakernel on the ragged
-    serving step (``ops/pallas/mega_decode.py`` — same always-with-
-    fallback semantics); ``"auto"`` → only when the kernel dispatch is
-    live (TPU backend, no active mesh, ``use_pallas_kernels`` flag) AND
-    the tuned configs do not veto it (``{"enabled": false}`` recorded by
-    the autotuner when the sweep measured the fusion as a loss for this
-    geometry)."""
+    ``"auto"`` → only when the kernel dispatch is live (TPU backend, no
+    active mesh, ``use_pallas_kernels`` flag) AND the tuned configs do
+    not veto it (``{"enabled": false}`` recorded by the autotuner when
+    the sweep measured the fusion as a loss for this geometry)."""
     if mode == "off" or not mode:
         return False
-    if mode in ("on", "mega"):
+    if mode == "on":
         return True
     if mode != "auto":
-        raise ValueError(f"fused_ops={mode!r}: expected on|off|auto|mega")
+        raise ValueError(f"fused_ops={mode!r}: expected on|off|auto")
     from . import dispatch
     if dispatch.get(op) is None:
         return False

@@ -485,9 +485,6 @@ class Engine:
         # _publish_compiled_obs when the compiled-artifact ledger is
         # active; None keeps the disabled path at one falsy check)
         self._roofline_min_ms: Optional[Dict[str, float]] = None
-        # structural dispatch count of the one step program (filled
-        # lazily by dispatches_per_step — an abstract trace, no compile)
-        self._dispatches_per_step: Optional[int] = None
         self._build_fns()
 
     # -- compiled paths ----------------------------------------------------
@@ -543,7 +540,6 @@ class Engine:
             return [paged_copy_blocks(c, src, dst) for c in caches]
 
         # pools are donated: the engine owns exactly one copy in HBM
-        self._step_fn_raw = step_fn   # for dispatches_per_step's trace
         self._step_fn = jax.jit(step_fn, donate_argnums=(1,))
         self._cow_fn = jax.jit(cow_fn, donate_argnums=(0,))
 
@@ -653,33 +649,6 @@ class Engine:
                 default=0)
         return stats
 
-    def dispatches_per_step(self) -> int:
-        """Structural dispatch count of the ONE serving step: the number
-        of top-level equations in the traced step program.  The fused
-        entry points (custom_vjp-wrapped — ``fused_rms_rope_qkv``,
-        ``fused_swiglu_mlp``, and the whole-layer ``mega_decode_layer``)
-        close over their internals and count as ONE equation each,
-        mirroring their one-dispatch lowering on TPU; XLA may still fuse
-        neighboring elementwise equations off-chip, so this is a
-        structural proxy (program shape, not measured kernel launches)
-        — which is exactly what makes the mega-vs-on-vs-off A/B honest
-        on CPU.  Pure abstract trace: nothing compiles, the recompile
-        sentinel never fires.  Cached after the first call."""
-        if self._dispatches_per_step is None:
-            b, mb, c = self.max_batch, self.max_blocks_per_seq, \
-                self.prefill_chunk
-            oob = jnp.asarray(np.full((b, mb), self.kv.oob_block,
-                                      np.int32))
-            zi = jnp.asarray(np.zeros((b,), np.int32))
-            with self._trace_mesh():
-                jaxpr = jax.make_jaxpr(self._step_fn_raw)(
-                    self.params, self.kv.caches,
-                    jnp.asarray(np.zeros((b, c), np.int32)), oob,
-                    zi, zi, jnp.asarray(np.zeros((b,), np.float32)),
-                    self._key, zi, zi, self._lora_stacks(), zi)
-            self._dispatches_per_step = len(jaxpr.jaxpr.eqns)
-        return self._dispatches_per_step
-
     def _publish_compiled_obs(self) -> None:
         """Post-warmup: the ``serve.hbm.*`` gauge block and per-program
         analytic roofline minimums (``serve.roofline.<prog>.min_ms``)
@@ -695,15 +664,9 @@ class Engine:
             # picture survives even after the engine is gone
             led.set_hbm(hbm)
             mins: Dict[str, float] = {}
-            pairs = [("step", "serve.step"), ("cow", "serve.cow"),
-                     ("swap", "serve.swap"), ("lora", "serve.lora")]
-            if getattr(getattr(self.model, "cfg", None), "fused_ops",
-                       None) == "mega":
-                # the megakernel step's roofline row, tagged so A/B
-                # dashboards overlay mega-on vs mega-off engines
-                # without aliasing the plain step row
-                pairs.append(("step.mega", "serve.step"))
-            for key, site in pairs:
+            for key, site in (("step", "serve.step"), ("cow", "serve.cow"),
+                              ("swap", "serve.swap"),
+                              ("lora", "serve.lora")):
                 m = led.min_ms_for(site)
                 if m:
                     mins[key] = m
@@ -711,8 +674,6 @@ class Engine:
         if reg is not None:
             for k, v in hbm.items():
                 reg.gauge(f"serve.hbm.{k}").set(v)
-            reg.gauge("serve.dispatches_per_step").set(
-                self.dispatches_per_step())
             for key, m in (self._roofline_min_ms or {}).items():
                 reg.gauge(f"serve.roofline.{key}.min_ms").set(round(m, 6))
 

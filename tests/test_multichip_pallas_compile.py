@@ -13,7 +13,9 @@ a ``v5e:2x2`` topology that is described, not attached.  Three groups:
   gate must decline (VMEM limits, operand types: none of it shows in
   interpret mode);
 - the two programs ``chip_smoke.py`` runs on the chip, built by its own
-  builders: the 7B-width ``TrainStep`` and the engine's ragged step.
+  builders: the 7B-width ``TrainStep`` and the engine's ragged step;
+- the step of each benchmark cell, built from the cell's own files under
+  ``benchmark/`` at its own widths and shapes: which kernels are in it.
 
 Nothing runs, so nothing here says a result is right or fast.  The
 topology is described inside a module-scoped fixture: only the xdist
@@ -21,9 +23,12 @@ worker that is handed this file loads libtpu, and it skips where the
 topology cannot be described.  Keep every such test in THIS file.
 """
 
+import importlib
+import json
 import math
 import os
 import re
+import sys
 
 import pytest
 
@@ -36,9 +41,9 @@ pytestmark = pytest.mark.skipif(
 
 # Llama-2-7B widths (models/llama.py PRESETS["llama2-7b"])
 H, I, NH, HD = 4096, 11008, 32, 128
-# llama-350m-hd128: the widest preset whose weights the two
-# weight-resident kernels (fused_norm_qkv, mega_decode) can hold in VMEM
-H350, NH350 = 1024, 8
+# llama-350m-hd128: the widest preset whose weights the weight-resident
+# fused_norm_qkv kernel can hold in VMEM
+H350 = 1024
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
 
@@ -314,20 +319,6 @@ def _case_fused_rms_rope_qkv(shape):
         ["fused_rms_rope_qkv"]
 
 
-def _case_mega_decode(shape):
-    from paddle_tpu.ops.pallas import mega_decode as m
-    pool, tables, lens = _pools(shape, 16, heads=NH350, ctx=256)
-    x, w, rope = (shape((8, 16, H350)), shape((H350, H350)),
-                  shape((8, 16, HD), F32))
-    assert m.supported(x, w, w, w, HD, cache=(pool, pool))
-
-    def layer(x, g, wq, wk, wv, wo, cos, sin, kp, vp, t, starts, ln):
-        return m.mega_decode(x, g, wq, wk, wv, wo, cos, sin, kp, vp, t,
-                             starts, ln, head_dim=HD)
-    return layer, (x, shape((H350,)), w, w, w, w, rope, rope, pool, pool,
-                   tables, lens, lens), ["mega_decode"]
-
-
 KERNEL_CASES = {
     "fused_swiglu_mlp-7b": _case_fused_swiglu_mlp,
     "flash_attention-7b": _case_flash_attention,
@@ -343,7 +334,6 @@ KERNEL_CASES = {
     "int4_matmul-7b": _case_int4_matmul,
     "lora_bgmv-7b": _case_lora_bgmv,
     "fused_rms_rope_qkv-350m-hd128": _case_fused_rms_rope_qkv,
-    "mega_decode-350m-hd128": _case_mega_decode,
 }
 
 
@@ -404,19 +394,14 @@ def test_fused_adamw_takes_the_leaf_as_it_is(dims, g_dtype, shape, as_tpu):
 
 
 def test_gates_decline_what_mosaic_refuses(as_tpu):
-    """At Llama-2-7B widths the weight-resident kernels do not fit VMEM
-    and the exact-gelu kernel has no erf to lower to: their gates say
-    no, and the model keeps the XLA composition."""
-    from paddle_tpu.ops.pallas import (fused_mlp, fused_norm_qkv, mega_decode,
-                                       ragged_attention)
+    """At Llama-2-7B widths the weight-resident kernel does not fit
+    VMEM: its gate says no, and the model keeps the XLA composition."""
+    from paddle_tpu.ops.pallas import fused_norm_qkv, ragged_attention
 
     def z(*dims):
         return jax.ShapeDtypeStruct(dims, BF16)
     x, w = z(2048, H), z(H, H)
     assert not fused_norm_qkv.supported(x, w, w, HD)
-    pool = z(64, 16, NH, HD)
-    assert not mega_decode.supported(z(8, 16, H), w, w, w, HD,
-                                     cache=(pool, pool))
     # a bf16 page whose kv heads do not fill its HBM tiles (MQA, 6 or 12
     # kv heads) cannot be DMA'd whole; 2, 4 and multiples of 8 can
     span, i32 = z(8, 16, 24, HD), jax.ShapeDtypeStruct((8,), I32)
@@ -428,8 +413,6 @@ def test_gates_decline_what_mosaic_refuses(as_tpu):
     # llama-1b widths: compiles to 50 MiB of scoped VMEM, past the limit
     x1, w1 = z(2048, 2048), z(2048, 2048)
     assert not fused_norm_qkv.supported(x1, w1, w1, HD)
-    assert not fused_mlp.supported(x, z(H, 4 * H), z(4 * H, H),
-                                   op="fused_gelu_mlp")
 
 
 def test_smoke_train_step_compiles_for_v5e(topo, as_tpu, smoke):
@@ -486,4 +469,130 @@ def test_smoke_serve_step_compiles_for_v5e(one_chip, as_tpu, smoke):
         smoke.serve_step_hlo(eng, sharding=one_chip))
     assert found.get("ragged_paged_attention") == smoke.LAYERS, found
     assert found.get("fused_swiglu_mlp") == smoke.LAYERS, found
-    assert "mega_decode" not in found, found
+    assert len(found) == 2, found
+
+
+# -- the benchmark's cells: which kernels their steps hold --------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL_DEPTH = 2
+# PERF.md section 4, a layer's share times CELL_DEPTH: attention and MLP
+# kernels once a layer; fused_adamw once a matrix (Mistral 7 a layer, the
+# embedding and the head; GPT 4 a layer and both embeddings)
+CELL_KERNELS = {
+    "mistral-7b.train-8k": {"flash_attention_fwd": CELL_DEPTH,
+                            "flash_attention_bwd": CELL_DEPTH,
+                            "fused_swiglu_mlp": CELL_DEPTH,
+                            "fused_adamw": 7 * CELL_DEPTH + 2},
+    "gpt3-6.7b.train-8k": {"flash_attention_fwd": CELL_DEPTH,
+                           "flash_attention_bwd": CELL_DEPTH,
+                           "fused_adamw": 4 * CELL_DEPTH + 2},
+    "mistral-7b.serve-chat": {"ragged_paged_attention": CELL_DEPTH,
+                              "fused_swiglu_mlp": CELL_DEPTH},
+}
+
+
+def _cell_files(name):
+    """The cell's and its configuration's files, read as the benchmark
+    reads them; ``benchmark.builders`` imports from the repo's root."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    with open(os.path.join(REPO, "benchmark", "workloads",
+                           name + ".json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           cell["config"] + ".json")) as f:
+        config = json.load(f)
+    return cell, config, importlib.import_module(
+        "benchmark.builders." + config["builder"])
+
+
+def _train_cell_hlo(name, topo):
+    """``benchmark/runners/train.py``'s Program without the weights."""
+    from jax.sharding import NamedSharding
+
+    from paddle_tpu import amp, nn, optimizer
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.jit import TrainStep
+
+    cell, config, builder = _cell_files(name)
+    hp, traffic = cell["optimizer"], cell["traffic"]
+    fleet._reset()
+    try:
+        fleet.init(is_collective=True, devices=[topo.devices[0]])
+        with nn.meta_init():
+            model = builder.build_model(config, CELL_DEPTH,
+                                        config["max_position_embeddings"])
+        opt = optimizer.AdamW(
+            learning_rate=hp["learning_rate"], beta1=hp["beta1"],
+            beta2=hp["beta2"], epsilon=hp["epsilon"],
+            weight_decay=hp["weight_decay"],
+            grad_clip=nn.ClipGradByGlobalNorm(hp["clip_norm"]),
+            parameters=model.parameters())
+        model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
+        step = TrainStep(model, builder.loss_fn(), opt)
+        ids = jax.ShapeDtypeStruct(
+            (traffic["batch"], traffic["seq"]), jnp.int32,
+            sharding=NamedSharding(step.mesh, step.batch_spec))
+        return step.lower(step.abstract_state(),
+                          {"input_ids": ids, "labels": ids}
+                          ).compile().as_text()
+    finally:
+        fleet._reset()
+
+
+def _serve_cell_hlo(name, topo, smoke):
+    """``benchmark/runners/serve.py``'s Program as far as its Engine: the
+    one ``(max_batch, prefill_chunk)`` step that ``warmup`` compiles."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu import nn, serving
+
+    cell, config, builder = _cell_files(name)
+    with nn.meta_init():
+        model = builder.build_model(
+            config, CELL_DEPTH, config["max_position_embeddings"],
+            dtype="bfloat16")
+        model.astype("bfloat16")
+        model.eval()
+        eng = serving.Engine(model, **cell["engine"])
+    assert (eng.max_batch, eng.prefill_chunk) == (32, 16)
+    return smoke.serve_step_hlo(
+        eng, sharding=SingleDeviceSharding(topo.devices[0]))
+
+
+@pytest.fixture(scope="module")
+def cell_kernels(topo, smoke):
+    """``cell_kernels(name)``: the Pallas calls by name in the cell's step
+    compiled for v5e at depth ``CELL_DEPTH``; one compile a cell."""
+    from paddle_tpu.ops import dispatch
+
+    found = {}
+
+    def kernels(name):
+        if name not in found:
+            with pytest.MonkeyPatch.context() as mp:     # as ``as_tpu``
+                mp.setattr(dispatch, "_backend", lambda: "tpu")
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                hlo = (_serve_cell_hlo(name, topo, smoke)
+                       if name.endswith("serve-chat")
+                       else _train_cell_hlo(name, topo))
+            found[name] = smoke.pallas_kernels(hlo)
+        return found[name]
+    return kernels
+
+
+@pytest.mark.parametrize(
+    "cell,kernel",
+    [(c, k) for c, ks in CELL_KERNELS.items() for k in ks])
+def test_benchmark_cell_holds_its_kernel(cell, kernel, cell_kernels):
+    """Every cell's program runs the kernels PERF.md says it runs, as
+    often: a gate that starts to decline at a cell's widths, or a model
+    path that stops reaching its kernel, shows here and not on the chip."""
+    found = cell_kernels(cell)
+    assert found.get(kernel) == CELL_KERNELS[cell][kernel], found
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
+def test_benchmark_cell_holds_no_other_kernel(cell, cell_kernels):
+    assert sorted(cell_kernels(cell)) == sorted(CELL_KERNELS[cell])

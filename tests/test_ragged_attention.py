@@ -193,9 +193,10 @@ class TestRaggedFunctionalOp:
                 np.testing.assert_array_equal(kc_np[blk, pos % 16],
                                               new_k[b, j])
 
-    def test_decode_span_matches_paged_decode_attend(self):
-        """A C=1 ragged batch IS the legacy decode step — both ops must
-        produce the same tokens' attention from the same pools."""
+    def test_decode_span_matches_paged_attention(self):
+        """A C=1 ragged batch IS one decode step of the public paged op
+        (``write_paged_kv`` + ``paged_attention``) — both must produce
+        the same tokens' attention from the same pools."""
         q, kp, vp, tables, starts, lens = _case(
             B=3, C=1, H=4, HKV=2, starts=[30, 8, 55], lens=[1, 1, 1])
         new_k = R.normal(size=(3, 1, 2, 128)).astype("float32")
@@ -204,12 +205,14 @@ class TestRaggedFunctionalOp:
             (jnp.asarray(kp), jnp.asarray(vp)), jnp.asarray(q),
             jnp.asarray(new_k), jnp.asarray(new_v), jnp.asarray(tables),
             jnp.asarray(starts), jnp.asarray(lens))
-        legacy, _ = IF.paged_decode_attend(
-            (jnp.asarray(kp), jnp.asarray(vp)), jnp.asarray(q[:, 0]),
-            jnp.asarray(new_k[:, 0]), jnp.asarray(new_v[:, 0]),
-            jnp.asarray(tables), jnp.asarray(starts))
+        ctx = jnp.asarray(starts) + 1
+        kc, vc = IF.write_paged_kv(
+            jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(new_k[:, 0]),
+            jnp.asarray(new_v[:, 0]), jnp.asarray(tables), ctx)
+        paged = IF.paged_attention(jnp.asarray(q[:, 0]), kc, vc,
+                                   jnp.asarray(tables), ctx)
         np.testing.assert_allclose(np.asarray(ragged[:, 0]),
-                                   np.asarray(legacy),
+                                   np.asarray(paged),
                                    rtol=2e-4, atol=2e-5)
 
     def test_int8_pools_equivalence(self):

@@ -252,108 +252,6 @@ def sweep_blocks(preset, t, dtype, iters):
     return results
 
 
-def sweep_mega(preset, dtype, iters):
-    """Decode-megakernel sweep (TPU only — interpret-mode timings say
-    nothing about Mosaic).  The megakernel has no internal block knobs:
-    its tiles ARE the serving shapes — the span width C (token tile,
-    the engine's decode/chunked-prefill span) and the KV pool page size
-    (page block, the grid's sequential axis) set the whole schedule.
-    Each (C, page) combo is timed kernel-vs-XLA-composition as separate
-    jit dispatches (the honest A/B), the fastest combo is recorded, and
-    a measured loss records ``{"enabled": false}`` — the veto
-    ``fused_ops="auto"`` models honor through ``ops.tuning``."""
-    if jax.default_backend() != "tpu":
-        print("# mega sweep skipped: backend is "
-              f"{jax.default_backend()!r} (kernel runs interpreted)")
-        return {}
-    from paddle_tpu.incubate.nn import functional as IF
-    from paddle_tpu.ops import tuning
-    from paddle_tpu.ops.pallas import mega_decode as MD
-
-    geom = _geometry(preset)
-    h, hd, nq, nk, eps = (geom["h"], geom["hd"], geom["nq"], geom["nk"],
-                          geom["eps"])
-    h_kv = geom["kv_heads"]
-    key = tuning.geom_key(h=h, nq=nq, nk=nk, hd=hd)
-    bsz, max_seq = 8, 2048
-    r = np.random.default_rng(0)
-
-    def arr(*shape, scale=0.05):
-        return jnp.asarray(r.normal(size=shape) * scale, dtype)
-
-    gw = jnp.ones((h,), dtype)
-    wq, wk, wv, wo = arr(h, nq), arr(h, nk), arr(h, nk), arr(nq, h)
-    best = (float("inf"), None, None)
-    for c in (8, 16, 32):
-        for page in (16, 64, 128):
-            x = arr(bsz, c, h, scale=1.0)
-            mb = max_seq // page
-            nb = bsz * mb
-            kp = arr(nb, page, h_kv, hd, scale=0.5)
-            if not MD.supported(x, wq, wk, wo, hd, cache=(kp, kp)):
-                print(f"# mega_decode_layer c={c} page={page}: "
-                      "supported() declines this geometry")
-                continue
-            vp = arr(nb, page, h_kv, hd, scale=0.5)
-            # mixed decode + chunked-prefill-tail spans, long prefixes —
-            # the serving regime the kernel exists for
-            st_np = np.array([max_seq - c, 37, 1023, 0, 511, 128,
-                              max_seq // 2, 7][:bsz], np.int32)
-            ln_np = np.array([1, c, 1, c, 1, 1, c, 1][:bsz], np.int32)
-            pos = st_np[:, None] + np.arange(c)[None, :]
-            inv = 1.0 / (10000.0 ** (np.arange(0, hd, 2) / hd))
-            ang = pos[..., None] * inv[None, None, :]
-            cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1), dtype)
-            sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1), dtype)
-            tb = jnp.asarray(
-                r.permutation(nb).reshape(bsz, mb).astype(np.int32))
-            st, ln = jnp.asarray(st_np), jnp.asarray(ln_np)
-
-            # fused leg: the dispatcher path — kernel + the shared span
-            # scatter.  One compile per swept combo, by design.
-            @jax.jit
-            def fused_leg(x, kp, vp, tb, st, ln, _c=c):
-                o, kk, vv = MD.mega_decode(
-                    x, gw, wq, wk, wv, wo, cos, sin, kp, vp, tb, st, ln,
-                    hd, eps)
-                kc, vc = IF._paged_span_write(
-                    (kp, vp), kk.reshape(bsz, _c, h_kv, hd),
-                    vv.reshape(bsz, _c, h_kv, hd), tb, st, ln)
-                return o, kc, vc
-
-            # pdtpu-lint: disable=retrace-hazard — one compile per swept config, by design
-            base_leg = jax.jit(
-                lambda x, kp, vp, tb, st, ln: IF._mega_decode_layer_ref(
-                    x, gw, wq, wk, wv, wo, cos, sin, (kp, vp), tb, st,
-                    ln, hd, eps, None))
-            try:
-                fused = _time(fused_leg, x, kp, vp, tb, st, ln,
-                              iters=iters)
-                base = _time(base_leg, x, kp, vp, tb, st, ln,
-                             iters=iters)
-                fused = min(fused, _time(fused_leg, x, kp, vp, tb, st,
-                                         ln, iters=iters))
-                base = min(base, _time(base_leg, x, kp, vp, tb, st, ln,
-                                       iters=iters))
-            except Exception as e:  # noqa: BLE001 — VMEM overflow etc.
-                print(f"# mega_decode_layer c={c} page={page}: "
-                      f"{type(e).__name__}")
-                continue
-            print(f"# mega_decode_layer c={c} page={page}: "
-                  f"kernel {fused:.3f} ms vs composition {base:.3f} ms")
-            best = min(best, (fused, base, (c, page)),
-                       key=lambda t: t[0])
-    if best[2] is None:
-        return {}
-    fused, base, (c, page) = best
-    speedup = base / fused if fused else 0.0
-    return {"mega_decode_layer": {key: {
-        "enabled": bool(speedup >= 1.0),
-        "speedup": round(speedup, 3),
-        "span_c": c, "page_block": page,
-        "unfused_ms": round(base, 4), "fused_ms": round(fused, 4)}}}
-
-
 def sweep_serving(preset, on_tpu):
     """Page size × prefill chunk on a small continuous-batching drain.
     Engines are built per combo and timed over one warmed pass."""
@@ -419,7 +317,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="llama-350m")
     ap.add_argument("--ops", default="all",
-                    help="comma list of: fusion, blocks, mega, serving")
+                    help="comma list of: fusion, blocks, serving")
     ap.add_argument("--tokens", type=int, default=None,
                     help="token count for the op sweeps (default: 2048 "
                          "on TPU, 256 on CPU)")
@@ -432,7 +330,7 @@ def main():
     t = args.tokens or (2048 if on_tpu else 256)
     iters = args.iters or (20 if on_tpu else 5)
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
-    wanted = (("fusion", "blocks", "mega", "serving")
+    wanted = (("fusion", "blocks", "serving")
               if args.ops == "all" else tuple(args.ops.split(",")))
     preset = args.preset
 
@@ -441,8 +339,6 @@ def main():
         _merge(results, "_", sweep_fusion(preset, t, dtype, iters))
     if "blocks" in wanted:
         _merge(results, "_", sweep_blocks(preset, t, dtype, iters))
-    if "mega" in wanted:
-        _merge(results, "_", sweep_mega(preset, dtype, iters))
     if "serving" in wanted:
         _merge(results, "_", sweep_serving(preset, on_tpu))
 

@@ -1146,74 +1146,6 @@ def gate_serving_smoke(max_batch: int = 4, n_requests: int = 10) -> int:
                   "1 hot-load mid-churn, 1 evict, mixed "
                   "base+3-adapter batches) token-identical to "
                   "merged-weight references, 0 compiles after warmup")
-
-        # 7. MEGAKERNEL DECODE PATH (docs/KERNELS.md "Decode
-        # megakernel"): ``fused_ops="mega"`` collapses the whole cached
-        # decoder layer (norm → QKV+RoPE → ragged paged attention →
-        # o-proj + residual) into ONE closed dispatch.  The standing
-        # contracts hold unchanged — one warmup compile set, ZERO
-        # compiles under mixed prefill+decode churn, jit caches at one
-        # entry, greedy outputs token-identical to model.generate() —
-        # and the step program is PROVABLY smaller:
-        # ``dispatches_per_step`` (top-level equation count of the
-        # unified ragged step) strictly below the unfused engine's.
-        # On CPU the Pallas megakernel itself declines and the XLA
-        # composition rides the same contract; the dispatch-count A/B
-        # is structural, not a timing claim.
-        pt.seed(0)
-        mmodel = llama("tiny", fused_ops="mega")
-        meng = serving.Engine(mmodel, max_batch=max_batch,
-                              max_seq_len=64, page_size=8,
-                              prefill_chunk=8).warmup()
-        mega_warmup = tel.sentinel.compiles()
-        mprompts = [rng.integers(0, mmodel.cfg.vocab_size,
-                                 size=n).astype(np.int32)
-                    for n in (3, 17, 9, 26, 40)]
-        served = []
-        for p in mprompts:
-            rid = meng.add_request(p, max_new_tokens=6)
-            meng.step()     # staggered: join a running batch
-            outs = meng.run()
-            served.append((p, outs[rid]))
-        mega_churn = tel.sentinel.compiles() - mega_warmup
-        if mega_churn:
-            failures.append(
-                f"{mega_churn} compile(s) after warmup with the "
-                "megakernel decode path on — mega_decode_layer "
-                "re-traces under churn (its geometry gate must resolve "
-                "before warmup)")
-        for fn, name in ((meng._step_fn, "mega step"),
-                         (meng._cow_fn, "mega cow")):
-            n = getattr(fn, "_cache_size", lambda: None)()
-            if n is not None and n > 1:
-                failures.append(
-                    f"{name} jit cache holds {n} entries, expected 1")
-        for p, got in served:
-            ref = np.asarray(mmodel.generate(
-                jnp.asarray(p)[None], max_new_tokens=6,
-                temperature=0.0))[0, len(p):]
-            if not np.array_equal(ref, np.asarray(got)):
-                failures.append(
-                    f"megakernel request (prompt {len(p)}) diverged "
-                    "from model.generate() — the one-dispatch decode "
-                    "layer changed greedy outputs")
-        if meng.kv_blocks_used != 0:
-            failures.append(
-                f"{meng.kv_blocks_used} KV block(s) still referenced "
-                "after the megakernel runs")
-        d_mega = meng.dispatches_per_step()
-        d_base = eng.dispatches_per_step()
-        if not d_mega < d_base:
-            failures.append(
-                f"megakernel step program is not smaller: {d_mega} "
-                f"top-level equations vs {d_base} unfused — the fused "
-                "layer block is not closing into one dispatch")
-        if not any("mega" in f for f in failures):
-            print(f"serving-smoke: megakernel decode path "
-                  f"(fused_ops=mega): {len(mprompts)} requests "
-                  "token-identical to generate(), 0 compiles after "
-                  f"warmup, step program {d_mega} eqns vs {d_base} "
-                  "unfused")
     finally:
         obs.disable()
 
@@ -1523,96 +1455,6 @@ def gate_chaos_serving(max_batch: int = 4) -> int:
                   "and a mid-decode preemption absorbed on the "
                   "speculative engine: outputs token-identical, "
                   "0 compiles, all blocks reclaimed")
-
-        # MEGAKERNEL under chaos (docs/KERNELS.md "Decode megakernel"):
-        # with ``fused_ops="mega"`` the whole decoder layer is ONE
-        # closed dispatch, so a serve.step fault fires
-        # MID-MEGAKERNEL-STEP — the fused layer's outputs and its
-        # in-step KV pool writes are already in flight when the slot
-        # bookkeeping raises.  The isolation rewind must discard the
-        # entire fused step as one unit: no half-applied layer, no torn
-        # KV page.  Same contract as above — greedy outputs
-        # token-identical to the fault-free mega run, zero compiles,
-        # full reclaim — with a mid-decode preemption riding the
-        # preempt→swap→restore path on the megakernel engine.
-        MSPEC = "serve.prefill@1,serve.step@3x2,serve.swap@0:OSError"
-        mega_sites = ("serve.prefill", "serve.step", "serve.swap")
-        pt.seed(0)
-        mmodel = llama("tiny", fused_ops="mega")
-
-        def mega_scenario(spec, tag):
-            rs.clear_faults()
-            inj = None
-            if spec:
-                os.environ["PDTPU_FAULTS"] = spec
-                inj = rs.install_faults_from_env()
-            try:
-                eng = serving.Engine(
-                    mmodel, max_batch=max_batch, max_seq_len=64,
-                    page_size=8, prefill_chunk=8,
-                    retry=rs.RetryPolicy(max_attempts=4, backoff_s=0.0,
-                                         jitter=0.0,
-                                         sleep=lambda _s: None)).warmup()
-                c0 = tel.sentinel.compiles()
-                rids = []
-                preempted = False
-                with warnings.catch_warnings():
-                    warnings.simplefilter("ignore", RuntimeWarning)
-                    for p, m_ in zip(prompts[:5], budgets[:5]):
-                        rids.append(eng.add_request(p, max_new_tokens=m_))
-                        eng.step()
-                    for _ in range(200):
-                        if not preempted:
-                            # victim a DECODING slot so the preemption
-                            # lands between megakernel steps — the swap
-                            # must round-trip pages the fused layer
-                            # wrote in the SAME dispatch as attention
-                            for _slot, st in eng.scheduler.active():
-                                if not st.prefilling:
-                                    preempted = eng.preempt(
-                                        st.request.request_id)
-                                    break
-                        if not eng.has_work():
-                            break
-                        eng.step()
-                    eng.run()
-                churn = tel.sentinel.compiles() - c0
-                if churn:
-                    failures.append(
-                        f"{tag}: {churn} compile(s) after warmup on "
-                        "the megakernel engine")
-                if not preempted:
-                    failures.append(
-                        f"{tag}: mid-decode preemption never engaged "
-                        "on the megakernel engine")
-                if eng.kv_blocks_used != 0:
-                    failures.append(
-                        f"{tag}: {eng.kv_blocks_used} KV block(s) "
-                        "leaked on the megakernel engine")
-                return [eng.output_ids(r) for r in rids], inj
-            finally:
-                rs.clear_faults()
-                os.environ.pop("PDTPU_FAULTS", None)
-
-        mbase, _ = mega_scenario(None, "mega-baseline")
-        mfault, minj = mega_scenario(MSPEC, "mega-faulted")
-        mfired = {site for site, _idx in minj.fired}
-        mmissing = [s for s in mega_sites if s not in mfired]
-        if mmissing:
-            failures.append(
-                f"mega-faulted: plan never fired at {mmissing} — the "
-                "scenario lost coverage of those sites")
-        mdiverged = [i for i, (a, b) in enumerate(zip(mbase, mfault))
-                     if a != b]
-        if mdiverged:
-            failures.append(
-                f"mega-faulted: requests {mdiverged} diverged from the "
-                "fault-free megakernel run — the one-dispatch layer is "
-                "not rewound as a unit")
-        elif not mmissing:
-            print("chaos-serving: mid-megakernel-step faults absorbed "
-                  "on the fused_ops=mega engine: outputs "
-                  "token-identical, 0 compiles, all blocks reclaimed")
     finally:
         obs.disable()
 

@@ -715,58 +715,6 @@ def bench_serve_lora(preset="llama-350m", n_adapters=3, rank=8,
             "active_adapters": pool.active_adapters}
 
 
-def bench_decode_mega(preset="llama-350m-hd128", prefill=128, max_new=256,
-                      page_size=16, repeats=3):
-    """bs=1 decode through the serving engine with the decode megakernel
-    on (``fused_ops="mega"``) vs the per-stage fused path
-    (``fused_ops="on"``) — docs/KERNELS.md "Decode megakernel".
-
-    The megakernel serves the PAGED ragged step only, so this row
-    measures ``serving.Engine`` decode, not ``generate()`` (whose dense
-    cache path never routes through it), at the hd128 preset the
-    kernel's MXU-alignment gate accepts.  HONESTY NOTE: on the chip the
-    ``mega`` leg is the Pallas kernel and the tok/s ratio is the
-    headline; OFF the chip the kernel declines and both legs run XLA
-    compositions, so the CPU number is a STRUCTURAL A/B only — the
-    recorded ``dispatches_per_step`` delta (one closed equation per
-    layer vs the per-stage chain) is the signal there, and the tok/s
-    ratio must not be read as kernel speed."""
-    import paddle_tpu as pt
-    from paddle_tpu import serving
-    from paddle_tpu.models.llama import llama
-
-    rng = np.random.default_rng(0)
-    max_seq = prefill + max_new + 8
-    prompt = None
-    out = {"metric": "decode_bs1_mega_tok_s", "preset": preset,
-           "prefill": prefill, "max_new_tokens": max_new,
-           "page_size": page_size, "backend": jax.default_backend()}
-    for mode in ("on", "mega"):
-        pt.seed(0)
-        model = llama(preset, max_position_embeddings=max_seq,
-                      dtype="bfloat16", fused_ops=mode)
-        model.astype("bfloat16")
-        eng = serving.Engine(model, max_batch=1, max_seq_len=max_seq,
-                             page_size=page_size).warmup()
-        if prompt is None:
-            prompt = rng.integers(0, model.cfg.vocab_size,
-                                  size=prefill).astype(np.int32)
-        best, ntok = float("inf"), 0
-        for _ in range(repeats):
-            rid = eng.add_request(prompt, max_new_tokens=max_new)
-            t0 = time.perf_counter()
-            outs = eng.run()
-            best = min(best, time.perf_counter() - t0)
-            ntok = len(outs[rid])
-            assert eng.kv_blocks_used == 0, "KV blocks leaked at drain"
-        out[f"{mode}_tok_s"] = round(ntok / best, 1)
-        out[f"{mode}_dispatches_per_step"] = eng.dispatches_per_step()
-    out["decode_bs1_mega_tok_s"] = out["mega_tok_s"]
-    out["vs_fused_on"] = (round(out["mega_tok_s"] / out["on_tok_s"], 2)
-                          if out["on_tok_s"] else None)
-    return out
-
-
 def bench_decode_attention(batch=8, heads=16, head_dim=64, ctx=1024,
                            block_size=64, iters=200):
     """Paged vs contiguous decode attention, op-level, slope-amortized."""
@@ -831,10 +779,6 @@ def main():
     for batch in (1, 8):
         print(json.dumps(bench_generate(batch=batch, kv_cache_dtype="int8",
                                         weight_quant="int8")), flush=True)
-    # decode megakernel: bs=1 paged decode with the whole layer in one
-    # dispatch vs the per-stage fused path — a kernel headline on the
-    # chip, a structural (dispatch-count) A/B only off it
-    print(json.dumps(bench_decode_mega()), flush=True)
     # continuous batching: the aggregate serving number next to the
     # per-sequence decode rows (bf16 and the int8-KV serving point)
     print(json.dumps(bench_serve()), flush=True)

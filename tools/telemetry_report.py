@@ -579,19 +579,6 @@ def render(agg, malformed=0):
             lines.append(f"| ragged occupancy p50 / p95 | "
                          f"{fmt(occ.get('p50'))} / {fmt(occ.get('p95'))} "
                          f"({sv['span_tokens']} span tokens) |")
-        # decode megakernel (docs/KERNELS.md "Decode megakernel"): the
-        # dispatch-count gauge is the fusion contract made visible (one
-        # closed eqn per decoder layer when fused_ops="mega" engaged);
-        # the step.mega roofline row only exists on a mega engine, so
-        # its presence tags the stream's leg for A/B overlays
-        disp = m.get("serve.dispatches_per_step")
-        if disp is not None:
-            lines.append(f"| dispatches per decode step | {disp} |")
-        mega_ms = m.get("serve.roofline.step.mega.min_ms")
-        if mega_ms is not None:
-            frac = m.get("serve.roofline.step.frac")
-            lines.append(f"| megakernel step roofline min ms (frac) | "
-                         f"{fmt(mega_ms)} ({fmt(frac, 3)}) |")
         # speculative decoding (docs/SERVING.md "Speculative decoding"):
         # acceptance-rate column from the serve.spec.* counters, accept
         # length distribution from the histogram
@@ -970,13 +957,6 @@ def main(argv=None) -> int:
                       / m["serve.spec.proposed"], 3)
                 if m.get("serve.spec.proposed") else None),
             "spec_draft_errors": m.get("serve.spec.draft_errors") or 0,
-            # decode megakernel (docs/KERNELS.md "Decode megakernel"):
-            # None (not 0) when the engine never published them — a
-            # pre-megakernel stream must not read as "0 dispatches"
-            "dispatches_per_step": m.get("serve.dispatches_per_step"),
-            "roofline_step_min_ms": m.get("serve.roofline.step.min_ms"),
-            "roofline_step_mega_min_ms": m.get(
-                "serve.roofline.step.mega.min_ms"),
             # disaggregated handoff/transfer fold (docs/SERVING.md
             # "Disaggregated serving")
             "handoffs": sv["handoffs"],

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
-"""Re-sweep previously-rejected tuning knobs after the matmul-rope step
-change (BENCH.md §attribution): trace-time QKV/gate-up fusion and bs8 +
-chunked CE were rejected at the r2/r3 cost structure; the layout-traffic
-profile changed, so re-measure.
+"""Re-sweep a previously-rejected tuning knob after the matmul-rope step
+change (BENCH.md §attribution): bs8 + chunked CE was rejected at the
+r2/r3 cost structure; the layout-traffic profile changed, so re-measure.
 
 Usage: python tools/tune_sweep.py [--steps 15] [--windows 2]
 """
@@ -24,10 +23,8 @@ def main():
     import bench
 
     cases = [
-        ("bs4", dict(batch_size=4, loss_chunks=1, fuse=False)),
-        ("bs4+fuse", dict(batch_size=4, loss_chunks=1, fuse=True)),
-        ("bs8+ce8", dict(batch_size=8, loss_chunks=8, fuse=False)),
-        ("bs8+ce8+fuse", dict(batch_size=8, loss_chunks=8, fuse=True)),
+        ("bs4", dict(batch_size=4, loss_chunks=1)),
+        ("bs8+ce8", dict(batch_size=8, loss_chunks=8)),
     ]
     out = {}
     print("| case | mfu | ms/step | tok/s/chip |")
@@ -36,8 +33,7 @@ def main():
         try:
             mfu, stats = bench.measure(args.preset, kw["batch_size"], 2048,
                                        args.steps, args.windows,
-                                       loss_chunks=kw["loss_chunks"],
-                                       fuse=kw["fuse"])
+                                       loss_chunks=kw["loss_chunks"])
             print(f"| {name} | {mfu:.4f} | {stats['ms_per_step']} "
                   f"| {stats['tokens_per_sec_per_chip']} |", flush=True)
             out[name] = {"mfu": round(mfu, 4),
