@@ -28,11 +28,15 @@ Step anatomy (one :meth:`step` call):
 1. **admit**: waiting requests move into free slots while blocks last;
    prefix-cache hits skip straight to their first uncached token;
 2. **plan + CoW**: each active slot gets its span (next prefill chunk,
-   bounded by the per-step token budget, or its pending decode token);
+   bounded by the per-step token budget, or its pending decode token),
+   and the rows of slots that hold no request go to the prompts that
+   still have tokens left, oldest admission first — a prefilling
+   request may hold several rows of one step (``scheduler.plan_spans``);
    spans landing in borrowed pages trigger the copy-on-write dispatch;
 3. **one ragged step**: every span's KV is scattered at its positions,
-   every query row attends its prefix, one token is sampled per slot —
-   consumed only by slots that completed their prompt (TTFT) or decoded;
+   every query row attends its prefix, one token is sampled per row —
+   consumed only by requests that completed their prompt (TTFT, read
+   from the row that holds the prompt's last token) or decoded;
 4. **retire**: EOS / max-token requests leave their slot; their private
    full-prompt pages stay indexed in the prefix cache (evictable LRU),
    everything else returns to the free list.
@@ -51,7 +55,8 @@ layers multi-tenant SLO admission on top.
 Telemetry (all zero-overhead when observability is disabled):
 ``serve.ttft_ms``, ``serve.step_ms``, ``serve.tok_s``,
 ``serve.queue_depth``, ``serve.kv_blocks_used``, ``serve.active_requests``,
-``serve.ragged_occupancy``, ``serve.prefix_hits``/``misses``,
+``serve.ragged_occupancy``, ``serve.prefill_rows``,
+``serve.prefill_steps``, ``serve.prefix_hits``/``misses``,
 ``serve.shared_blocks``, ``serve.cached_blocks``, ``serve.cow_copies``,
 ``serve.preemptions``/``restores``/``swapped_pages``/
 ``isolated_failures``, and — with speculative decoding on —
@@ -97,7 +102,7 @@ from ..resilience.retry import RetryPolicy
 from .block_allocator import PagedKVCache, PrefixCache, SwapManager
 from .errors import (AdmissionError, BudgetUnsatisfiable, QueueFull,
                      UnknownAdapter)
-from .scheduler import Request, RequestState, Scheduler
+from .scheduler import Request, RequestState, Scheduler, by_request
 
 __all__ = ["Engine", "TokenEvent"]
 
@@ -194,11 +199,14 @@ class Engine:
     ``prefill_chunk``: span width C of the unified step (default
     ``min(16, max_seq_len)``) — prompts prefill in ≤C-token chunks
     interleaved with decode, so one compiled
-    ``(B, C)`` program serves every batch mix.  ``prefill_token_budget``
-    caps the TOTAL prefill tokens scheduled per step (default:
-    unbounded, i.e. ``max_batch * prefill_chunk``) — on TPU the ragged
-    kernel skips dead pages, so a tighter budget bounds per-step latency
-    under bursty admission.
+    ``(B, C)`` program serves every batch mix; a prompt also takes the
+    rows of slots that hold no request, a chunk each, so it advances by
+    up to ``(free rows + 1) * C`` tokens a step.  ``prefill_token_budget``
+    caps the TOTAL prefill tokens scheduled per step, over all rows
+    (default: unbounded, i.e. ``max_batch * prefill_chunk``) — on TPU
+    the ragged kernel skips dead pages, so a tighter budget bounds
+    per-step latency under bursty admission; a budget of one chunk
+    leaves each prompt its own row only.
 
     ``enable_prefix_caching``: hash-based sharing of page-aligned prompt
     prefixes across requests (copy-on-write on shared-page writes, LRU
@@ -936,21 +944,23 @@ class Engine:
     # -- the loop ----------------------------------------------------------
 
     def _run_cow(self, plan):
-        """Copy-on-write: any span about to write into a borrowed
+        """Copy-on-write: any request about to write into a borrowed
         (shared) page gets a private copy first — the reserved spare
         block takes the page's content via one fixed-shape device copy,
         the table is repointed, and the shared reference is dropped.
-        Returns the plan minus any request isolated by a ``serve.cow``
-        fault (fired BEFORE that request's tables are touched, so
-        isolation sees consistent state)."""
+        The pages are those of the request's whole fan this step, once
+        per request.  Returns the plan minus any request isolated by a
+        ``serve.cow`` fault (fired BEFORE that request's tables are
+        touched, so isolation sees consistent state)."""
         fi = _rs_state.FAULTS[0]
         copies = []
-        dropped = []
-        for i, st, n, is_prefill in plan:
+        dropped = set()
+        for spans in by_request(plan):
+            st = spans[0].st
             if not st.borrowed:
                 continue
-            first = st.kv_len // self.page_size
-            last = (st.kv_len + n - 1) // self.page_size
+            first = spans[0].start // self.page_size
+            last = (spans[-1].start + spans[-1].n - 1) // self.page_size
             pgs = [pg for pg in range(first, last + 1) if pg in st.borrowed]
             if not pgs:
                 continue
@@ -959,9 +969,9 @@ class Engine:
                     fi("serve.cow")
                 except Exception as e:  # noqa: BLE001
                     # nothing mutated for this request yet this step:
-                    # plain isolation, and its span leaves the plan
+                    # plain isolation, and its spans leave the plan
                     self._isolate(st, e)
-                    dropped.append(i)
+                    dropped.add(id(st))
                     continue
             for pg in pgs:
                 src = int(st.table[pg])
@@ -973,7 +983,7 @@ class Engine:
                 self.kv.allocator.free([src])   # drop OUR shared ref
                 copies.append((src, dst))
         if dropped:
-            plan = [it for it in plan if it[0] not in dropped]
+            plan = [sp for sp in plan if id(sp.st) not in dropped]
         if not copies:
             return plan
         k = self.max_batch
@@ -1303,7 +1313,7 @@ class Engine:
                                                  self.prefill_token_budget)
                 if plan:
                     plan = self._run_cow(plan)
-                live_tokens = sum(n for _, _, n, _ in plan)
+                live_tokens = sum(sp.n for sp in plan)
                 if plan:
                     (tokens, tables, starts, lens, temps, seeds, emit,
                      adapters) = self.scheduler.span_arrays(
@@ -1426,7 +1436,7 @@ class Engine:
                 m = rf.get("step")
                 if m:
                     frac = round(m / max(dt * 1e3, 1e-9), 4)
-                    n_pref = sum(n for _, _, n, p in plan if p)
+                    n_pref = sum(sp.n for sp in plan if sp.is_prefill)
                     cls = "prefill" if 2 * n_pref >= live_tokens \
                         else "decode"
                     reg.gauge("serve.roofline.step.frac").set(frac)
@@ -1448,11 +1458,17 @@ class Engine:
         if plan:
             fi = _rs_state.FAULTS[0]
             tr = _obs_state.TRACE[0]
-            for i, st, n, is_prefill in plan:
+            reg = obs.get_registry()
+            # one consumption per REQUEST, not per row: a prefilling
+            # request's fan (its own row plus the free rows it was
+            # dealt) advances kv_len once, by the sum of its rows
+            for spans in by_request(plan):
+                first = spans[0]
+                st = first.st
                 # pre-span snapshot: isolation rewinds to here, and
-                # re-running the span after restore is idempotent
-                # (the dispatch above already wrote this span's KV;
-                # the rewound re-run rewrites identical bytes — a
+                # re-running the spans after restore is idempotent
+                # (the dispatch above already wrote their KV; the
+                # rewound re-run rewrites identical bytes — a
                 # speculative span's rejected tail is re-proposed from
                 # the same context, and kv_len only ever covered the
                 # accepted prefix)
@@ -1462,31 +1478,39 @@ class Engine:
                         st.spec_accepted)
                 try:
                     if fi is not None:
-                        fi("serve.prefill" if is_prefill
+                        fi("serve.prefill" if first.is_prefill
                            else "serve.step")
-                    if not is_prefill:
+                    if not first.is_prefill:
                         # decode: plain single token, or the
                         # speculative verify span (mid-verify faults
                         # fired above land in the rollback below)
-                        self._consume_decode(st, i, n, nxt, events)
+                        self._consume_decode(st, first.row, first.n, nxt,
+                                             events)
                         continue
+                    n = sum(sp.n for sp in spans)
                     st.kv_len += n
+                    st.prefill_steps += 1
                     if tr is not None:
                         tr.point(st.request.request_id, "prefill_chunk",
                                  tokens=n, kv_len=st.kv_len)
+                    if reg is not None:
+                        reg.histogram("serve.prefill_rows").observe(
+                            len(spans))
                     if st.prefilling:
-                        continue    # mid-prefill: sample discarded
+                        continue    # mid-prefill: samples discarded
                     # prompt complete: this sample is the request's
                     # first token — TTFT stops here.  first_token_t
                     # survives a hard replica-failure reset (the
                     # request re-prefills from scratch), so the
                     # re-completion must not re-emit serve_request /
                     # re-observe TTFT for the same request
-                    # (serving/distributed.py).  The speculative
-                    # program samples every span position; the prompt's
-                    # last position carries the first token.
-                    tok = int(nxt[i]) if nxt.ndim == 1 \
-                        else int(nxt[i, n - 1])
+                    # (serving/distributed.py).  The row that holds
+                    # the prompt's last token carries the first token —
+                    # at its last position on the speculative program,
+                    # which samples every span position.
+                    last = spans[-1]
+                    tok = int(nxt[last.row]) if nxt.ndim == 1 \
+                        else int(nxt[last.row, last.n - 1])
                     self._register_prefix(st)
                     # disaggregated prefill role: stage the handoff
                     # (swap the pages to host) BEFORE any state
@@ -1516,10 +1540,11 @@ class Engine:
                         continue
                     st.first_token_t = time.perf_counter()
                     req = st.request
-                    reg = obs.get_registry()
                     if reg is not None:
                         ttft = (st.first_token_t - st.submit_t) * 1e3
                         reg.histogram("serve.ttft_ms").observe(ttft)
+                        reg.histogram("serve.prefill_steps").observe(
+                            st.prefill_steps)
                         if req.tenant:
                             # the per-tenant aggregate the FrontDoor
                             # SLO policy reads (frontdoor._ttft_p95)

@@ -9,7 +9,12 @@ join/leave a running batch purely by editing the VALUES in those arrays:
 
 - a slot mid-PREFILL carries its next ≤C-token prompt chunk starting at
   ``kv_len`` (chunked prefill — no per-length bucket programs, no
-  head-of-line stall while a long prompt prefills);
+  head-of-line stall while a long prompt prefills), and the rows of
+  slots that hold NO request carry the chunks after it ("span
+  fan-out": a row is nothing but ``(block table, span start, span
+  length)``, and the step writes every row's KV before any row attends,
+  so ``k`` rows with one request's table and starts ``s, s+C, ...``
+  compute what ``k`` consecutive steps would);
 - a DECODING slot carries its single pending token (span length 1);
 - an idle/inactive slot carries span length 0 and the out-of-range
   block sentinel (scatters drop) — its lane computes garbage the engine
@@ -26,10 +31,11 @@ reserves a private replacement for the copy-on-write the engine performs
 before that write (serving/block_allocator.py has the lifecycle).
 
 Per-step chunk budgeting: ``plan_spans(chunk, budget)`` caps the TOTAL
-prefill tokens scheduled per step and round-robins the budget across
-prefilling slots, so on TPU (where the ragged kernel skips dead pages) a
-burst of admissions cannot stretch one step's latency unboundedly —
-decode slots always advance.
+prefill tokens scheduled per step — fan-out rows included — and
+round-robins the budget across prefilling slots, so on TPU (where the
+ragged kernel skips dead pages) a burst of admissions cannot stretch one
+step's latency unboundedly — decode slots always advance.  A budget of
+one chunk is a plan without fan-out.
 """
 
 from __future__ import annotations
@@ -39,13 +45,14 @@ import dataclasses
 import itertools
 import time
 import zlib
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Set, Tuple)
 
 import numpy as np
 
 from .block_allocator import PrefixCache
 
-__all__ = ["Request", "RequestState", "Scheduler"]
+__all__ = ["Request", "RequestState", "Scheduler", "Span", "by_request"]
 
 _ids = itertools.count()
 
@@ -93,7 +100,8 @@ class RequestState:
                  "drained", "num_shared", "num_cowed", "cached_tokens",
                  "borrowed", "cow_spare", "page_keys", "swapped",
                  "preempts", "handoffs", "sample_seed", "draft",
-                 "spec_proposed", "spec_accepted")
+                 "spec_proposed", "spec_accepted", "admit_seq",
+                 "prefill_steps")
 
     def __init__(self, request: Request):
         self.request = request
@@ -139,6 +147,8 @@ class RequestState:
         self.draft: List[int] = []
         self.spec_proposed = 0       # draft tokens sent to verification
         self.spec_accepted = 0       # of those, accepted
+        self.admit_seq = 0           # admission ordinal (fan-out order)
+        self.prefill_steps = 0       # steps that consumed prompt tokens
 
     @property
     def total_len(self) -> int:
@@ -147,6 +157,29 @@ class RequestState:
     @property
     def prefilling(self) -> bool:
         return self.kv_len < int(self.request.prompt_ids.size)
+
+
+class Span(NamedTuple):
+    """One row of a step's plan.  ``row`` is the row of the ``(B, C)``
+    step the span rides: the request's own slot for its first span, a
+    slot that holds no request for a fan-out span — never read
+    ``st.slot`` for it."""
+
+    row: int
+    st: RequestState
+    start: int          # pool position of the span's first token
+    n: int              # tokens in the span (1..C)
+    is_prefill: bool
+
+
+def by_request(plan: Sequence[Span]) -> List[List[Span]]:
+    """A plan's spans grouped by request: requests in slot order, each
+    request's spans in start order (the order ``plan_spans`` emits) —
+    one group is what a step consumes of one request."""
+    groups: Dict[int, List[Span]] = {}
+    for sp in plan:
+        groups.setdefault(id(sp.st), []).append(sp)
+    return list(groups.values())
 
 
 class Scheduler:
@@ -171,6 +204,7 @@ class Scheduler:
         self.slots: List[Optional[RequestState]] = [None] * self.max_batch
         self._rr = 0   # round-robin origin for the prefill token budget
         self._submits = 0   # submission ordinal folded into sample seeds
+        self._admits = 0    # admission ordinal: fan-out serves oldest first
 
     # -- admission ---------------------------------------------------------
 
@@ -246,12 +280,11 @@ class Scheduler:
             if not self.allocator.can_allocate(total):
                 return None
             self.waiting.popleft()
-            st.slot = slot
             st.blocks = self.allocator.allocate(total)
             st.table = np.full((self.max_blocks_per_seq,), self.oob_block,
                                np.int32)
             st.table[:total] = st.blocks
-            self.slots[slot] = st
+            self._seat(st, slot)
             return st
         plen = int(st.request.prompt_ids.size)
         total = self.blocks_needed(st)
@@ -289,7 +322,6 @@ class Scheduler:
         if self.prefix_cache is not None and keys:
             self.prefix_cache.record(shared, len(keys) - shared)
         self.waiting.popleft()
-        st.slot = slot
         st.blocks = list(hit_blocks) + priv        # one reference each
         st.table = np.full((self.max_blocks_per_seq,), self.oob_block,
                            np.int32)
@@ -305,8 +337,14 @@ class Scheduler:
         st.num_shared = shared
         st.cached_tokens = first_write
         st.kv_len = first_write
-        self.slots[slot] = st
+        self._seat(st, slot)
         return st
+
+    def _seat(self, st: RequestState, slot: int) -> None:
+        st.slot = slot
+        self.slots[slot] = st
+        st.admit_seq = self._admits
+        self._admits += 1
 
     # -- the running batch -------------------------------------------------
 
@@ -315,17 +353,27 @@ class Scheduler:
 
     # requires-lock: _lock — advances the _rr round-robin origin
     def plan_spans(self, chunk: int, budget: Optional[int] = None
-                   ) -> List[Tuple[int, "RequestState", int, bool]]:
-        """Decide each active slot's span for this step: ``(slot, state,
-        span_len, is_prefill)``.  Decode slots get their pending token
-        plus any speculative draft the engine attached (``st.draft`` —
-        span ``1 + len(draft)``, still ≤ chunk by the engine's draft
-        cap); prefilling slots split ``budget`` prefill tokens (default:
-        no cap) in ≤``chunk`` chunks, round-robined across steps so a
-        tight budget starves nobody.  Slots left out idle this step
-        (span 0).  The engine runs copy-on-write for spans that land in
-        borrowed pages BEFORE materializing the batch arrays
-        (span_arrays) — draft positions included."""
+                   ) -> List[Span]:
+        """Decide this step's rows: a list of :class:`Span`.
+
+        First pass, one span per active slot on its own row: decode
+        slots get their pending token plus any speculative draft the
+        engine attached (``st.draft`` — span ``1 + len(draft)``, still
+        ≤ chunk by the engine's draft cap); prefilling slots split
+        ``budget`` prefill tokens (default: ``max_batch * chunk``) in
+        ≤``chunk`` chunks, round-robined across steps so a tight budget
+        starves nobody.  Slots left out idle this step.
+
+        Second pass (span fan-out): the rows nobody claimed go to the
+        requests that still have prompt left, each extra row the next
+        ≤``chunk`` tokens of that prompt, oldest admission first (two
+        interleaved prompts delay both first tokens), until rows,
+        ``budget`` or prompts run out.
+
+        Requests come in slot order and each request's spans in start
+        order (:func:`by_request`).  The engine runs copy-on-write for
+        spans that land in borrowed pages BEFORE materializing the batch
+        arrays (span_arrays) — draft positions included."""
         c = int(chunk)
         left = int(budget) if budget is not None else self.max_batch * c
         self._rr = (self._rr + 1) % max(self.max_batch, 1)
@@ -339,31 +387,47 @@ class Scheduler:
                 if n <= 0:
                     continue                       # budget spent: idle
                 left -= n
-                plan.append((i, st, n, True))
+                plan.append(Span(i, st, st.kv_len, n, True))
             else:
                 # draft tokens are NOT prefill work: they ride the
                 # decode slot's lane for free (the ragged kernel skips
                 # dead rows either way) and never touch the budget
-                plan.append((i, st, 1 + min(len(st.draft), c - 1), False))
-        plan.sort(key=lambda t: t[0])
+                plan.append(Span(i, st, st.kv_len,
+                                 1 + min(len(st.draft), c - 1), False))
+        plan.sort(key=lambda sp: sp.row)
+        taken = {sp.row for sp in plan}
+        free = [r for r in range(self.max_batch) if r not in taken]
+        for first in sorted((sp for sp in plan if sp.is_prefill),
+                            key=lambda sp: sp.st.admit_seq):
+            st = first.st
+            plen = int(st.request.prompt_ids.size)
+            pos = first.start + first.n
+            while free and left > 0 and pos < plen:
+                n = min(c, plen - pos, left)
+                plan.append(Span(free.pop(0), st, pos, n, True))
+                pos += n
+                left -= n
         return plan
 
-    def span_arrays(self, plan, chunk: int, spec_emit: bool = False):
+    def span_arrays(self, plan: Sequence[Span], chunk: int,
+                    spec_emit: bool = False):
         """The fixed-shape ragged step inputs for a span plan:
         ``(tokens (B,C), tables (B,MB), starts (B,), lens (B,),
         temps (B,), seeds (B,), emit (B,), adapters (B,))`` as numpy
-        arrays.  Idle/empty slots get the inert sentinel values —
+        arrays.  Unclaimed rows get the inert sentinel values —
         shapes NEVER depend on occupancy (a draft miss is ``len 1``,
         never a new shape; an adapter change is a new VALUE in
-        ``adapters``, never a new program).  Call AFTER copy-on-write
-        has patched the tables.
+        ``adapters``, never a new program).  A fan-out row carries its
+        request's table, sampling policy and adapter index.  Call AFTER
+        copy-on-write has patched the tables.
 
         ``seeds``/``emit`` drive the per-emitted-token-index PRNG key
-        derivation (``engine._sample``): ``emit[i]`` is the emit index
-        of the slot's FIRST sampled position — for the speculative step
+        derivation (``engine._sample``): ``emit[r]`` is the emit index
+        of the row's FIRST sampled position — for the speculative step
         (``spec_emit=True``, which samples every span position) a
-        completing prefill span is rebased so its LAST position lands
-        on emit index ``len(output_ids)``."""
+        prefill span is rebased so its LAST position lands on emit
+        index ``len(output_ids)``; only the row that holds the prompt's
+        last token is ever consumed."""
         b, mb, c = self.max_batch, self.max_blocks_per_seq, int(chunk)
         tokens = np.zeros((b, c), np.int32)
         tables = np.full((b, mb), self.oob_block, np.int32)
@@ -373,16 +437,16 @@ class Scheduler:
         seeds = np.zeros((b,), np.int32)
         emit = np.zeros((b,), np.int32)
         adapters = np.zeros((b,), np.int32)   # 0 = base no-op slot
-        for i, st, n, is_prefill in plan:
+        for i, st, start, n, is_prefill in plan:
             req = st.request
             if is_prefill:
-                tokens[i, :n] = req.prompt_ids[st.kv_len:st.kv_len + n]
+                tokens[i, :n] = req.prompt_ids[start:start + n]
             else:
                 tokens[i, 0] = st.pending_token
                 if n > 1:
                     tokens[i, 1:n] = st.draft[:n - 1]
             tables[i] = st.table
-            starts[i] = st.kv_len
+            starts[i] = start
             lens[i] = n
             temps[i] = req.temperature
             seeds[i] = st.sample_seed

@@ -715,7 +715,11 @@ def test_telemetry_report_folds_serving_events(tmp_path):
             "serve.cow_copies": 1, "serve.shared_blocks": 2,
             "serve.cached_blocks": 4,
             "serve.ragged_occupancy": {"count": 4, "sum": 1.06,
-                                       "p50": 0.19, "p95": 0.56}}}) + "\n")
+                                       "p50": 0.19, "p95": 0.56},
+            "serve.prefill_rows": {"count": 5, "sum": 37.0, "p50": 4.0,
+                                   "p95": 27.0, "max": 27.0},
+            "serve.prefill_steps": {"count": 3, "sum": 6.0, "p50": 2.0,
+                                    "p95": 3.0, "max": 3.0}}}) + "\n")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "telemetry_report.py"),
          path], capture_output=True, text=True, timeout=60)
@@ -727,8 +731,13 @@ def test_telemetry_report_folds_serving_events(tmp_path):
     assert "| CoW copies | 1 |" in r.stdout
     assert "| ragged occupancy p50 / p95 | 0.19 / 0.56 " \
            "(17 span tokens) |" in r.stdout
+    assert "| prefill rows a step p50 / p95 / max | 4 / 27 / 27 |" \
+        in r.stdout
+    assert "| prefill steps to first token p50 / p95 / max | 2 / 3 / 3 |" \
+        in r.stdout
     summary = json.loads(r.stdout.strip().splitlines()[-1])
     sv = summary["serving"]
+    assert sv["prefill_rows_p95"] == 27.0 and sv["prefill_steps_p95"] == 3.0
     assert sv["requests"] == 3 and sv["steps"] == 4
     assert sv["tokens"] == 9
     assert sv["finished"] == {"eos": 1, "length": 1}

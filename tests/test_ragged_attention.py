@@ -103,6 +103,18 @@ class TestRaggedKernelVsOracle:
         _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
                                              lens), lens)
 
+    def test_fan_rows_share_one_table(self):
+        """Span fan-out (serving/scheduler.py): the rows of one prompt
+        carry ONE block table and consecutive starts, across a block
+        boundary; each row reads its own prefix through it."""
+        q, kp, vp, tables, starts, lens = _case(
+            B=4, MB=16, NB=64, starts=[112, 120, 128, 136],
+            lens=[8, 8, 8, 3])
+        tables = np.repeat(tables[:1], 4, axis=0)
+        got = _run_kernel(q, kp, vp, tables, starts, lens)
+        _assert_live_rows_close(got, _oracle(q, kp, vp, tables, starts,
+                                             lens), lens)
+
     # What the block schedule can get wrong.  A KV block is
     # ``_pages_per_block`` pages = 128 positions at these shapes (8 pages
     # of 16, 2 of 64), so MB = 16 pages of 16 is two blocks.

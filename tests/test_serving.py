@@ -200,6 +200,93 @@ class TestScheduler:
         assert s.admit_next() is not None
 
 
+class TestSpanFanOutPlan:
+    """``plan_spans`` alone: a prefilling request takes the rows of
+    slots that hold no request (ISSUE 31)."""
+
+    def _sched(self, max_batch, prompts, page=8, mb=32):
+        a = BlockAllocator(256)
+        s = Scheduler(max_batch=max_batch, page_size=page,
+                      max_blocks_per_seq=mb, allocator=a, oob_block=256)
+        sts = []
+        for n in prompts:
+            s.submit(Request(prompt_ids=_prompt(n), max_new_tokens=4))
+            sts.append(s.admit_next())
+        return s, sts
+
+    @pytest.mark.parametrize("plen,free,budget,want", [
+        # (prompt, free rows, budget) -> the fan's (start, n) in order
+        (100, 3, None, [(0, 4), (4, 4), (8, 4), (12, 4)]),
+        (10, 3, None, [(0, 4), (4, 4), (8, 2)]),      # ends with the prompt
+        (100, 3, 10, [(0, 4), (4, 4), (8, 2)]),       # ends with the budget
+        (100, 3, 4, [(0, 4)]),                        # one chunk: no fan-out
+        (3, 3, None, [(0, 3)]),                       # fits its own row
+        (100, 0, None, [(0, 4)]),                     # no free row
+    ])
+    def test_one_prompt_over_free_rows(self, plen, free, budget, want):
+        s, (st,) = self._sched(free + 1, [plen])
+        plan = s.plan_spans(chunk=4, budget=budget)
+        assert [(sp.start, sp.n) for sp in plan] == want
+        assert all(sp.st is st and sp.is_prefill for sp in plan)
+        assert plan[0].row == st.slot
+        assert len({sp.row for sp in plan}) == len(plan)
+        assert sum(sp.n for sp in plan) <= (budget or (free + 1) * 4)
+        assert plan[-1].start + plan[-1].n <= plen
+        tokens, tables, starts, lens, temps, seeds, emit, adapters = \
+            s.span_arrays(plan, 4)
+        for sp in plan:
+            assert (tables[sp.row] == st.table).all()
+            assert starts[sp.row] == sp.start and lens[sp.row] == sp.n
+            assert (tokens[sp.row, :sp.n] ==
+                    st.request.prompt_ids[sp.start:sp.start + sp.n]).all()
+            assert seeds[sp.row] == st.sample_seed
+        idle = sorted(set(range(free + 1)) - {sp.row for sp in plan})
+        assert (lens[idle] == 0).all() and (tables[idle] == 256).all()
+
+    def test_decode_and_draft_rows_untouched(self):
+        """Decode slots keep their own row, their draft and nothing
+        else; only rows of EMPTY slots are dealt out, and the fan's
+        sampling policy and adapter ride every row of it."""
+        s, (dec, spec, pre) = self._sched(6, [5, 6, 40])
+        dec.kv_len, dec.pending_token = 5, 7
+        spec.kv_len, spec.pending_token, spec.draft = 6, 9, [1, 2]
+        pre.request.temperature, pre.request.adapter_slot = 0.5, 2
+        plan = s.plan_spans(chunk=4)
+        by_row = {sp.row: sp for sp in plan}
+        assert by_row[0] == (0, dec, 5, 1, False)
+        assert by_row[1] == (1, spec, 6, 3, False)
+        fan = [sp for sp in plan if sp.st is pre]
+        assert [sp.row for sp in fan] == [2, 3, 4, 5]
+        assert [sp.start for sp in fan] == [0, 4, 8, 12]
+        assert [[sp.row for sp in g] for g in serving.scheduler.by_request(
+            plan)] == [[0], [1], [2, 3, 4, 5]]
+        tokens, _t, starts, lens, temps, _s, emit, adapters = \
+            s.span_arrays(plan, 4, spec_emit=True)
+        assert list(tokens[1, :3]) == [9, 1, 2] and lens[1] == 3
+        assert (temps[2:] == 0.5).all() and (adapters[2:] == 2).all()
+        assert (temps[:2] == 0).all() and (adapters[:2] == 0).all()
+
+    @pytest.mark.parametrize("order", [(0, 1), (1, 0)])
+    def test_two_prompts_oldest_admission_first(self, order):
+        """The free rows finish the OLDEST prompt first (interleaving
+        two prompts delays both first tokens), whatever slot it sits
+        in; the younger one takes what is left."""
+        s, sts = self._sched(6, [9, 9])       # 9 tokens: 3 rows each
+        old, young = sts[order[0]], sts[order[1]]
+        old.admit_seq, young.admit_seq = 0, 1
+        plan = s.plan_spans(chunk=4)
+        rows = {id(st): [(sp.row, sp.start, sp.n) for sp in plan
+                         if sp.st is st] for st in sts}
+        assert rows[id(old)] == [(old.slot, 0, 4), (2, 4, 4), (3, 8, 1)]
+        assert rows[id(young)] == [(young.slot, 0, 4), (4, 4, 4),
+                                   (5, 8, 1)]
+        # one free row fewer: the younger prompt's tail waits
+        s2, sts2 = self._sched(5, [9, 9])
+        plan2 = s2.plan_spans(chunk=4)
+        assert sum(sp.n for sp in plan2 if sp.st is sts2[0]) == 9
+        assert sum(sp.n for sp in plan2 if sp.st is sts2[1]) == 8
+
+
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
@@ -800,6 +887,171 @@ class TestTypedAdmissionErrors:
             eng.add_request(_prompt(4), max_new_tokens=2,
                             request_id="dup")
         eng.run()
+
+
+class TestSpanFanOut:
+    """The engine with span fan-out: the same chunks against the same
+    prefixes as an engine that cannot fan out
+    (``prefill_token_budget=prefill_chunk``), in fewer steps."""
+
+    KW = dict(max_batch=4, max_seq_len=128, page_size=8, prefill_chunk=4)
+
+    def _ref(self, model, p, m):
+        return list(np.asarray(model.generate(
+            jnp.asarray(p)[None], max_new_tokens=m,
+            temperature=0.0))[0, len(p):])
+
+    def _churn(self, eng, prompts, new, **kw):
+        """A decoding request, then a long prompt that meets free rows,
+        then the rest; returns (outputs, steps)."""
+        rids = [eng.add_request(prompts[0], max_new_tokens=new[0], **kw)]
+        steps = 0
+        for _ in range(3):
+            eng.step()
+            steps += 1
+        rids += [eng.add_request(p, max_new_tokens=m, **kw)
+                 for p, m in zip(prompts[1:], new[1:])]
+        while eng.has_work():
+            eng.step()
+            steps += 1
+        assert eng.kv_blocks_used == 0
+        return [eng.output_ids(r) for r in rids], steps
+
+    @pytest.mark.parametrize("kind", ["fp", "int8", "spec", "spec-int8",
+                                      "lora", "temperature"])
+    def test_greedy_identity_with_and_without_fan_out(self, kind):
+        from paddle_tpu.models.llama import llama
+        pt.seed(0)
+        model = llama("tiny")
+        kw, req_kw, ref_model = dict(self.KW), {}, model
+        if "int8" in kind:
+            kw["kv_cache_dtype"] = "int8"
+        if "spec" in kind:
+            kw.update(spec_decode=True, draft_depth=3)
+        if kind == "lora":
+            from paddle_tpu.serving import (LoRAPool, merge_adapter,
+                                            random_adapter)
+            ws = random_adapter(model, rank=8,
+                                rng=np.random.default_rng(7), scale=0.05)
+            kw["lora"] = LoRAPool(model, max_adapters=1, rank=8)
+            kw["lora"].load("a", ws)
+            req_kw = {"adapter": "a"}
+            pt.seed(0)
+            ref_model = llama("tiny")
+            merge_adapter(ref_model, ws)
+        if kind == "temperature":    # the stream is keyed per emit index
+            req_kw = {"temperature": 0.8}
+        motif = np.tile(_prompt(5), 6)        # drafts hit on this one
+        prompts = [motif, _prompt(70), _prompt(9), _prompt(33)]
+        new = [12, 6, 5, 4]
+        fan, steps_fan = self._churn(
+            serving.Engine(model, **kw).warmup(), prompts, new, **req_kw)
+        one, steps_one = self._churn(
+            serving.Engine(model, prefill_token_budget=4, **kw).warmup(),
+            prompts, new, **req_kw)
+        assert fan == one
+        assert steps_fan < steps_one
+        if "int8" not in kind and kind != "temperature":
+            # (generate() keeps a float cache and draws another stream)
+            for p, m, out in zip(prompts, new, fan):
+                assert out == self._ref(ref_model, p, m)
+
+    @pytest.mark.parametrize("plen,max_batch,chunk,steps", [
+        (100, 4, 4, 7),              # ceil(100 / 16)
+        (100, 1, 4, 25),             # no free row: one chunk a step
+        (33, 8, 4, 2),               # 32 lanes, then the last token
+        (7, 4, 8, 1),
+    ])
+    def test_steps_to_first_token(self, tiny_llama, plen, max_batch,
+                                  chunk, steps):
+        eng = serving.Engine(tiny_llama, max_batch=max_batch,
+                             max_seq_len=128, page_size=8,
+                             prefill_chunk=chunk).warmup()
+        p = _prompt(plen)
+        rid = eng.add_request(p, max_new_tokens=3)
+        n = 0
+        while not eng.output_ids(rid):
+            eng.step()
+            n += 1
+        assert n == steps and eng._states[rid].prefill_steps == steps
+        eng.run()
+        assert eng.output_ids(rid) == self._ref(tiny_llama, p, 3)
+
+    def test_fan_across_a_borrowed_page_copies_it_once(self, tiny_llama):
+        """A fan whose rows all write into one borrowed page privatises
+        it once: the request, not the row, owns the copy."""
+        eng = serving.Engine(tiny_llama, max_batch=4, max_seq_len=64,
+                             page_size=16, prefill_chunk=4).warmup()
+        p = _prompt(32)                           # exactly 2 pages
+        r1 = eng.add_request(p, max_new_tokens=5)
+        eng.run()
+        r2 = eng.add_request(p, max_new_tokens=5)
+        eng._admit_all()
+        st = eng._states[r2]
+        assert st.borrowed == {1} and st.kv_len == 31
+        # rewind to the borrowed page's start, as a re-run of its prefill
+        # would: the fan's four rows all land in page 1
+        st.kv_len = 16
+        eng.step()
+        assert st.kv_len == 32 and not st.borrowed
+        assert eng.prefix_stats()["cow_copies"] == 1
+        eng.run()
+        assert eng.output_ids(r2) == eng.output_ids(r1) \
+            == self._ref(tiny_llama, p, 5)
+        assert eng.kv_blocks_used == 0
+
+    @pytest.mark.parametrize("at", [0, 1, 2])
+    def test_prefill_fault_rewinds_the_whole_fan(self, tiny_llama, at):
+        from paddle_tpu import resilience as rs
+        eng = serving.Engine(tiny_llama, **self.KW).warmup()
+        p = _prompt(40)                           # 16 + 16 + 8
+        inj = rs.install_faults(f"serve.prefill@{at}")
+        try:
+            rid = eng.add_request(p, max_new_tokens=6)
+            for _ in range(at):
+                eng.step()
+            before = eng._states[rid].kv_len
+            assert before == 16 * at
+            with pytest.warns(RuntimeWarning, match="isolated"):
+                eng.step()
+            st = eng._states[rid]
+            assert st.kv_len == before and st.preempts == 1
+            eng.run()
+        finally:
+            rs.clear_faults()
+        assert [s for s, _ in inj.fired] == ["serve.prefill"]
+        assert eng.output_ids(rid) == self._ref(tiny_llama, p, 6)
+        assert eng.kv_blocks_used == 0
+
+    def test_zero_compiles_and_histograms(self, tiny_llama):
+        """A mix that fans out compiles nothing after warmup(), and the
+        two histograms say how often the fan engaged."""
+        import paddle_tpu.observability as obs
+        tel = obs.enable(sinks=[obs.InMemorySink()], crash_hooks=False)
+        try:
+            eng = serving.Engine(tiny_llama, **self.KW).warmup()
+            c0 = tel.sentinel.compiles()
+            prompts = [_prompt(6), _prompt(70), _prompt(9), _prompt(33),
+                       _prompt(50)]
+            new = [12, 6, 5, 4, 3]
+            outs, _ = self._churn(eng, prompts, new)
+            assert tel.sentinel.compiles() - c0 == 0
+            assert eng._step_fn._cache_size() == 1
+            snap = tel.registry.snapshot()
+            rows, steps = snap["serve.prefill_rows"], \
+                snap["serve.prefill_steps"]
+            assert steps["count"] == len(prompts)
+            assert rows["count"] == steps["sum"]
+            assert rows["max"] > 1 and steps["max"] < -(-70 // 4)
+            # every prompt token was dealt to exactly one row
+            occ = snap["serve.ragged_occupancy"]
+            n_dec = sum(new) - len(prompts)
+            assert round(occ["sum"] * 16) == \
+                sum(len(p) for p in prompts) + n_dec
+        finally:
+            obs.disable()
+        for p, m, out in zip(prompts, new, outs):
+            assert out == self._ref(tiny_llama, p, m)
 
 
 class TestFaultIsolation:

@@ -393,7 +393,8 @@ class TestEngineTracing:
             _assert_exact_sum(tl)
             s = tl["summary"]
             assert s["done"] and s["decode_tokens"] == len(outs[rid])
-        # the 20-token prompt prefilled in 8-token chunks: 3 chunks
+        # the 20-token prompt prefilled in 8-token chunks, one a step
+        # (both slots hold a request, so no free row to fan out over)
         assert tr.timeline(rids[0])["summary"]["prefill_chunks"] == 3
         # phase histograms + per-tenant aggregates landed
         snap = tel.registry.snapshot()

@@ -579,6 +579,18 @@ def render(agg, malformed=0):
             lines.append(f"| ragged occupancy p50 / p95 | "
                          f"{fmt(occ.get('p50'))} / {fmt(occ.get('p95'))} "
                          f"({sv['span_tokens']} span tokens) |")
+        # span fan-out (docs/SERVING.md "Step anatomy"): rows a
+        # prefilling request held in a step (1 = no fan-out), and steps
+        # from a request's first chunk to its first token
+        for label, key in (("prefill rows a step", "serve.prefill_rows"),
+                           ("prefill steps to first token",
+                            "serve.prefill_steps")):
+            h = m.get(key) or {}
+            if h:
+                lines.append(f"| {label} p50 / p95 / max | "
+                             f"{fmt(h.get('p50'), 0)} / "
+                             f"{fmt(h.get('p95'), 0)} / "
+                             f"{fmt(h.get('max'), 0)} |")
         # speculative decoding (docs/SERVING.md "Speculative decoding"):
         # acceptance-rate column from the serve.spec.* counters, accept
         # length distribution from the histogram
@@ -944,6 +956,10 @@ def main(argv=None) -> int:
             "span_tokens": sv["span_tokens"],
             "ragged_occupancy_p50": occ.get("p50"),
             "ragged_occupancy_p95": occ.get("p95"),
+            "prefill_rows_p95":
+                (m.get("serve.prefill_rows") or {}).get("p95"),
+            "prefill_steps_p95":
+                (m.get("serve.prefill_steps") or {}).get("p95"),
             "preempts": sv["preempts"],
             "restores": sv["restores"],
             "swapped_pages": sv["swapped_pages"],
