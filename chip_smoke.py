@@ -352,11 +352,15 @@ def serve_step_hlo(eng, sharding=None) -> str:
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
+    # what the cache kind adds to the step's inputs (None for one table)
+    aux = eng.cache_spec.aux_shapes(b, c)
+    if aux is not None:
+        aux = {k: i32(*shape) for k, (shape, _) in aux.items()}
     return eng._step_fn.lower(
         jax.tree.map(on, eng.params), jax.tree.map(on, eng.kv.caches),
         i32(b, c), i32(b, mb), i32(b), i32(b),
         jax.ShapeDtypeStruct((b,), jnp.float32, sharding=sharding),
-        on(eng._key), i32(b), i32(b), None, i32(b)).compile().as_text()
+        on(eng._key), i32(b), i32(b), None, i32(b), aux).compile().as_text()
 
 
 def serve_phase(preset: str, layers: int, *, prompt_lens=(5, 23, 61),

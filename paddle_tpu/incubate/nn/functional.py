@@ -570,12 +570,16 @@ def _paged_span_write(cache, k, v, block_tables, span_starts, span_lens):
             vc.at[blk, off].set(v.astype(vc.dtype)))
 
 
-def _ragged_attend_dense(q, k, v, span_starts, scale):
+def _ragged_attend_dense(q, k, v, span_starts, scale, skips=None, page=None):
     """Span attention over dense gathered (B, S, H_kv, D) K/V: query row
     ``j`` of slot ``b`` (position ``span_starts[b] + j``) attends over
     positions ``[0, span_starts[b] + j]``.  GQA without repeating KV,
     fp32 accumulation — the (B, C)-shaped analogue of
-    :func:`_attend_dense_gqa` (shared by the ragged fallbacks)."""
+    :func:`_attend_dense_gqa` (shared by the ragged fallbacks).  With
+    ``skips`` ``(B,)`` slot ``b`` sees nothing from position
+    ``skips[b]`` to the end of the ``page`` it lies in (the XLA
+    composition of the ragged kernel's operand of that name:
+    :func:`eva_paged_attend`)."""
     b, c, h, d = q.shape
     s = k.shape[1]
     h_kv = k.shape[2]
@@ -587,6 +591,10 @@ def _ragged_attend_dense(q, k, v, span_starts, scale):
     # position 0 is always visible (pos >= 0), so no row softmaxes over
     # an empty set — dead rows produce finite garbage the caller discards
     mask = jnp.arange(s)[None, None, :] <= pos[:, :, None]    # (B, C, S)
+    if skips is not None:
+        key, lo = jnp.arange(s)[None, :], skips[:, None]
+        seen = (key < lo) | (key >= -(-lo // page) * page)    # (B, S)
+        mask = mask & seen[:, None, :]
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bckgs,bskd->bckgd", probs, v.astype(jnp.float32),
@@ -641,6 +649,105 @@ def ragged_paged_attend(cache, q, new_k, new_v, block_tables, span_starts,
             return out, new_cache
     kd, vd = _paged_gather_dense(kc, vc, block_tables)
     return _ragged_attend_dense(q, kd, vd, span_starts, scale), new_cache
+
+
+def eva_chunk_summaries(k, v, phi, mu, scale):
+    """EVA's chunk summariser (``models/evabyte.py``): ``k``, ``v``
+    ``(..., c, H, D)``, the ``c`` positions of one chunk a leading index;
+    ``phi``, ``mu`` ``(H, D)``.  Weights ``softmax over the chunk of
+    (scale * phi_h . k_m)``; returns ``(ktilde = weighted keys + mu,
+    vtilde = weighted values)``, each ``(..., H, D)`` in float32."""
+    p = _prec(k.dtype)
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    sc = jnp.einsum("hd,...mhd->...mh", phi.astype(jnp.float32), kf,
+                    precision=p) * scale
+    w = jax.nn.softmax(sc, axis=-2)
+    kt = jnp.einsum("...mh,...mhd->...hd", w, kf, precision=p) \
+        + mu.astype(jnp.float32)
+    vt = jnp.einsum("...mh,...mhd->...hd", w, vf, precision=p)
+    return kt, vt
+
+
+def _eva_write_summaries(kc, vc, block_tables, cache_starts, summary_dst,
+                         phi, mu, scale):
+    """Summarise every chunk that a row's span completes, from the page as
+    the pool holds it, and write ``(ktilde, vtilde)`` at its summary row.
+
+    ``summary_dst`` ``(B, M)``: slot ``i`` of row ``b`` is the chunk whose
+    window page is entry ``cache_starts[b] // page + i`` of the row's
+    table; its value is ``block * page + row`` of the summary's place in
+    the pool, or ``num_blocks * page`` where the span does not end that
+    chunk (the write drops to the out-of-range block, as a dead slot's
+    do)."""
+    nb, page = kc.shape[:2]
+    mb = block_tables.shape[1]
+    m = summary_dst.shape[1]
+    idx = cache_starts[:, None] // page + jnp.arange(m)[None, :]
+    src = jnp.take_along_axis(block_tables, jnp.minimum(idx, mb - 1), axis=1)
+    # an out-of-range table entry clamps to a real page under the gather;
+    # what is computed from it goes nowhere
+    kt, vt = eva_chunk_summaries(kc[src], vc[src], phi, mu, scale)
+    blk, row = summary_dst // page, summary_dst % page
+    return (kc.at[blk, row].set(kt.astype(kc.dtype)),
+            vc.at[blk, row].set(vt.astype(vc.dtype)))
+
+
+def eva_paged_attend(cache, q, new_k, new_v, block_tables, span_lens, aux,
+                     phi, mu, scale: Optional[float] = None):
+    """ONE serving step of EVA, chunked linearized attention
+    (``models/evabyte.py``), for a ragged batch of token spans, beside
+    :func:`ragged_paged_attend`.
+
+    A request's cache is two kinds of page of ONE geometry in one pool:
+    exact k/v pages of its open window, and summary pages holding one
+    ``(ktilde, vtilde)`` row per completed chunk.  A row's table lists
+    the summary pages that hold a row of a window before the span's,
+    then the pages of the span's own window.  ``aux`` is what
+    ``serving.block_allocator.WindowSummarySpec.step_aux`` made of the
+    step's plan: ``summary_rows`` ``(B,)``, the summaries the row's
+    queries see, ``(W / c) * w`` of them; ``cache_starts`` ``(B,)``, the
+    span's start counted along the table (the summary pages, then the
+    span's offset in its window); ``summary_dst`` ``(B, C / c)``, where
+    the summaries of the chunks that the span completes go.  Causal
+    attention along the table, but for the rows of the last summary page
+    from ``summary_rows`` on, is then EVA's: every summary of an earlier
+    window, the own window's keys up to the query, one softmax.  The
+    step, in order:
+
+    1. write the spans' k/v at ``[cache_starts, cache_starts + lens)``;
+    2. for every chunk a span completes, summarise it from the page as
+       the pool now holds it and write the summary's row: a later row of
+       the same step, past a window boundary, reads it in step 3;
+    3. attend: the ragged kernel over the row's table on TPU (its events
+       are named ``eva_ragged_paged_attention``), the XLA gather
+       composition elsewhere.
+
+    ``q``/``new_k``/``new_v`` are ``(B, C, H, D)``; ``phi``/``mu``
+    ``(H, D)``.  Returns ``(out (B, C, H, D), new_cache)``.
+    """
+    if len(cache) != 2:
+        raise NotImplementedError(
+            "EVA's window+summary cache has float pools only: a summary "
+            "row has no per-position scale to be quantized with")
+    d = q.shape[-1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    starts, rows = aux["cache_starts"], aux["summary_rows"]
+    kc, vc = _paged_span_write(cache, new_k, new_v, block_tables, starts,
+                               span_lens)
+    with jax.named_scope("eva_summarise"):
+        kc, vc = _eva_write_summaries(kc, vc, block_tables, starts,
+                                      aux["summary_dst"], phi, mu, scale)
+    with jax.named_scope("eva_attend"):
+        from ...ops import dispatch as _dispatch
+        kernel = _dispatch.get("eva_ragged_paged_attention")
+        if kernel is not None:
+            out = kernel(q.astype(kc.dtype), kc, vc, block_tables, starts,
+                         span_lens, rows, scale=scale)
+            if out is not None:
+                return out.astype(q.dtype), (kc, vc)
+        kd, vd = _paged_gather_dense(kc, vc, block_tables)
+        return (_ragged_attend_dense(q, kd, vd, starts, scale, skips=rows,
+                                     page=kc.shape[1]), (kc, vc))
 
 
 def paged_copy_blocks(cache, src_blocks, dst_blocks):

@@ -4,6 +4,8 @@
 - gpt: GPT/ERNIE-style decoder (13B TP+PP config)
 - moe: Mixtral-style mixture-of-experts (expert parallel)
 - sdxl_unet: Stable-Diffusion-XL UNet (conv/GroupNorm/attention breadth)
+- evabyte: EvaByte, a byte-level LM on EVA chunked linearized attention
+  (window pages beside chunk summaries in the serving cache)
 """
 
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, PRESETS,  # noqa: F401
@@ -12,6 +14,6 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, PRESETS,  # noqa:
 
 def __getattr__(name):
     import importlib
-    if name in ("gpt", "moe", "sdxl_unet"):
+    if name in ("gpt", "moe", "sdxl_unet", "evabyte"):
         return importlib.import_module(f".{name}", __name__)
     raise AttributeError(name)

@@ -98,6 +98,22 @@ def _ragged_paged_attention_dispatch(q, k_pool, v_pool, block_tables,
 dispatch.register("ragged_paged_attention", _ragged_paged_attention_dispatch,
                   platform="tpu")
 
+
+def _eva_ragged_paged_attention_dispatch(q, k_pool, v_pool, block_tables,
+                                         starts, lens, skips, scale=None):
+    """EVA's attention (``incubate.nn.functional.eva_paged_attend``): the
+    ragged kernel over a table of summary pages then window pages, under
+    a name of its own."""
+    if not _ra.supported(q, k_pool, v_pool, block_tables, starts, lens):
+        return None  # caller falls back to the XLA gather formulation
+    return _ra.ragged_paged_attention(q, k_pool, v_pool, block_tables,
+                                      starts, lens, scale=scale, skips=skips,
+                                      name="eva_ragged_paged_attention")
+
+
+dispatch.register("eva_ragged_paged_attention",
+                  _eva_ragged_paged_attention_dispatch, platform="tpu")
+
 # -- fused-kernel library (docs/KERNELS.md) ---------------------------------
 # Each dispatch returns None when the kernel cannot serve (shape gate or
 # an active mesh — GSPMD cannot auto-partition Mosaic kernels) and the

@@ -62,12 +62,16 @@ def _pages_per_block(page, h_kv, d, dtype, mb):
     return max(1, min(by_vmem, BLOCK_TOKENS // page, mb))
 
 
-def _kernel(tables_ref, starts_ref, lens_ref,   # scalar prefetch
-            q_ref, k_hbm, v_hbm,                # q block; pools left in HBM
-            o_ref,                              # out block
-            k_buf, v_buf, sems,                 # double-buffered KV blocks
-            m_scr, l_scr, acc_scr,              # online-softmax state
-            *, page, ppb, scale, g):
+def _kernel(*refs, page, ppb, scale, g, skip):
+    # scalar prefetch (tables, starts, lens and, with ``skip``, skips);
+    # the q block; the pools, left in HBM; the out block; the
+    # double-buffered KV blocks; the online-softmax state
+    (tables_ref, starts_ref, lens_ref), refs = refs[:3], refs[3:]
+    skips_ref = None
+    if skip:
+        skips_ref, refs = refs[0], refs[1:]
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+     m_scr, l_scr, acc_scr) = refs
     b = pl.program_id(0)
     rows = acc_scr.shape[1]
     blk = ppb * page
@@ -117,7 +121,14 @@ def _kernel(tables_ref, starts_ref, lens_ref,   # scalar prefetch
         for cp in copies(i, buf):
             cp.wait()
         # causal vs the pool: row j sees positions [0, start + j]
-        live = i * blk + col <= start + j_row
+        pos = i * blk + col
+        live = pos <= start + j_row
+        if skips_ref is not None:
+            # ... but for the rest of the page that position skips[b]
+            # lies in: rows of a summary page that are not for this
+            # query (``eva_paged_attend``); nothing where it is aligned
+            lo = skips_ref[b]
+            live = live & ((pos < lo) | (pos >= pl.cdiv(lo, page) * page))
         q = q_ref[0].astype(k_buf.dtype)                  # (H_kv, C*G, D)
         k = jnp.swapaxes(k_buf[buf], 0, 1)                # (H_kv, blk, D)
         v = jnp.swapaxes(v_buf[buf], 0, 1)
@@ -142,10 +153,23 @@ def _kernel(tables_ref, starts_ref, lens_ref,   # scalar prefetch
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
-                           scale=None, interpret=False):
+                           scale=None, interpret=False, skips=None,
+                           name="ragged_paged_attention"):
     """q (B, C, H, D) spans × paged KV pools → (B, C, H, D).
 
     ``interpret=True`` runs the kernel in the Pallas interpreter (CPU CI).
+
+    ``skips`` and ``name`` serve EVA's step
+    (``incubate.nn.functional.eva_paged_attend``), which walks a table of
+    summary pages then window pages through this kernel.  ``skips``
+    ``(B,)`` is a fourth scalar-prefetch operand: slot ``b`` sees nothing
+    from position ``skips[b]`` to the end of the page it lies in (the
+    rows of the last summary page that are not yet for this query; none
+    where ``skips[b]`` is a multiple of the page).  Without it the
+    program is the one a full-attention model has always had.  ``name``
+    is the ``pallas_call``'s, hence its device events':
+    ``eva_ragged_paged_attention`` there, so that a trace tells them from
+    a full-attention model's.
     """
     b, c, h, d = q.shape
     _, page, h_kv, _ = k_pool.shape
@@ -159,15 +183,18 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
     qg = q.reshape(b, c, h_kv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, h_kv, rows, d)
 
-    def slot_map(ib, tables, starts_, lens_):
+    def slot_map(ib, *prefetch):
         return (ib, 0, 0, 0)
 
+    prefetch = (block_tables, starts, lens) \
+        + (() if skips is None else (skips,))
     kernel = functools.partial(_kernel, page=page, ppb=ppb,
-                               scale=float(scale), g=g)
+                               scale=float(scale), g=g,
+                               skip=skips is not None)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, h_kv, rows, d), slot_map),
@@ -186,10 +213,30 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, lens,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, rows, d), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
-    )(block_tables, starts, lens, qg, k_pool, v_pool)
+        name=name,
+    )(*prefetch, qg, k_pool, v_pool)
     return out.reshape(b, h_kv, c, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, c, h, d)
+
+
+# Mosaic grants a kernel 16 MiB of scoped VMEM unless it asks for more
+# (``_common.py``), and this kernel does not ask
+SCOPED_VMEM = 16 * 2 ** 20
+
+
+def vmem_bytes(c, h, h_kv, d, page, mb, dtype) -> int:
+    """What one grid step holds in VMEM: the double-buffered K and V
+    blocks and their head-major copies, the q and o blocks (each
+    double-buffered by the pipeline), the running (m, l, acc) and the
+    score-sized temporaries (s, p, the mask).  With 32 kv heads a page
+    is 128 KiB a pool, so the blocks alone take 6 MiB of it."""
+    item = jnp.dtype(dtype).itemsize
+    rows = c * (h // h_kv)
+    block = _pages_per_block(page, h_kv, d, dtype, mb) * page * h_kv * d * item
+    qo = h_kv * rows * d * item
+    state = h_kv * rows * (d + 2) * 4
+    scores = h_kv * rows * min(BLOCK_TOKENS, mb * page) * 4
+    return 4 * block + 2 * block + 4 * qo + state + 3 * scores
 
 
 def supported(q, k_pool, v_pool, block_tables, starts, lens) -> bool:
@@ -198,6 +245,14 @@ def supported(q, k_pool, v_pool, block_tables, starts, lens) -> bool:
     b, c, h, d = q.shape
     h_kv = k_pool.shape[2]
     page = k_pool.shape[1]
+    if h % h_kv:
+        return False
+    # deviceless v5e compiles refuse a span of 256 at GQA 32/8 and of 128
+    # at 32 kv heads (PR 26, PR 32): what a grid step holds passes the
+    # scoped limit there
+    if vmem_bytes(c, h, h_kv, d, page, block_tables.shape[1],
+                  k_pool.dtype) > SCOPED_VMEM:
+        return False
     # same page-size gates as the decode kernel (v5e sweep 2026-07-30:
     # page=32 triggers a Mosaic layout pathology and is excluded)
     page_ok = page == 16 or page % 64 == 0

@@ -49,7 +49,8 @@ import jax.numpy as jnp
 
 from ..resilience import _state as _rs_state
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "SwapManager"]
+__all__ = ["BlockAllocator", "PagedKVCache", "PagedKVSpec", "PrefixCache",
+           "SwapManager", "WindowSummarySpec", "cache_spec_of"]
 
 
 class BlockAllocator:
@@ -251,6 +252,284 @@ class PrefixCache:
                 "hit_rate": (self.hits / probes) if probes else 0.0,
                 "registered_pages": len(self._blocks),
                 "evictions": self.allocator.evictions}
+
+
+class PagedKVSpec:
+    """The cache of full causal attention: ONE block table a request,
+    whose pages only grow, ``ceil(positions / page)`` of them.  Every
+    page a request can ever write is reserved at admission
+    (``scheduler.py``), so growth and closing are no-ops here.
+
+    A cache spec is what allocation, swap and accounting ask about a
+    request's pages, whatever kind they are: how many a request of ``n``
+    positions is reckoned at, which hold content (and in what order they
+    travel to host and back), what a row of the step's table holds."""
+
+    kind = "kv"
+    reserves_ahead = True       # every page a request can write, at admission
+
+    def __init__(self, page_size: int, table_width: int):
+        self.page_size = int(page_size)
+        self.table_width = int(table_width)
+        self.swap_chunk = self.table_width
+
+    def blocks_for(self, total_len: int) -> int:
+        return -(-int(total_len) // self.page_size)
+
+    def span_room(self, pos: int) -> Optional[int]:
+        """Positions a span starting at ``pos`` may hold (None: any)."""
+        return None
+
+    def held_ids(self, st) -> List[int]:
+        """The pages that hold ``st``'s content, in the order a swap
+        payload lists them."""
+        return [int(b) for b in st.table[:self.blocks_for(st.kv_len)]]
+
+    def restore(self, st, allocator: BlockAllocator, oob: int) -> bool:
+        """Private pages for a swapped request's whole budget (the host
+        payload is scattered into the first of them); False when the
+        pool cannot give them."""
+        total = self.blocks_for(st.total_len)
+        if not allocator.can_allocate(total):
+            return False
+        st.blocks = allocator.allocate(total)
+        st.table = np.full((self.table_width,), oob, np.int32)
+        st.table[:total] = st.blocks
+        return True
+
+    def table_row(self, st, start: int, oob: int) -> np.ndarray:
+        return st.table
+
+    def step_aux(self, plan, max_batch: int, chunk: int, oob: int):
+        """Further inputs of the step that the cache kind needs (None)."""
+        return None
+
+    def aux_shapes(self, max_batch: int, chunk: int):
+        return None
+
+
+class WindowSummaryPages:
+    """One request's pages under :class:`WindowSummarySpec`: the exact
+    k/v pages of each window still held, by window, in position order,
+    and the summary pages, in chunk order."""
+
+    __slots__ = ("windows", "summaries")
+
+    def __init__(self):
+        self.windows: Dict[int, List[int]] = {}
+        self.summaries: List[int] = []
+
+
+class WindowSummarySpec:
+    """The cache of EVA, chunked linearized attention
+    (``models/evabyte.py``): TWO tables a request over pages of one
+    geometry in one pool.
+
+    - **window pages**: the exact k/v of the OPEN window, ``chunk``
+      positions a page, at most ``window / chunk`` of them; allocated as
+      the window fills and returned to the allocator when it closes (in
+      the ``step_finish`` that consumed its last position);
+    - **summary pages**: one row per completed chunk, ``chunk`` rows a
+      page (so a page per ``chunk * chunk`` positions), growing for the
+      request's life.  A summary is read by queries of the windows AFTER
+      its own.
+
+    Both kinds are the same bytes, so one pool under one allocator serves
+    both: memory is not split ahead of the traffic, and swap moves pages
+    without knowing their kind.  Nothing is reserved ahead: admission
+    reckons a request of ``n`` positions at its peak,
+    ``min(n, window) / chunk`` window pages + ``ceil(n / chunk^2)``
+    summary pages, and compares it with what is free; pages are taken as
+    positions are written (:meth:`short` / :meth:`grow`, before each
+    step), and a pool that runs dry preempts its youngest request
+    (``Engine._grow_pages``).
+
+    A row of the step's table lists the summary pages that hold a row
+    of a window before the span's, then the span's window's pages;
+    positions along it (:meth:`cache_start`) are those pages' rows and
+    then the offset in window ``w``, so that causal attention along the
+    table, less the rows of the last summary page that belong to the
+    span's own window, is EVA's
+    (``incubate.nn.functional.eva_paged_attend``).
+    """
+
+    kind = "window+summary"
+    reserves_ahead = False      # pages are taken as positions are written
+
+    def __init__(self, window: int, chunk: int, max_seq_len: int):
+        self.window, self.page_size = int(window), int(chunk)
+        if self.window % self.page_size:
+            raise ValueError(f"window={window} is no multiple of "
+                             f"chunk={chunk}")
+        c = self.page_size
+        self.window_pages = self.window // c
+        self.summary_pages = -(-int(max_seq_len) // (c * c))
+        self.table_width = self.window_pages + self.summary_pages
+        # a swap program gathers and scatters this many pages at once
+        self.swap_chunk = min(self.table_width, 32)
+
+    def blocks_for(self, total_len: int) -> int:
+        n, c = int(total_len), self.page_size
+        return -(-min(n, self.window) // c) + -(-n // (c * c))
+
+    def span_room(self, pos: int) -> int:
+        """A span ends with its window: a row's table holds one."""
+        return self.window - pos % self.window
+
+    def _seen(self, w: int):
+        """(summary rows, summary pages) that window ``w``'s queries
+        see: one row per chunk of the windows before it."""
+        rows = self.window_pages * w
+        return rows, -(-rows // self.page_size)
+
+    def cache_start(self, start: int) -> int:
+        """Where position ``start`` lies along its row's table: past the
+        summary pages, at its offset in its window."""
+        w = start // self.window
+        return self._seen(w)[1] * self.page_size + start - w * self.window
+
+    # -- what a request holds ---------------------------------------------
+
+    def _held(self, kv_len: int):
+        """(summary pages, open window, its pages) that hold content."""
+        c = self.page_size
+        w = kv_len // self.window
+        return -(-(kv_len // c) // c), w, -(-(kv_len - w * self.window) // c)
+
+    def held_ids(self, st) -> List[int]:
+        n_sum, w, n_win = self._held(st.kv_len)
+        return st.pages.summaries[:n_sum] \
+            + st.pages.windows.get(w, [])[:n_win]
+
+    def counts(self, st):
+        """(window pages, summary pages) the request holds."""
+        return (sum(len(p) for p in st.pages.windows.values()),
+                len(st.pages.summaries))
+
+    def _want(self, st, end: int):
+        """Pages the request must hold before positions up to ``end``
+        are written: {window: pages}, summary pages."""
+        c = self.page_size
+        want = {}
+        for w in range(st.kv_len // self.window,
+                       (end - 1) // self.window + 1):
+            want[w] = -(-(min(end, (w + 1) * self.window)
+                          - w * self.window) // c)
+        return want, -(-(end // c) // c)
+
+    def short(self, st, end: int) -> int:
+        """Pages still to take before a step that writes up to ``end``."""
+        want, n_sum = self._want(st, end)
+        pg = st.pages
+        return sum(max(0, n - len(pg.windows.get(w, [])))
+                   for w, n in want.items()) \
+            + max(0, n_sum - len(pg.summaries))
+
+    def grow(self, st, end: int, allocator: BlockAllocator) -> int:
+        """Take them (the caller has seen that the pool can give them)."""
+        want, n_sum = self._want(st, end)
+        pg = st.pages
+        took = 0
+        for w, n in want.items():
+            have = pg.windows.setdefault(w, [])
+            if n > len(have):
+                new = allocator.allocate(n - len(have))
+                have.extend(new)
+                st.blocks.extend(new)
+                took += len(new)
+        if n_sum > len(pg.summaries):
+            new = allocator.allocate(n_sum - len(pg.summaries))
+            pg.summaries.extend(new)
+            st.blocks.extend(new)
+            took += len(new)
+        return took
+
+    def close(self, st, allocator: BlockAllocator):
+        """Return the pages of every window that ``st.kv_len`` has left
+        to the allocator.  (windows closed, pages freed)."""
+        pg = st.pages
+        closed = [w for w in pg.windows if (w + 1) * self.window <= st.kv_len]
+        freed = 0
+        for w in closed:
+            ids = pg.windows.pop(w)
+            allocator.free(ids)
+            gone = set(ids)
+            st.blocks = [b for b in st.blocks if b not in gone]
+            freed += len(ids)
+        return len(closed), freed
+
+    def seat(self, st) -> None:
+        st.pages = WindowSummaryPages()
+        st.blocks = []
+        st.table = None
+
+    def restore(self, st, allocator: BlockAllocator, oob: int) -> bool:
+        """Private pages for what the swapped request held, in
+        :meth:`held_ids` order; False when the pool cannot give the
+        request its peak (a restore that would be preempted again)."""
+        if not allocator.can_allocate(self.blocks_for(st.total_len)):
+            return False
+        n_sum, w, n_win = self._held(st.kv_len)
+        self.seat(st)
+        st.pages.summaries = allocator.allocate(n_sum)
+        if n_win:
+            st.pages.windows[w] = allocator.allocate(n_win)
+        st.blocks = self.held_ids(st)
+        return True
+
+    # -- what the step is given -------------------------------------------
+
+    def table_row(self, st, start: int, oob: int) -> np.ndarray:
+        w = start // self.window
+        row = np.full((self.table_width,), oob, np.int32)
+        n_sum = self._seen(w)[1]
+        row[:n_sum] = st.pages.summaries[:n_sum]
+        win = st.pages.windows[w]
+        row[n_sum:n_sum + len(win)] = win
+        return row
+
+    def aux_shapes(self, max_batch: int, chunk: int):
+        return {"cache_starts": ((max_batch,), np.int32),
+                "summary_rows": ((max_batch,), np.int32),
+                "summary_dst": ((max_batch, chunk // self.page_size),
+                                np.int32)}
+
+    def step_aux(self, plan, max_batch: int, chunk: int, oob: int):
+        """``cache_starts`` ``(B,)``: each row's start along its table.
+        ``summary_rows`` ``(B,)``: the summaries its queries see.
+        ``summary_dst`` ``(B, chunk / page)``: for each chunk the row's
+        span may complete, ``block * page + row`` of its summary's place,
+        or the out-of-range block's where the span does not end it."""
+        c = self.page_size
+        starts = np.zeros((max_batch,), np.int32)
+        rows = np.zeros((max_batch,), np.int32)
+        dst = np.full((max_batch, chunk // c), oob * c, np.int32)
+        for sp in plan:
+            starts[sp.row] = self.cache_start(sp.start)
+            rows[sp.row] = self._seen(sp.start // self.window)[0]
+            summ = sp.st.pages.summaries
+            for i in range(dst.shape[1]):
+                j = sp.start // c + i
+                if c * j + c - 1 < sp.start + sp.n:
+                    dst[sp.row, i] = summ[j // c] * c + j % c
+        return {"cache_starts": starts, "summary_rows": rows,
+                "summary_dst": dst}
+
+
+def cache_spec_of(model, page_size: int, max_seq_len: int):
+    """The cache spec of a CausalLM: the kind the model declares
+    (``kv_cache_spec()``), else the cache of full causal attention."""
+    declared = getattr(model, "kv_cache_spec", None)
+    if declared is None:
+        return PagedKVSpec(page_size, -(-int(max_seq_len) // int(page_size)))
+    d = declared()
+    if d["kind"] != WindowSummarySpec.kind:
+        raise NotImplementedError(f"unknown cache kind {d['kind']!r}")
+    if int(page_size) != int(d["chunk"]):
+        raise ValueError(
+            f"page_size={page_size}: a {d['kind']} cache holds one chunk "
+            f"a page, so page_size must be chunk_size={d['chunk']}")
+    return WindowSummarySpec(d["window"], d["chunk"], max_seq_len)
 
 
 class PagedKVCache:

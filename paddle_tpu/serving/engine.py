@@ -99,7 +99,8 @@ from ..observability.spans import span
 from ..nn.layer import _swapped_params, functional_call, serving_params
 from ..resilience import _state as _rs_state
 from ..resilience.retry import RetryPolicy
-from .block_allocator import PagedKVCache, PrefixCache, SwapManager
+from .block_allocator import (PagedKVCache, PrefixCache, SwapManager,
+                              cache_spec_of)
 from .errors import (AdmissionError, BudgetUnsatisfiable, QueueFull,
                      UnknownAdapter)
 from .scheduler import Request, RequestState, Scheduler, by_request
@@ -123,7 +124,11 @@ class TokenEvent(NamedTuple):
 
 
 def _kv_geometry(model):
-    """(num_layers, kv_heads, head_dim) from a CausalLM config."""
+    """(num_layers, kv_heads, head_dim) of a CausalLM's cache pages."""
+    declared = getattr(model, "kv_cache_spec", None)
+    if declared is not None:
+        d = declared()
+        return d["layers"], d["kv_heads"], d["head_dim"]
     cfg = model.cfg
     kv = getattr(cfg, "num_key_value_heads", None) or \
         cfg.num_attention_heads
@@ -283,6 +288,20 @@ class Engine:
     qkv/MLP projection path (the deltas inject pre-RoPE and around the
     activation, which the fused single-pass kernels cannot expose).
 
+    **Cache kinds** (docs/SERVING.md "Cache kinds").  What a request's
+    pages are is the model's to declare (``kv_cache_spec()``) and
+    ``serving/block_allocator.py``'s to answer for: full causal
+    attention's one growing table (``PagedKVSpec``: every model without
+    a declaration), or EVA's exact window pages beside chunk-summary
+    pages (``WindowSummarySpec``: ``models/evabyte.py``), which are
+    taken as positions are written and returned when a window closes.
+    The same entry points, scheduler, span fan-out and sampler serve
+    both; a feature that does not serve a kind yet raises
+    ``NotImplementedError`` HERE, naming both — on the window+summary
+    kind: prefix caching (so say ``enable_prefix_caching=False``),
+    ``role`` other than ``"both"``, ``spec_decode``, ``lora``,
+    ``weight_quant``, int8 pools and a ``mesh``.
+
     ``role``: disaggregated serving (docs/SERVING.md "Disaggregated
     serving").  ``"both"`` (default) is the colocated engine above.
     ``"prefill"`` retires every request at prefill-complete — the first
@@ -369,6 +388,33 @@ class Engine:
             raise ValueError(
                 f"max_seq_len={max_seq_len} exceeds the model's "
                 f"max_position_embeddings={max_pos}")
+        # the cache kind the model declares (block_allocator.py): full
+        # attention's one growing table, or EVA's window pages beside
+        # summary pages.  What does not serve a kind yet is refused HERE,
+        # by name, never ignored (docs/SERVING.md "Cache kinds")
+        cache_spec = cache_spec_of(model, page_size, max_seq_len)
+        if cache_spec.kind != "kv":
+            if prefill_chunk % cache_spec.page_size:
+                raise ValueError(
+                    f"prefill_chunk={prefill_chunk} must be a multiple of "
+                    f"the {cache_spec.kind} cache's chunk_size="
+                    f"{cache_spec.page_size} (= page_size={page_size}): a "
+                    "row's span completes whole chunks")
+            from ..models.generation import _is_int8
+            asked = {"prefix caching (enable_prefix_caching=True)":
+                     enable_prefix_caching,
+                     f"role={role!r}": role != "both",
+                     "spec_decode": spec_decode,
+                     "lora": lora is not None,
+                     "weight_quant": weight_quant is not None,
+                     "int8 pools (kv_cache_dtype)": _is_int8(kv_cache_dtype),
+                     "mesh": mesh is not None}
+            for feature, on in asked.items():
+                if on:
+                    raise NotImplementedError(
+                        f"{feature} does not serve a {cache_spec.kind} "
+                        f"cache ({type(model).__name__}) yet — "
+                        "docs/SERVING.md \"Cache kinds\"")
         if weight_quant is not None and lora is not None:
             # the stacked-delta path targets the model's float 2-D
             # projection weights; quantized layers keep int codes +
@@ -404,10 +450,13 @@ class Engine:
         # a zero/negative budget would idle every prefilling slot forever
         self.prefill_token_budget = None if prefill_token_budget is None \
             else max(1, int(prefill_token_budget))
-        self.max_blocks_per_seq = -(-self.max_seq_len // self.page_size)
+        self.cache_spec = cache_spec
+        # the width of a row's block table in the step
+        self.max_blocks_per_seq = cache_spec.table_width
         if num_blocks is None:
             # enough for every slot to run a full-length sequence
-            num_blocks = self.max_batch * self.max_blocks_per_seq
+            num_blocks = self.max_batch * cache_spec.blocks_for(
+                self.max_seq_len)
         dtype = kv_cache_dtype if kv_cache_dtype is not None else \
             getattr(model.cfg, "dtype", "float32")
         self.mesh = mesh
@@ -418,7 +467,8 @@ class Engine:
         self.scheduler = Scheduler(self.max_batch, self.page_size,
                                    self.max_blocks_per_seq,
                                    self.kv.allocator, self.kv.oob_block,
-                                   prefix_cache=self.prefix_cache)
+                                   prefix_cache=self.prefix_cache,
+                                   spec=cache_spec)
         # preemption/restore machinery: host-RAM page swap plus the
         # retry policy wrapped around serving host I/O (swap dispatches)
         # so a transient (or injected) fault becomes a logged retry, not
@@ -426,7 +476,7 @@ class Engine:
         self.max_queue = None if max_queue is None else int(max_queue)
         self._retry = retry if retry is not None else \
             RetryPolicy(max_attempts=3, backoff_s=0.02)
-        self._swap = SwapManager(self.kv, chunk=self.max_blocks_per_seq)
+        self._swap = SwapManager(self.kv, chunk=cache_spec.swap_chunk)
         self.params = serving_params(model)
         if mesh is not None:
             from .distributed import shard_serving_params
@@ -506,7 +556,7 @@ class Engine:
                 return model.logits(hidden)[:, 0]
 
         def step_fn(params, caches, tokens, tables, starts, lens, temps,
-                    key, seeds, emit, lora_ab, adapters):
+                    key, seeds, emit, lora_ab, adapters, cache_aux=None):
             """The ONE serving program: every slot's span (prefill
             chunk, decode token, or decode-plus-draft verify span)
             writes its KV and attends in a single ragged dispatch.
@@ -518,14 +568,19 @@ class Engine:
             so accept/reject needs no second dispatch.  ``lora_ab`` is
             the stacked adapter pytree (None on non-LoRA engines — the
             model path is then byte-for-byte today's) and ``adapters``
-            the per-slot stack indices the grouped BGMV gathers by."""
+            the per-slot stack indices the grouped BGMV gathers by.
+            ``cache_aux`` is what the cache kind adds to the step's
+            inputs (``cache_spec.step_aux``; None for full attention's
+            one table, whose program is then the one it always was)."""
             mp = {k[len("model."):]: v for k, v in params.items()
                   if k.startswith("model.")}
+            kind_kw = {} if cache_aux is None else {"cache_aux": cache_aux}
+            if lora_ab is not None:
+                kind_kw["lora"] = (lora_ab, adapters)
             hidden, caches = functional_call(
                 model.model, mp, tokens, caches=caches, seq_lens=lens,
                 block_tables=tables, span_starts=starts,
-                lora=None if lora_ab is None else (lora_ab, adapters),
-                training=False)
+                training=False, **kind_kw)
             # the model's own regions end with the final norm; the head
             # and the sampler are one region (observability/regions.py)
             with region("lm_head_loss"):
@@ -609,7 +664,8 @@ class Engine:
                     jnp.asarray(zeros_i), jnp.asarray(zeros_i),
                     jnp.asarray(np.zeros((b,), np.float32)),
                     self._key, jnp.asarray(zeros_i), jnp.asarray(zeros_i),
-                    self._lora_stacks(), jnp.asarray(zeros_i))
+                    self._lora_stacks(), jnp.asarray(zeros_i),
+                    self._device_aux([]))
                 jax.block_until_ready(nxt)
             self.kv.caches = caches
             pad = np.full((b,), self.kv.oob_block, np.int32)
@@ -631,6 +687,15 @@ class Engine:
         self._warmed = True
         self._publish_compiled_obs()
         return self
+
+    def _device_aux(self, plan):
+        """The cache kind's further step inputs for ``plan`` (an empty
+        plan: the warm-up's, whose writes all drop), on the device."""
+        aux = self.cache_spec.step_aux(plan, self.max_batch,
+                                       self.prefill_chunk,
+                                       self.kv.oob_block)
+        return None if aux is None else \
+            {k: jnp.asarray(v) for k, v in aux.items()}
 
     def hbm_stats(self) -> Dict[str, int]:
         """Live HBM accounting: bytes owned by each device-resident
@@ -836,10 +901,12 @@ class Engine:
 
     def _preempt_state(self, st: RequestState, head: bool,
                        reason: str) -> None:
-        pages = -(-st.kv_len // self.page_size)
+        # the pages that hold content, of whatever kind, in the order
+        # the restore lists them again (cache_spec.held_ids)
+        ids = self.cache_spec.held_ids(st)
+        pages = len(ids)
         host = None
         if pages:
-            ids = [int(b) for b in st.table[:pages]]
             host = self._retry.run(self._swap.swap_out, ids,
                                    site="serve.swap")
         self.scheduler.release_slot(st)
@@ -868,8 +935,8 @@ class Engine:
         new (all-private) blocks; prefill/decode resumes at kv_len."""
         pages, host = st.swapped
         if pages:
-            ids = [int(b) for b in st.table[:pages]]
-            self._retry.run(self._swap.swap_in, ids, host,
+            self._retry.run(self._swap.swap_in,
+                            self.cache_spec.held_ids(st), host,
                             site="serve.swap")
         st.swapped = None
         tr = _obs_state.TRACE[0]
@@ -942,6 +1009,71 @@ class Engine:
                 self._restore(st)
 
     # -- the loop ----------------------------------------------------------
+
+    def _grow_pages(self, plan):
+        """(A cache kind that reserves nothing ahead: window+summary.)
+        Take the pages this step's spans write, oldest admission first.
+        Where the pool cannot give them, a request first gives up its
+        fan-out rows, last first (its prompt advances by less this
+        step); where its own row still cannot be served, the YOUNGEST
+        running request is preempted to host, itself if that is it, and
+        comes back when the pool can hold its peak (``spec.restore``).
+        The oldest request always gets its pages: ``add_request`` refuses
+        a peak above the pool.  Returns the plan less what was given up.
+        Runs inside ``serve.step.plan``."""
+        spec, alloc = self.cache_spec, self.kv.allocator
+        keeps = {}                       # id(request state) -> spans kept
+        for spans in sorted(by_request(plan),
+                            key=lambda g: g[0].st.admit_seq):
+            st = spans[0].st
+            keep = len(spans)
+            while st.slot is not None:   # else: preempted for an older one
+                end = spans[keep - 1].start + spans[keep - 1].n
+                if alloc.can_allocate(spec.short(st, end)):
+                    spec.grow(st, end, alloc)
+                    keeps[id(st)] = keep
+                    break
+                if keep > 1:
+                    keep -= 1
+                    continue
+                victim = max((s_ for _, s_ in self.scheduler.active()),
+                             key=lambda s_: s_.admit_seq)
+                self._preempt_state(victim, head=True,
+                                    reason="pool_exhausted")
+        # the plan's own order (by_request relies on it), less the rows
+        # given up: a request's spans come in start order
+        out, seen = [], {}
+        for sp in plan:
+            k = seen.get(id(sp.st), 0)
+            seen[id(sp.st)] = k + 1
+            if sp.st.slot is not None and k < keeps.get(id(sp.st), 0):
+                out.append(sp)
+        return out
+
+    def _close_windows(self, advanced) -> None:
+        """(window+summary caches.)  After a step's consumption: count
+        the summary rows its spans completed, and give the pages of every
+        window a request has left back to the allocator — in the
+        ``step_finish`` that consumed the window's last position.  Runs
+        inside ``serve.step.emit``, after every request's events: a
+        request isolated by a fault was rewound first and is not here."""
+        spec, alloc = self.cache_spec, self.kv.allocator
+        reg = obs.get_registry()
+        tr = _obs_state.TRACE[0]
+        rows = closed = freed = 0
+        for st, kv_before in advanced:
+            rows += st.kv_len // spec.page_size - kv_before // spec.page_size
+            if st.pages is None:
+                continue                 # finished: its pages all went
+            n, f = spec.close(st, alloc)
+            closed, freed = closed + n, freed + f
+            if n and tr is not None:
+                tr.point(st.request.request_id, "window_close",
+                         windows=n, pages_freed=f, kv_len=st.kv_len)
+        if reg is not None:
+            reg.counter("serve.eva.summary_rows").inc(rows)
+            reg.counter("serve.eva.windows_closed").inc(closed)
+            reg.counter("serve.eva.pages_freed").inc(freed)
 
     def _run_cow(self, plan):
         """Copy-on-write: any request about to write into a borrowed
@@ -1311,6 +1443,8 @@ class Engine:
             with span("serve.step.plan", emit=False):
                 plan = self.scheduler.plan_spans(self.prefill_chunk,
                                                  self.prefill_token_budget)
+                if plan and not self.cache_spec.reserves_ahead:
+                    plan = self._grow_pages(plan)
                 if plan:
                     plan = self._run_cow(plan)
                 live_tokens = sum(sp.n for sp in plan)
@@ -1319,6 +1453,7 @@ class Engine:
                      adapters) = self.scheduler.span_arrays(
                         plan, self.prefill_chunk,
                         spec_emit=self.spec is not None)
+                    aux = self._device_aux(plan)
             nxt = None
             if plan:
                 # device_put of ready numpy arrays only: jnp.asarray of
@@ -1335,7 +1470,7 @@ class Engine:
                         jnp.asarray(tables), jnp.asarray(starts),
                         jnp.asarray(lens), jnp.asarray(temps), self._key,
                         jnp.asarray(seeds), jnp.asarray(emit),
-                        self._lora_stacks(), jnp.asarray(adapters))
+                        self._lora_stacks(), jnp.asarray(adapters), aux)
                     self.kv.caches = caches
         # busy accounting covers THIS engine's own engagement only
         # (begin and finish timed separately): under a replica set the
@@ -1410,6 +1545,13 @@ class Engine:
             reg.gauge("serve.queue_depth").set(self.scheduler.queue_depth())
             reg.gauge("serve.kv_blocks_used").set(
                 self.kv.allocator.used_blocks)
+            if not self.cache_spec.reserves_ahead:
+                held = [self.cache_spec.counts(s_)
+                        for _, s_ in self.scheduler.active()]
+                reg.gauge("serve.eva.window_blocks").set(
+                    sum(h[0] for h in held))
+                reg.gauge("serve.eva.summary_blocks").set(
+                    sum(h[1] for h in held))
             reg.gauge("serve.active_requests").set(
                 len(self.scheduler.active()))
             reg.histogram("serve.step_ms").observe(dt * 1e3)
@@ -1462,9 +1604,11 @@ class Engine:
             # one consumption per REQUEST, not per row: a prefilling
             # request's fan (its own row plus the free rows it was
             # dealt) advances kv_len once, by the sum of its rows
+            advanced = []
             for spans in by_request(plan):
                 first = spans[0]
                 st = first.st
+                advanced.append((st, st.kv_len))
                 # pre-span snapshot: isolation rewinds to here, and
                 # re-running the spans after restore is idempotent
                 # (the dispatch above already wrote their KV; the
@@ -1582,7 +1726,10 @@ class Engine:
                     rid = st.request.request_id
                     events[:] = [ev for ev in events
                                  if ev.request_id != rid]
+                    advanced.pop()
                     self._isolate(st, e)
+            if not self.cache_spec.reserves_ahead:
+                self._close_windows(advanced)
 
     def _consume_decode(self, st: RequestState, i: int, n: int, nxt,
                         events: List[TokenEvent]) -> None:

@@ -50,7 +50,7 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
 
 import numpy as np
 
-from .block_allocator import PrefixCache
+from .block_allocator import PagedKVSpec, PrefixCache
 
 __all__ = ["Request", "RequestState", "Scheduler", "Span", "by_request"]
 
@@ -101,7 +101,7 @@ class RequestState:
                  "borrowed", "cow_spare", "page_keys", "swapped",
                  "preempts", "handoffs", "sample_seed", "draft",
                  "spec_proposed", "spec_accepted", "admit_seq",
-                 "prefill_steps")
+                 "prefill_steps", "pages")
 
     def __init__(self, request: Request):
         self.request = request
@@ -149,6 +149,10 @@ class RequestState:
         self.spec_accepted = 0       # of those, accepted
         self.admit_seq = 0           # admission ordinal (fan-out order)
         self.prefill_steps = 0       # steps that consumed prompt tokens
+        # a cache kind with more than one table a request keeps its pages
+        # here (block_allocator.WindowSummaryPages); ``blocks`` then lists
+        # them all and ``table`` stays None
+        self.pages = None
 
     @property
     def total_len(self) -> int:
@@ -187,10 +191,14 @@ class Scheduler:
 
     def __init__(self, max_batch: int, page_size: int,
                  max_blocks_per_seq: int, allocator, oob_block: int,
-                 prefix_cache: Optional[PrefixCache] = None):
+                 prefix_cache: Optional[PrefixCache] = None, spec=None):
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
+        # the cache kind (block_allocator.py): what a request's pages are
+        # reckoned at, which hold content, what a row's table lists
+        self.spec = spec if spec is not None else \
+            PagedKVSpec(self.page_size, self.max_blocks_per_seq)
         self.allocator = allocator
         self.oob_block = int(oob_block)
         self.prefix_cache = prefix_cache
@@ -246,10 +254,11 @@ class Scheduler:
         return None
 
     def blocks_for(self, total_len: int) -> int:
-        """Blocks a ``total_len``-token sequence reserves: ceil(len/page).
-        The ONE place this formula lives — Engine.add_request's
-        unsatisfiable-budget rejection must agree with admission."""
-        return -(-int(total_len) // self.page_size)
+        """Blocks a ``total_len``-token sequence is reckoned at: the
+        cache kind's formula (ceil(len/page) for full attention's one
+        table).  The ONE place admission and Engine.add_request's
+        unsatisfiable-budget rejection ask."""
+        return self.spec.blocks_for(total_len)
 
     def blocks_needed(self, st: RequestState) -> int:
         return self.blocks_for(st.total_len)
@@ -276,14 +285,19 @@ class Scheduler:
             # the host payload is the authoritative content) — the
             # engine swap_ins pages [0, ceil(kv_len/page)) right after
             # this returns, then prefill/decode resumes at kv_len.
-            total = self.blocks_needed(st)
-            if not self.allocator.can_allocate(total):
+            if not self.spec.restore(st, self.allocator, self.oob_block):
                 return None
             self.waiting.popleft()
-            st.blocks = self.allocator.allocate(total)
-            st.table = np.full((self.max_blocks_per_seq,), self.oob_block,
-                               np.int32)
-            st.table[:total] = st.blocks
+            self._seat(st, slot)
+            return st
+        if not self.spec.reserves_ahead:
+            # nothing is reserved ahead for this cache kind: the request
+            # is reckoned at its peak against what is free now, and takes
+            # its pages as it writes them (Engine._grow_pages)
+            if not self.allocator.can_allocate(self.blocks_needed(st)):
+                return None
+            self.waiting.popleft()
+            self.spec.seat(st)
             self._seat(st, slot)
             return st
         plen = int(st.request.prompt_ids.size)
@@ -380,10 +394,12 @@ class Scheduler:
         order = sorted(self.active(),
                        key=lambda t: (t[0] - self._rr) % self.max_batch)
         plan = []
+        room = self.spec.span_room
         for i, st in order:
             if st.prefilling:
                 plen = int(st.request.prompt_ids.size)
-                n = min(c, plen - st.kv_len, left)
+                n = min(c, plen - st.kv_len, left,
+                        room(st.kv_len) or c)
                 if n <= 0:
                     continue                       # budget spent: idle
                 left -= n
@@ -403,7 +419,7 @@ class Scheduler:
             plen = int(st.request.prompt_ids.size)
             pos = first.start + first.n
             while free and left > 0 and pos < plen:
-                n = min(c, plen - pos, left)
+                n = min(c, plen - pos, left, room(pos) or c)
                 plan.append(Span(free.pop(0), st, pos, n, True))
                 pos += n
                 left -= n
@@ -445,7 +461,7 @@ class Scheduler:
                 tokens[i, 0] = st.pending_token
                 if n > 1:
                     tokens[i, 1:n] = st.draft[:n - 1]
-            tables[i] = st.table
+            tables[i] = self.spec.table_row(st, start, self.oob_block)
             starts[i] = start
             lens[i] = n
             temps[i] = req.temperature
@@ -476,6 +492,7 @@ class Scheduler:
             self.allocator.free(st.blocks)
             st.blocks = []
         st.table = None
+        st.pages = None
         st.borrowed = set()
         st.cow_spare = {}
         # unaccepted speculative tokens never outlive the slot: a

@@ -489,6 +489,10 @@ CELL_KERNELS = {
                            "fused_adamw": 4 * CELL_DEPTH + 2},
     "mistral-7b.serve-chat": {"ragged_paged_attention": CELL_DEPTH,
                               "fused_swiglu_mlp": CELL_DEPTH},
+    # EVA's step walks its summary pages then its window pages through the
+    # ragged kernel under a name of its own (PR 32)
+    "evabyte.serve-doc-bytes": {"eva_ragged_paged_attention": CELL_DEPTH,
+                                "fused_swiglu_mlp": CELL_DEPTH},
 }
 
 
@@ -575,7 +579,7 @@ def cell_kernels(topo, smoke):
                 mp.setattr(dispatch, "_backend", lambda: "tpu")
                 mp.setattr(jax, "default_backend", lambda: "tpu")
                 hlo = (_serve_cell_hlo(name, topo, smoke)
-                       if name.endswith("serve-chat")
+                       if _cell_files(name)[0]["runner"] == "serve"
                        else _train_cell_hlo(name, topo))
             found[name] = smoke.pallas_kernels(hlo)
         return found[name]
@@ -596,3 +600,18 @@ def test_benchmark_cell_holds_its_kernel(cell, kernel, cell_kernels):
 @pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
 def test_benchmark_cell_holds_no_other_kernel(cell, cell_kernels):
     assert sorted(cell_kernels(cell)) == sorted(CELL_KERNELS[cell])
+
+
+def test_fused_swiglu_mlp_block_width_at_evabyte_ffn():
+    """11008 = 43 x 256 columns: the widest 128-multiple that divides it
+    and is at most the default 512 is 256, a block width the Mistral
+    cells (14336 = 28 x 512) never take; the gate admits it."""
+    from paddle_tpu.ops.pallas import fused_mlp
+
+    assert fused_mlp._blocks(512, 4096, 11008, None, None, 2) == (256, 256)
+    assert fused_mlp._blocks(512, 4096, 14336, None, None, 2) == (256, 512)
+    x = jax.ShapeDtypeStruct((512, 4096), jnp.bfloat16)
+    w1 = jax.ShapeDtypeStruct((4096, 11008), jnp.bfloat16)
+    w2 = jax.ShapeDtypeStruct((11008, 4096), jnp.bfloat16)
+    assert fused_mlp.supported(x, w1, w2)
+
