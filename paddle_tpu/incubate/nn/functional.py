@@ -121,12 +121,12 @@ def _fused_swiglu_mlp_ref(x, w_gate, w_up, w_down):
                        preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _fused_swiglu_mlp_impl(x, w_gate, w_up, w_down):
+def _fused_swiglu_mlp_impl(x, w_gate, w_up, w_down, live=None):
     from ...ops import dispatch as _dispatch
     kernel = _dispatch.get("fused_swiglu_mlp")
     if kernel is not None:
         out = kernel(x, w_gate.astype(x.dtype), w_up.astype(x.dtype),
-                     w_down.astype(x.dtype))
+                     w_down.astype(x.dtype), live=live)
         if out is not None:
             return out
     return _fused_swiglu_mlp_ref(x, w_gate, w_up, w_down)
@@ -151,6 +151,35 @@ def _fused_swiglu_mlp_bwd(res, ct):
 
 
 fused_swiglu_mlp.defvjp(_fused_swiglu_mlp_fwd, _fused_swiglu_mlp_bwd)
+
+
+def live_token_order(seq_lens, span: int):
+    """Which lanes of the ragged serving step hold a token, once a step:
+    ``seq_lens`` (B,) are the rows' span lengths, ``span`` the row width
+    C.  Returns ``(order, inverse, n_live)`` over the ``B * C`` lanes:
+    ``x[order]`` puts the live tokens first (a stable order),
+    ``y[inverse]`` puts every lane back, ``n_live`` is their count.  By
+    token, not by row: 32 decoding rows are 32 live tokens."""
+    live = (jnp.arange(span)[None, :] < seq_lens[:, None]).reshape(-1)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    ahead = jnp.cumsum(live, dtype=jnp.int32)
+    lane = jnp.arange(live.shape[0], dtype=jnp.int32)
+    # a live lane goes after the live lanes before it, a dead one after
+    # every live lane and the dead lanes before it
+    inverse = jnp.where(live, ahead - 1, n_live + lane - ahead)
+    order = jnp.zeros_like(lane).at[inverse].set(lane)
+    return order, inverse, n_live
+
+
+def fused_swiglu_mlp_live(x, w_gate, w_up, w_down, live):
+    """``fused_swiglu_mlp`` for the ragged serving step, which knows its
+    live lanes (``live`` = ``live_token_order(...)``): the kernel computes
+    ``ceil(n_live / tile)`` token tiles under weights read once, and the
+    dead lanes come back zero (``ops/pallas/fused_mlp.py``).  Where no
+    kernel serves, the XLA composition computes every lane as
+    ``fused_swiglu_mlp`` does: the live lanes' results are the same.
+    Inference only (no vjp)."""
+    return _fused_swiglu_mlp_impl(x, w_gate, w_up, w_down, live)
 
 
 def _fused_rms_rope_qkv_ref(x, norm_weight, w_q, w_k, w_v, cos, sin,

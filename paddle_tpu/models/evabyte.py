@@ -207,7 +207,7 @@ class EvaByteDecoderLayer(Layer):
         self.mlp = LlamaMLP(cfg)
 
     def forward(self, x, cos, sin, cache=None, seq_lens=None,
-                block_tables=None, cache_aux=None):
+                block_tables=None, cache_aux=None, mlp_live=None):
         """``x`` is the float32 residual stream (``fp32_skip_add``); the
         blocks compute in the parameters' dtype."""
         dt = self.self_attn.q_proj.weight.dtype
@@ -217,7 +217,8 @@ class EvaByteDecoderLayer(Layer):
             cache_aux=cache_aux)
         with region("attn_proj"):
             x = x + attn.astype(jnp.float32)
-        h = self.mlp(self.post_attention_layernorm(x).astype(dt))
+        h = self.mlp(self.post_attention_layernorm(x).astype(dt),
+                     live=mlp_live)
         with region("mlp"):
             x = x + h.astype(jnp.float32)
         return x, cache
@@ -265,11 +266,16 @@ class EvaByteModel(Layer):
             for layer in self.layers:
                 x, _ = layer(x, cos, sin)
             return self.norm(x)
+        # the step's live lanes, once a step and not once a layer: the
+        # MLP's cost follows them (ops/pallas/fused_mlp.py)
+        from ..incubate.nn.functional import live_token_order
+        live = live_token_order(seq_lens, s)
         x, new_caches = run_cached_layers(
             self.layers, x, caches,
             lambda inner, x, cache: inner(
                 x, cos, sin, cache=cache, seq_lens=seq_lens,
-                block_tables=block_tables, cache_aux=cache_aux))
+                block_tables=block_tables, cache_aux=cache_aux,
+                mlp_live=live))
         return self.norm(x), new_caches
 
 

@@ -309,13 +309,16 @@ class LlamaMLP(Layer):
         self.down_proj = RowParallelLinear(i, h, has_bias=False,
                                            weight_attr=attr, sequence_parallel=sp)
 
-    def forward(self, x, lora=None):
+    def forward(self, x, lora=None, live=None):
+        """``live`` is the ragged serving step's ``live_token_order``:
+        the fused kernel then computes the live lanes only and returns
+        zeros on the others; every other path computes every lane."""
         # the region lives here so that every user of the block (a MoE
         # layer's experts too) carries it
         with region("mlp"):
-            return self._forward(x, lora)
+            return self._forward(x, lora, live)
 
-    def _forward(self, x, lora):
+    def _forward(self, x, lora, live):
         cfg = self.cfg
         from ..ops.tuning import geom_key
 
@@ -352,12 +355,12 @@ class LlamaMLP(Layer):
             # one pass over the weights, the (T, I) gate/up intermediate
             # stays in VMEM on TPU (incubate fused entry; XLA
             # composition where the kernel cannot serve)
-            from ..incubate.nn.functional import fused_swiglu_mlp
+            from ..incubate.nn import functional as IF
             lead = x.shape[:-1]
-            y = fused_swiglu_mlp(x.reshape(-1, cfg.hidden_size),
-                                 self.gate_proj.weight,
-                                 self.up_proj.weight,
-                                 self.down_proj.weight)
+            args = (x.reshape(-1, cfg.hidden_size), self.gate_proj.weight,
+                    self.up_proj.weight, self.down_proj.weight)
+            y = IF.fused_swiglu_mlp(*args) if live is None \
+                else IF.fused_swiglu_mlp_live(*args, live)
             return y.reshape(*lead, cfg.hidden_size)
         return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
 
@@ -402,7 +405,7 @@ class LlamaDecoderLayer(Layer):
 
     def forward(self, x, cos, sin, attn_mask=None, cache=None,
                 seq_lens=None, block_tables=None, span_starts=None,
-                lora=None):
+                lora=None, mlp_live=None):
         # regions (observability/regions.py): each block opens its own;
         # the residual adds sit in their block's region too, since XLA
         # fuses them into its last matmul and a fusion carries its root's
@@ -422,7 +425,8 @@ class LlamaDecoderLayer(Layer):
             attn, cache = attn
         with region("attn_proj"):
             x = x + attn
-        h = self.mlp(self.post_attention_layernorm(x), lora=lora)
+        h = self.mlp(self.post_attention_layernorm(x), lora=lora,
+                     live=mlp_live)
         with region("mlp"):
             x = x + h
         return x if cache is None else (x, cache)
@@ -573,6 +577,10 @@ class LlamaModel(Layer):
         kw = {} if block_tables is None else {"block_tables": block_tables}
         if span_starts is not None:
             kw["span_starts"] = span_starts
+            # the step's live lanes, once a step and not once a layer:
+            # the MLP's cost follows them (ops/pallas/fused_mlp.py)
+            from ..incubate.nn.functional import live_token_order
+            kw["mlp_live"] = live_token_order(seq_lens, s)
         lens_arg = seq_lens if (decode or block_tables is not None) \
             else None
         # per-layer LoRA packs: run_cached_layers walks the stack in

@@ -124,10 +124,20 @@ from . import fused_norm_qkv as _fq
 from . import fused_adamw as _fadamw
 
 
-def _fused_swiglu_dispatch(x, w_gate, w_up, w_down):
+def _fused_swiglu_dispatch(x, w_gate, w_up, w_down, live=None,
+                           interpret=False):
+    """``live`` is the serving step's ``(order, inverse, n_live)``
+    (``incubate.nn.functional.live_token_order``): where the kernel takes
+    its weight-stationary order, the live rows are gathered to the front
+    for it and every lane is put back after (two ``(T, H)`` moves)."""
     if _active_mesh() is not None or not _fm.supported(x, w_gate, w_down):
         return None
-    return _fm.fused_swiglu_mlp(x, w_gate, w_up, w_down)
+    if live is not None and _fm.holds_live(x, w_gate):
+        order, inverse, n_live = live
+        return _fm.fused_swiglu_mlp(x[order], w_gate, w_up, w_down, n_live,
+                                    interpret=interpret)[inverse]
+    return _fm.fused_swiglu_mlp(x, w_gate, w_up, w_down,
+                                interpret=interpret)
 
 
 dispatch.register("fused_swiglu_mlp", _fused_swiglu_dispatch,

@@ -55,8 +55,9 @@ layers multi-tenant SLO admission on top.
 Telemetry (all zero-overhead when observability is disabled):
 ``serve.ttft_ms``, ``serve.step_ms``, ``serve.tok_s``,
 ``serve.queue_depth``, ``serve.kv_blocks_used``, ``serve.active_requests``,
-``serve.ragged_occupancy``, ``serve.prefill_rows``,
-``serve.prefill_steps``, ``serve.prefix_hits``/``misses``,
+``serve.ragged_occupancy``, ``serve.mlp_live_tiles``,
+``serve.prefill_rows``, ``serve.prefill_steps``,
+``serve.prefix_hits``/``misses``,
 ``serve.shared_blocks``, ``serve.cached_blocks``, ``serve.cow_copies``,
 ``serve.preemptions``/``restores``/``swapped_pages``/
 ``isolated_failures``, and — with speculative decoding on —
@@ -97,6 +98,7 @@ from ..observability import _state as _obs_state
 from ..observability.regions import region
 from ..observability.spans import span
 from ..nn.layer import _swapped_params, functional_call, serving_params
+from ..ops.pallas.fused_mlp import LIVE_TILE as _MLP_LIVE_TILE
 from ..resilience import _state as _rs_state
 from ..resilience.retry import RetryPolicy
 from .block_allocator import (PagedKVCache, PrefixCache, SwapManager,
@@ -1559,6 +1561,11 @@ class Engine:
             # (B, C) capacity — low occupancy means idle lanes, not bugs
             reg.histogram("serve.ragged_occupancy").observe(
                 live_tokens / (self.max_batch * self.prefill_chunk))
+            # token tiles the step's MLP kernel multiplied (its cost
+            # follows the live tokens, ops/pallas/fused_mlp.py): of
+            # ceil(B * C / tile), how few
+            reg.histogram("serve.mlp_live_tiles").observe(
+                -(-live_tokens // _MLP_LIVE_TILE))
             reg.gauge("serve.cached_blocks").set(
                 self.kv.allocator.cached_blocks)
             # pages still physically shared: admission hits minus the

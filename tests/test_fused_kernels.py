@@ -70,6 +70,57 @@ class TestFusedMLPKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("i,block_i", [(256, 256), (512, 128)],
+                             ids=["one-block", "several-blocks"])
+    @pytest.mark.parametrize("n_live", [0, 1, 23, 128, 129, 512])
+    def test_live_rows_are_the_token_tiled_kernels_and_the_rest_zero(
+            self, n_live, i, block_i, dtype):
+        """The weight-stationary order (``n_live`` given): rows below
+        ``n_live`` are the token-tiled kernel's on the same rows to the
+        last bit (the same I-blocks added in the same order), rows past
+        it are zero; 0 is the warm-up's all-empty step, 129 one row into
+        the second token tile, 512 every lane."""
+        h, t = 128, 512
+        x = _arr(t, h, dtype=dtype, scale=1.0)
+        wg, wu, wd = _arr(h, i, dtype=dtype), _arr(h, i, dtype=dtype), \
+            _arr(i, h, dtype=dtype)
+        want = np.asarray(FM.fused_swiglu_mlp(
+            x, wg, wu, wd, block_i=block_i, interpret=True), np.float32)
+        got = FM.fused_swiglu_mlp(x, wg, wu, wd, jnp.int32(n_live),
+                                  block_i=block_i, interpret=True)
+        assert got.shape == (t, h) and got.dtype == dtype
+        got = np.asarray(got, np.float32)
+        np.testing.assert_array_equal(got[:n_live], want[:n_live])
+        assert not got[n_live:].any()
+
+    def test_which_loop_order_runs_is_read_from_the_call(self):
+        """No ``n_live``: the token-tiled grid.  ``n_live`` and a
+        ``(T, H)`` VMEM can hold three times over: one grid axis, over
+        I, and a scalar-prefetch operand.  ``n_live`` and a training
+        batch's ``(T, H)``: the token-tiled grid again, every row
+        computed."""
+        h, i = 128, 256
+        wg, wd = _arr(h, i), _arr(i, h)
+
+        def grid_of(t, *n):
+            x = jax.ShapeDtypeStruct((t, h), jnp.float32)
+            jaxpr = jax.make_jaxpr(lambda x, *n: FM.fused_swiglu_mlp(
+                x, wg, wg, wd, *n, interpret=True))(x, *n)
+            eqn, = [e for e in jaxpr.jaxpr.eqns
+                    if e.primitive.name == "pallas_call"]
+            gm = eqn.params["grid_mapping"]
+            return gm.grid, gm.num_index_operands
+
+        n = jnp.int32(5)
+        assert grid_of(512) == ((2, 1), 0)
+        assert grid_of(512, n) == ((1,), 1)
+        big = 2 ** 17            # 128 Ki rows x 128 floats, thrice: no
+        assert not FM.holds_live(
+            jax.ShapeDtypeStruct((big, h), jnp.float32), wg)
+        assert grid_of(big, n) == ((big // 256, 1), 0)
+
     def test_entry_matches_unfused_model_path(self):
         # semantic pin: the fused entry ≈ the pre-fusion LlamaMLP math
         h, i, t = 128, 256, 16
@@ -539,7 +590,100 @@ class TestModelWiring:
         assert np.isfinite(float(met["loss"]))
 
 
+@pytest.fixture
+def interpreted_mlp_kernel(monkeypatch):
+    """The fused MLP's TPU dispatch (its gate, the compaction around the
+    call and both loop orders) through the Pallas interpreter on the
+    CPU; counts the calls that carried the step's live lanes."""
+    from paddle_tpu.ops import dispatch
+    from paddle_tpu.ops import pallas as P
+    calls = {"live": 0, "every-lane": 0}
+
+    def kernel(x, w_gate, w_up, w_down, live=None):
+        calls["every-lane" if live is None else "live"] += 1
+        return P._fused_swiglu_dispatch(x, w_gate, w_up, w_down, live=live,
+                                        interpret=True)
+    monkeypatch.setitem(dispatch._REGISTRY, "fused_swiglu_mlp", kernel)
+    monkeypatch.setitem(dispatch._PLATFORM, "fused_swiglu_mlp", "cpu")
+    return calls
+
+
+def _mistral_shaped():
+    from paddle_tpu.models.llama import llama
+    pt.seed(0)
+    model = llama("tiny", hidden_size=128, intermediate_size=256,
+                  fused_ops="on")        # 4 heads over 2 kv heads, d 32
+    return model, dict(max_batch=4, max_seq_len=128, page_size=8,
+                       prefill_chunk=8)
+
+
+def _evabyte_shaped():
+    from paddle_tpu.models import evabyte as E
+    pt.seed(0)
+    model = E.EvaByteForCausalLM(E.EvaByteConfig(
+        hidden_size=128, intermediate_size=128, num_hidden_layers=2,
+        num_attention_heads=4, max_position_embeddings=512, window_size=64,
+        fused_ops="on"))
+    model.eval()
+    return model, dict(max_batch=4, max_seq_len=512, num_blocks=64,
+                       enable_prefix_caching=False)
+
+
 class TestEngineWiring:
+    @pytest.mark.parametrize("family", [_mistral_shaped, _evabyte_shaped],
+                             ids=["mistral-shaped", "evabyte"])
+    def test_ragged_step_is_the_same_with_and_without_the_live_lanes(
+            self, family, interpreted_mlp_kernel, monkeypatch):
+        """One request decodes while a long prompt fans out over the free
+        rows, and its tail leaves rows empty: with the step's live lanes
+        handed to the MLP kernel (compacted, dead lanes zero) and without
+        (every lane through the token-tiled kernel), the same tokens come
+        out and the same rows are written to the pools."""
+        from paddle_tpu import serving
+        model, kw = family()
+        vocab = model.cfg.vocab_size
+        rng = np.random.default_rng(7)
+        short = rng.integers(0, vocab, size=5).astype(np.int32)
+        # two steps over all three free rows, then a tail in one row
+        chunk = kw.get("prefill_chunk", 16)
+        long = rng.integers(0, vocab, size=6 * chunk + 5).astype(np.int32)
+
+        def serve():
+            eng = serving.Engine(model, **kw).warmup()
+            lens_seen, real = [], eng._step_fn
+
+            def spy(params, caches, tokens, tables, starts, lens, *rest):
+                lens_seen.append(np.asarray(lens))
+                return real(params, caches, tokens, tables, starts, lens,
+                            *rest)
+            eng._step_fn = spy
+            a = eng.add_request(short, max_new_tokens=14)
+            eng.step()
+            eng.step()
+            b = eng.add_request(long, max_new_tokens=4)
+            outs = eng.run()
+            assert eng.kv_blocks_used == 0
+            pools = [np.asarray(leaf)
+                     for leaf in jax.tree.leaves(eng.kv.caches)]
+            return [list(outs[a]), list(outs[b])], pools, lens_seen
+
+        with_live = serve()
+        assert interpreted_mlp_kernel == {"live": 2, "every-lane": 0}
+        # steps held a decode row beside fan-out rows, and beside empty rows
+        assert any((ln == 1).any() and (ln > 1).sum() > 1
+                   for ln in with_live[2])
+        assert any((ln == 1).any() and (ln > 1).any() and (ln == 0).any()
+                   for ln in with_live[2])
+        monkeypatch.setattr(
+            IF, "fused_swiglu_mlp_live",
+            lambda x, wg, wu, wd, live: IF.fused_swiglu_mlp(x, wg, wu, wd))
+        without = serve()
+        assert interpreted_mlp_kernel == {"live": 2, "every-lane": 2}
+        assert with_live[0] == without[0]
+        assert len(with_live[2]) == len(without[2])
+        for got, want in zip(with_live[1], without[1]):
+            np.testing.assert_array_equal(got, want)
+
     def test_weight_quant_fused_token_identity(self):
         from paddle_tpu import serving
         from paddle_tpu.models.llama import llama

@@ -240,6 +240,21 @@ def _case_fused_swiglu_mlp(shape):
     return m.fused_swiglu_mlp, (x, wg, wg, wd), ["fused_swiglu_mlp"]
 
 
+def _case_fused_swiglu_mlp_live(ffn):
+    """The serving step's call: ``(32 x 16, 4096)`` lanes and the step's
+    live count, so the weight-stationary order; x, the float32
+    accumulator and the output whole in VMEM beside the weight blocks,
+    within ``VMEM_LIMIT``."""
+    def case(shape):
+        from paddle_tpu.ops.pallas import fused_mlp as m
+        x, wg, wd = shape((512, 4096)), shape((4096, ffn)), \
+            shape((ffn, 4096))
+        assert m.supported(x, wg, wd) and m.holds_live(x, wg)
+        return m.fused_swiglu_mlp, (x, wg, wg, wd, shape((), I32)), \
+            ["fused_swiglu_mlp"]
+    return case
+
+
 def _case_flash_attention(shape):
     from paddle_tpu.ops.pallas import flash_attention as m
     q = shape((1, 2048, NH, HD))
@@ -321,6 +336,9 @@ def _case_fused_rms_rope_qkv(shape):
 
 KERNEL_CASES = {
     "fused_swiglu_mlp-7b": _case_fused_swiglu_mlp,
+    # the two serving cells' own calls (Mistral's ffn, EvaByte's)
+    "fused_swiglu_mlp-live-ffn14336": _case_fused_swiglu_mlp_live(14336),
+    "fused_swiglu_mlp-live-ffn11008": _case_fused_swiglu_mlp_live(11008),
     "flash_attention-7b": _case_flash_attention,
     "ragged_paged_attention-7b-page16": _case_ragged(16),
     "ragged_paged_attention-7b-page64": _case_ragged(64),
@@ -566,24 +584,30 @@ def _serve_cell_hlo(name, topo, smoke):
 
 
 @pytest.fixture(scope="module")
-def cell_kernels(topo, smoke):
-    """``cell_kernels(name)``: the Pallas calls by name in the cell's step
-    compiled for v5e at depth ``CELL_DEPTH``; one compile a cell."""
+def cell_hlo(topo, smoke):
+    """``cell_hlo(name)``: the cell's step compiled for v5e at depth
+    ``CELL_DEPTH``, as text; one compile a cell."""
     from paddle_tpu.ops import dispatch
 
     found = {}
 
-    def kernels(name):
+    def hlo(name):
         if name not in found:
             with pytest.MonkeyPatch.context() as mp:     # as ``as_tpu``
                 mp.setattr(dispatch, "_backend", lambda: "tpu")
                 mp.setattr(jax, "default_backend", lambda: "tpu")
-                hlo = (_serve_cell_hlo(name, topo, smoke)
-                       if _cell_files(name)[0]["runner"] == "serve"
-                       else _train_cell_hlo(name, topo))
-            found[name] = smoke.pallas_kernels(hlo)
+                found[name] = (
+                    _serve_cell_hlo(name, topo, smoke)
+                    if _cell_files(name)[0]["runner"] == "serve"
+                    else _train_cell_hlo(name, topo))
         return found[name]
-    return kernels
+    return hlo
+
+
+@pytest.fixture(scope="module")
+def cell_kernels(cell_hlo, smoke):
+    """``cell_kernels(name)``: the Pallas calls by name in that step."""
+    return lambda name: smoke.pallas_kernels(cell_hlo(name))
 
 
 @pytest.mark.parametrize(
@@ -605,13 +629,52 @@ def test_benchmark_cell_holds_no_other_kernel(cell, cell_kernels):
 def test_fused_swiglu_mlp_block_width_at_evabyte_ffn():
     """11008 = 43 x 256 columns: the widest 128-multiple that divides it
     and is at most the default 512 is 256, a block width the Mistral
-    cells (14336 = 28 x 512) never take; the gate admits it."""
+    cells (14336 = 28 x 512) never take; the gate admits it.  Token-tiled
+    (training, ``generate``): token tiles of 256 rows, so a ``(512, H)``
+    call reads its weights twice."""
     from paddle_tpu.ops.pallas import fused_mlp
 
     assert fused_mlp._blocks(512, 4096, 11008, None, None, 2) == (256, 256)
     assert fused_mlp._blocks(512, 4096, 14336, None, None, 2) == (256, 512)
+    assert fused_mlp._blocks(8192, 4096, 14336, None, None, 2) == (256, 512)
     x = jax.ShapeDtypeStruct((512, 4096), jnp.bfloat16)
     w1 = jax.ShapeDtypeStruct((4096, 11008), jnp.bfloat16)
     w2 = jax.ShapeDtypeStruct((11008, 4096), jnp.bfloat16)
     assert fused_mlp.supported(x, w1, w2)
 
+
+@pytest.mark.parametrize("ffn,block_i", [(14336, 512), (11008, 256)])
+def test_fused_swiglu_mlp_geometry_the_serving_step_takes(ffn, block_i):
+    """With the step's live count the ``(512, 4096)`` lanes take the
+    weight-stationary order: the I-blocks are the token-tiled path's own
+    (28 x 512 at Mistral's ffn, 43 x 256 at EvaByte's: one grid step
+    each, every weight byte fetched once), token tiles of 128 rows under
+    them, 41.6 / 29.4 MiB of VMEM by the estimate; a training batch's
+    ``(8192, 4096)`` cannot be held and stays token-tiled."""
+    from paddle_tpu.ops.pallas import _common, fused_mlp
+
+    x = jax.ShapeDtypeStruct((512, 4096), jnp.bfloat16)
+    w1 = jax.ShapeDtypeStruct((4096, ffn), jnp.bfloat16)
+    assert fused_mlp._blocks(512, 4096, ffn, None, None, 2)[1] == block_i
+    assert fused_mlp._live_tile(512) == 128
+    assert fused_mlp.holds_live(x, w1)
+    est = fused_mlp._live_vmem_estimate(512, 128, block_i, 4096, 2)
+    assert est == {512: 43646976, 256: 30736384}[block_i]
+    assert est <= fused_mlp.LIVE_VMEM_BUDGET < _common.VMEM_LIMIT
+    assert not fused_mlp.holds_live(
+        jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16), w1)
+
+
+@pytest.mark.parametrize("cell,live", [("mistral-7b.serve-chat", True),
+                                       ("evabyte.serve-doc-bytes", True),
+                                       ("mistral-7b.train-8k", False)])
+def test_serving_cells_hand_the_mlp_their_live_count(cell, live, cell_hlo):
+    """Both serving cells' steps call the kernel in its weight-stationary
+    order (a one-element int32 scalar-prefetch operand before x); the
+    training cell's call has none."""
+    calls = [ln for ln in cell_hlo(cell).splitlines()
+             if re.search(r"= \S+ custom-call\(", ln)
+             and "fused_swiglu_mlp" in ln.split(" = ")[0]]
+    assert len(calls) == CELL_DEPTH, calls
+    assert all(("operand_layout_constraints={s32[1]" in c) == live
+               for c in calls), calls

@@ -719,7 +719,9 @@ def test_telemetry_report_folds_serving_events(tmp_path):
             "serve.prefill_rows": {"count": 5, "sum": 37.0, "p50": 4.0,
                                    "p95": 27.0, "max": 27.0},
             "serve.prefill_steps": {"count": 3, "sum": 6.0, "p50": 2.0,
-                                    "p95": 3.0, "max": 3.0}}}) + "\n")
+                                    "p95": 3.0, "max": 3.0},
+            "serve.mlp_live_tiles": {"count": 4, "sum": 7.0, "p50": 1.0,
+                                     "p95": 4.0, "max": 4.0}}}) + "\n")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "telemetry_report.py"),
          path], capture_output=True, text=True, timeout=60)
@@ -734,6 +736,8 @@ def test_telemetry_report_folds_serving_events(tmp_path):
     assert "| prefill rows a step p50 / p95 / max | 4 / 27 / 27 |" \
         in r.stdout
     assert "| prefill steps to first token p50 / p95 / max | 2 / 3 / 3 |" \
+        in r.stdout
+    assert "| MLP token tiles a step p50 / p95 / max | 1 / 4 / 4 |" \
         in r.stdout
     summary = json.loads(r.stdout.strip().splitlines()[-1])
     sv = summary["serving"]

@@ -1054,6 +1054,36 @@ class TestSpanFanOut:
             assert out == self._ref(tiny_llama, p, m)
 
 
+    def test_mlp_live_tiles_histogram(self, tiny_llama, monkeypatch):
+        """``serve.mlp_live_tiles`` is ``ceil(live tokens / tile)`` of
+        every step, observed beside ``serve.ragged_occupancy``: at a
+        tile of 4 lanes the 16-lane step reads 1 while one request
+        decodes and 4 when a prompt fans out over every row."""
+        import paddle_tpu.observability as obs
+        from paddle_tpu.serving import engine as engine_mod
+        assert engine_mod._MLP_LIVE_TILE == 128
+        monkeypatch.setattr(engine_mod, "_MLP_LIVE_TILE", 4)
+        tel = obs.enable(sinks=[obs.InMemorySink()], crash_hooks=False)
+        try:
+            eng = serving.Engine(tiny_llama, **self.KW).warmup()
+            eng.add_request(_prompt(6), max_new_tokens=10)
+            eng.step()
+            eng.step()
+            eng.add_request(_prompt(40), max_new_tokens=3)
+            eng.run()
+            snap = tel.registry.snapshot()
+            tiles, occ = snap["serve.mlp_live_tiles"], \
+                snap["serve.ragged_occupancy"]
+            assert tiles["count"] == occ["count"]
+            assert tiles["p50"] == 1 and tiles["max"] == 4
+            # ceil(n / 4) summed over the steps is at least the live
+            # tokens over 4, and under one more a step
+            assert occ["sum"] * 4 <= tiles["sum"] < occ["sum"] * 4 \
+                + tiles["count"]
+        finally:
+            obs.disable()
+
+
 class TestFaultIsolation:
     """Injected serve.* faults are confined to the ONE affected request
     (rewind → preempt → re-admit): the compiled step and the other

@@ -580,11 +580,15 @@ def render(agg, malformed=0):
                          f"{fmt(occ.get('p50'))} / {fmt(occ.get('p95'))} "
                          f"({sv['span_tokens']} span tokens) |")
         # span fan-out (docs/SERVING.md "Step anatomy"): rows a
-        # prefilling request held in a step (1 = no fan-out), and steps
-        # from a request's first chunk to its first token
+        # prefilling request held in a step (1 = no fan-out), steps
+        # from a request's first chunk to its first token, and the token
+        # tiles the step's MLP kernel multiplied (1 = the weights' one
+        # crossing of HBM and no more)
         for label, key in (("prefill rows a step", "serve.prefill_rows"),
                            ("prefill steps to first token",
-                            "serve.prefill_steps")):
+                            "serve.prefill_steps"),
+                           ("MLP token tiles a step",
+                            "serve.mlp_live_tiles")):
             h = m.get(key) or {}
             if h:
                 lines.append(f"| {label} p50 / p95 / max | "
