@@ -6,7 +6,8 @@ Subsystem map (reference SURVEY.md §2.4/2.5):
 - mp_layers: tensor-parallel layers + Megatron-SP
 - pipeline: 1F1B/GPipe pipeline parallel via shard_map + ppermute
 - sharding: ZeRO stage 1/2/3 semantics (group_sharded_parallel)
-- moe: expert parallel MoE layer (all_to_all dispatch)
+- moe: expert parallel MoE layer (all_to_all dispatch), and DroplessMoE:
+  routing as data over the experts held here, no capacity, no drop
 - cp: context parallelism (Ulysses all_to_all + ring attention)
 - auto: shard_tensor / reshard (auto-parallel DistTensor parity)
 """
@@ -39,7 +40,7 @@ from .pipeline import (LayerDesc, SharedLayerDesc, PipelineLayer,  # noqa: F401
 from . import sharding  # noqa: F401
 from .sharding import group_sharded_parallel  # noqa: F401
 from . import moe  # noqa: F401
-from .moe import MoELayer  # noqa: F401
+from .moe import DroplessMoE, MoELayer  # noqa: F401
 from . import cp  # noqa: F401
 from .cp import (ring_attention, ulysses_attention,  # noqa: F401
                  context_parallel_attention)

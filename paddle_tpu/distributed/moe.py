@@ -30,6 +30,7 @@ params are process-local.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Optional
 
@@ -281,6 +282,236 @@ class MoELayer(Layer):
 
         out = jnp.einsum("ech,nec->nh", expert_out,
                          combine.astype(x.dtype))
+        return out.reshape(shape)
+
+
+# ---------------------------------------------------------------------------
+# routing as data: the experts held here, no capacity, no dropped token
+# ---------------------------------------------------------------------------
+
+def row_block(worst: int, expected: float) -> int:
+    """Rows of one pass of :func:`held_experts`: one and a half times the
+    expected number of live rows on whole sublane tiles, at most the
+    worst case."""
+    return min(worst, max(8, -(-int(1.5 * expected) // 8) * 8))
+
+
+def _experts_block(rows, wts, sizes, w_gate, w_up, w_down):
+    """One block of sorted assignments through the held experts: ``rows``
+    ``(block, h)`` the tokens' rows, ``sizes`` the rows of each held
+    expert that lie in this block.  Rows past their sum belong to no
+    expert: ``grouped_matmul`` returns zeros there, and the gradient that
+    comes back for them (which the grouped kernel leaves undefined) is
+    cut by the select on ``rows``."""
+    from ..incubate.nn.functional import grouped_matmul, swiglu
+    live = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+    rows = jnp.where(live, rows, jnp.zeros((), rows.dtype))
+    mid = swiglu(grouped_matmul(rows, w_gate, sizes),
+                 grouped_matmul(rows, w_up, sizes))
+    return grouped_matmul(mid, w_down, sizes).astype(jnp.float32) \
+        * wts[:, None].astype(jnp.float32)
+
+
+def _blocks_of(tok, sizes, block):
+    """(number of blocks that hold a live row, ``i -> (first row, the
+    block's tokens, each held expert's rows inside the block)``)."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def at(i):
+        r0 = i * block
+        return r0, jax.lax.dynamic_slice_in_dim(tok, r0, block), (
+            jnp.clip(ends, r0, r0 + block)
+            - jnp.clip(starts, r0, r0 + block)).astype(jnp.int32)
+
+    return (ends[-1] + block - 1) // block, at
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(block, x, tok, wts, sizes, w_gate, w_up, w_down):
+    """``sum over a token's held experts of w_e E_e(x)``, the work
+    following the live rows.
+
+    ``x`` ``(N, h)``; ``tok`` ``(M,)`` the token of each assignment and
+    ``wts`` ``(M,)`` its weight, the assignments sorted by held expert
+    with the ones whose expert lies elsewhere last; ``sizes`` ``(count,)``
+    the rows of each held expert; the stacked leaves ``(count, h, f)``,
+    ``(count, h, f)``, ``(count, f, h)``.  ``M`` is the worst case (every
+    token choosing ``min(top_k, count)`` held experts, a multiple of
+    ``block``), so nothing is ever dropped, and only index arrays are
+    that long: the rows are gathered, multiplied and added back
+    ``block`` assignments at a time in a loop whose trip count,
+    ``ceil(live rows / block)``, is data.  The backward pass walks the
+    same blocks and recomputes each, so no buffer outlives its block."""
+    return _held_experts_fwd(block, x, tok, wts, sizes, w_gate, w_up,
+                             w_down)[0]
+
+
+def _held_experts_fwd(block, x, tok, wts, sizes, w_gate, w_up, w_down):
+    n_blocks, at = _blocks_of(tok, sizes, block)
+
+    def body(i, acc):
+        r0, tok_b, sizes_b = at(i)
+        y = _experts_block(x[tok_b],
+                           jax.lax.dynamic_slice_in_dim(wts, r0, block),
+                           sizes_b, w_gate, w_up, w_down)
+        # ten rows a token at the most add up in float32
+        return acc.at[tok_b].add(y)
+
+    out = jax.lax.fori_loop(0, n_blocks, body,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype), (x, tok, wts, sizes, w_gate, w_up, w_down)
+
+
+def _held_experts_bwd(block, res, ct):
+    x, tok, wts, sizes, w_gate, w_up, w_down = res
+    n_blocks, at = _blocks_of(tok, sizes, block)
+    ct = ct.astype(jnp.float32)
+
+    def body(i, carry):
+        dx, dwts, dws = carry
+        r0, tok_b, sizes_b = at(i)
+        _, vjp = jax.vjp(
+            lambda rows, wts_b, *ws: _experts_block(rows, wts_b, sizes_b,
+                                                    *ws),
+            x[tok_b], jax.lax.dynamic_slice_in_dim(wts, r0, block),
+            w_gate, w_up, w_down)
+        d_rows, d_wts, *d_ws = vjp(ct[tok_b])
+        return (dx.at[tok_b].add(d_rows.astype(jnp.float32)),
+                jax.lax.dynamic_update_slice_in_dim(dwts, d_wts, r0, 0),
+                tuple(a + d.astype(jnp.float32) for a, d in zip(dws, d_ws)))
+
+    dx, dwts, dws = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(wts),
+         tuple(jnp.zeros(w.shape, jnp.float32)
+               for w in (w_gate, w_up, w_down))))
+    return (dx.astype(x.dtype), None, dwts, None) + tuple(
+        d.astype(w.dtype) for d, w in zip(dws, (w_gate, w_up, w_down)))
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class _ExpertStack(Layer):
+    """The held experts' leaves, stacked on a leading axis."""
+
+    def __init__(self, count, d_model, width, weight_attr):
+        super().__init__()
+        for name, shape in (("gate_proj", (count, d_model, width)),
+                            ("up_proj", (count, d_model, width)),
+                            ("down_proj", (count, width, d_model))):
+            setattr(self, name, self.create_parameter(shape, attr=weight_attr))
+
+
+class _GatedMLP(Layer):
+    def __init__(self, d_model, width, weight_attr):
+        super().__init__()
+        from ..nn.layers_common import Linear
+        self.gate_proj = Linear(d_model, width, weight_attr, bias_attr=False)
+        self.up_proj = Linear(d_model, width, weight_attr, bias_attr=False)
+        self.down_proj = Linear(width, d_model, weight_attr, bias_attr=False)
+
+    def forward(self, x):
+        from ..incubate.nn.functional import swiglu
+        return self.down_proj(swiglu(self.gate_proj(x), self.up_proj(x)))
+
+
+class DroplessMoE(Layer):
+    """A sparse expert block whose routing is data: softmax over all
+    ``num_experts``, the ``top_k`` largest (renormalised to sum 1 under
+    ``norm_topk_prob``), every chosen expert computed for every token
+    that chose it, whatever the imbalance: no capacity, no dropped token.
+
+    ``held = (first, count)`` says which of the ``num_experts`` experts
+    this rank holds (expert parallelism: rank ``r`` of ``R`` holds
+    ``(r * num_experts // R, num_experts // R)``); the default holds them
+    all.  The router keeps its full width and its ``top_k``; the
+    assignments whose expert is held are sorted by expert and go through
+    one grouped product over the stacked leaves ``experts.gate_proj`` /
+    ``up_proj`` ``(count, d_model, expert_width)`` and
+    ``experts.down_proj`` ``(count, expert_width, d_model)``
+    (:func:`held_experts`); the result is this rank's part of the routed
+    sum.  What the experts held elsewhere would add is **left out**: on
+    one chip the layer runs without its exchange, and nothing stands in
+    for the other ranks.  The parts of all ranks add up to the whole
+    block's routed sum (``tests/test_qwen3_next.py``).
+
+    ``shared_width`` adds an expert that every token takes, behind a
+    sigmoid gate of one column: ``sigmoid(shared_expert_gate(x)) *
+    shared_expert(x)``; every rank computes it alike.
+
+    After ``forward`` returns, ``self.load`` holds that call's routing
+    counts (``rows_held``, ``expert_rows_max``, ``expert_rows_mean``),
+    valid at the same trace level only, as ``MoELayer.aux_loss``.
+    """
+
+    def __init__(self, d_model: int, num_experts: int, top_k: int,
+                 expert_width: int, held=None, shared_width: int = 0,
+                 norm_topk_prob: bool = True, weight_attr=None):
+        super().__init__()
+        from ..nn.layers_common import Linear
+        first, count = held if held is not None else (0, num_experts)
+        if not (0 <= first and 0 < count and first + count <= num_experts):
+            raise ValueError(f"held={held} does not lie in the router's "
+                             f"{num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.held = (first, count)
+        self.norm_topk_prob = norm_topk_prob
+        self.gate = Linear(d_model, num_experts, weight_attr, bias_attr=False)
+        self.experts = _ExpertStack(count, d_model, expert_width, weight_attr)
+        if shared_width:
+            self.shared_expert = _GatedMLP(d_model, shared_width, weight_attr)
+            self.shared_expert_gate = Linear(d_model, 1, weight_attr,
+                                             bias_attr=False)
+        else:
+            self.shared_expert = None
+        self.load = {}
+
+    def route(self, tokens):
+        """``(weights (N, top_k) float32, experts (N, top_k) int32)``."""
+        p = jax.nn.softmax(self.gate(tokens).astype(jnp.float32), axis=-1)
+        top, idx = jax.lax.top_k(p, self.top_k)
+        if self.norm_topk_prob:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return top, idx
+
+    def forward(self, x):
+        """x: [..., H] -> [..., H]; routing over the flattened tokens."""
+        from ..observability.regions import region
+        shape = x.shape
+        tokens = x.reshape(-1, shape[-1])
+        n = tokens.shape[0]
+        first, count = self.held
+        with region("mlp"):
+            with jax.named_scope("moe_router"):
+                top, idx = self.route(tokens)
+                local = idx.reshape(-1) - first
+                here = (local >= 0) & (local < count)
+                key = jnp.where(here, local, count)     # elsewhere: last
+                order = jnp.argsort(key, stable=True)
+                sizes = jnp.bincount(key, length=count + 1)[:count]
+                # an assignment's token is its place over top_k.  The
+                # worst case holds min(top_k, count) rows a token; the
+                # index arrays are padded to whole blocks
+                worst = n * min(self.top_k, count)
+                block = row_block(
+                    worst, n * self.top_k * count / self.num_experts)
+                order = jnp.pad(order[:worst], (0, -worst % block))
+                tok = (order // self.top_k).astype(jnp.int32)
+                wts = top.reshape(-1)[order]
+                self.load = {
+                    "rows_held": jnp.sum(sizes),
+                    "expert_rows_max": jnp.max(sizes),
+                    "expert_rows_mean": jnp.sum(sizes) / count}
+            with jax.named_scope("moe_experts"):
+                out = held_experts(
+                    block, tokens, tok, wts, sizes.astype(jnp.int32),
+                    self.experts.gate_proj, self.experts.up_proj,
+                    self.experts.down_proj)
+            if self.shared_expert is not None:
+                out = out + (jax.nn.sigmoid(self.shared_expert_gate(tokens))
+                             * self.shared_expert(tokens)).astype(out.dtype)
         return out.reshape(shape)
 
 

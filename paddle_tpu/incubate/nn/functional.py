@@ -779,6 +779,119 @@ def eva_paged_attend(cache, q, new_k, new_v, block_tables, span_lens, aux,
                                      page=kc.shape[1]), (kc, vc))
 
 
+def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
+    """The gated delta rule (Gated DeltaNet; ``models/qwen3_next.py``) in
+    its chunked form.  Per head, with a state ``S`` of ``(dk, dv)`` from
+    zero, position ``t`` does::
+
+        S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);
+        S <- S + k_t d^T;  o_t = S^T q_t
+
+    ``q``, ``k`` ``(B, S, H, dk)`` as the rule reads them (normalised and
+    scaled by the caller), ``v`` ``(B, S, H, dv)``, ``g`` (the log decay,
+    <= 0) and ``beta`` ``(B, S, H)``.  Returns ``o`` ``(B, S, H, dv)`` in
+    ``v``'s dtype.
+
+    Nothing runs position by position.  Within a chunk of ``chunk``
+    positions, with ``G`` the running sum of ``g`` and ``M[i, j] =
+    exp(G_i - G_j)`` for ``i >= j``, the chunk's own updates solve one
+    unit lower-triangular system ``(I + strict_lower((k beta) k^T * M))
+    [V' | K'] = [v beta | k beta exp(G)]``; everything that does not read
+    ``S`` is computed for all chunks at once, and a ``lax.scan`` over the
+    chunks carries ``S`` through four products a chunk::
+
+        v_new = V' - K' S;  o = (q exp(G)) S + lower(q k^T * M) v_new
+        S <- exp(G_C) S + (k exp(G_C - G))^T v_new
+
+    Every exponent is <= 0, so a strongly negative ``g`` underflows to an
+    exact forgetting and nothing overflows.  Float32 throughout; the
+    triangular system at full precision, the products that read ``S`` at
+    the precision of :func:`_prec` (bfloat16 operands where the model is
+    bfloat16).  The backward pass is autodiff through the scan: one ``S``
+    and one ``v_new`` a chunk are kept (``S / chunk`` x ``H (dk + chunk)
+    dv`` float32 numbers).  A sequence that is no multiple of ``chunk``
+    is padded with positions that neither write the state nor are read
+    back (``k = beta = g = 0``)."""
+    from jax.scipy.linalg import solve_triangular
+
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    p = _prec(q.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def chunks(x):
+        """(B, S, H, ...) -> float32 (B, H, n, chunk, ...)"""
+        x = x.astype(jnp.float32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((b, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    with jax.named_scope("gated_delta_rule"):
+        q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+        big = jnp.cumsum(g, axis=-1)                          # G
+        i = jnp.arange(chunk)
+        lower = i[:, None] >= i[None, :]
+        # the mask goes on the exponent: exp of a masked-out positive
+        # difference would overflow, and its zero cotangent would be NaN
+        m = jnp.exp(jnp.where(lower, big[..., :, None] - big[..., None, :],
+                              -jnp.inf))
+        kb = k * beta[..., None]
+        system = jnp.where(i[:, None] > i[None, :],
+                           jnp.einsum("...id,...jd->...ij", kb, k,
+                                      precision=hi) * m, 0.0) \
+            + jnp.eye(chunk, dtype=jnp.float32)
+        rhs = jnp.concatenate(
+            [v * beta[..., None], kb * jnp.exp(big)[..., None]], axis=-1)
+        solved = solve_triangular(system, rhs, lower=True,
+                                  unit_diagonal=True)
+        v_own, k_own = solved[..., :dv], solved[..., dv:]
+        qk = jnp.einsum("...id,...jd->...ij", q, k, precision=p) * m
+        q_in = q * jnp.exp(big)[..., None]
+        last = big[..., -1]                                   # G_C
+        k_out = k * jnp.exp(last[..., None] - big)[..., None]
+        decay = jnp.exp(last)
+
+        def step(state, xs):
+            v_own, k_own, qk, q_in, k_out, decay = xs
+            v_new = v_own - jnp.einsum("bhik,bhkv->bhiv", k_own, state,
+                                       precision=p)
+            o = jnp.einsum("bhik,bhkv->bhiv", q_in, state, precision=p) \
+                + jnp.einsum("bhij,bhjv->bhiv", qk, v_new, precision=p)
+            state = decay[..., None, None] * state \
+                + jnp.einsum("bhik,bhiv->bhkv", k_out, v_new, precision=p)
+            return state, o
+
+        xs = tuple(jnp.moveaxis(x, 2, 0)
+                   for x in (v_own, k_own, qk, q_in, k_out, decay))
+        _, o = jax.lax.scan(
+            step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
+        o = jnp.moveaxis(o, 0, 2)                             # (B,H,n,C,dv)
+        o = jnp.moveaxis(o, 1, 3).reshape(b, n * chunk, h, dv)
+    return o[:, :s].astype(v.dtype)
+
+
+def grouped_matmul(x, w, group_sizes):
+    """One product over a stack of matrices, the rows grouped as data:
+    ``x`` ``(M, K)`` sorted by group, ``w`` ``(G, K, N)``,
+    ``group_sizes`` ``(G,)`` int32; rows ``[sum(sizes[:g]),
+    sum(sizes[:g + 1]))`` are multiplied with ``w[g]``, rows past
+    ``sum(sizes)`` come back zero.  ``jax.lax.ragged_dot``: XLA's TPU
+    compiler lowers it to its own grouped-matmul kernel, which walks the
+    row tiles that hold a group's rows (their number is data), forward
+    and in both gradients; elsewhere it is a masked composition.  That
+    kernel leaves the rows past the groups as it finds them (whatever the
+    buffer held, NaN included), forward and in the gradient with respect
+    to ``x``; the select here, and its transpose on the way back, keep
+    such rows out of every operand of every product."""
+    sizes = group_sizes.astype(jnp.int32)
+    y = jax.lax.ragged_dot(x, w.astype(x.dtype), sizes)
+    return jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None], y,
+                     jnp.zeros((), y.dtype))
+
+
 def paged_copy_blocks(cache, src_blocks, dst_blocks):
     """Copy whole pages ``src_blocks[i] → dst_blocks[i]`` inside the
     paged pools — the device half of copy-on-write block sharing

@@ -6,6 +6,9 @@
 - sdxl_unet: Stable-Diffusion-XL UNet (conv/GroupNorm/attention breadth)
 - evabyte: EvaByte, a byte-level LM on EVA chunked linearized attention
   (window pages beside chunk summaries in the serving cache)
+- qwen3_next: Qwen3-Next, gated-delta linear-attention layers beside gated
+  softmax attention, dropless top-k experts with a shared expert (trains;
+  serving needs a cache kind that is not there yet)
 """
 
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, PRESETS,  # noqa: F401
@@ -14,6 +17,6 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, PRESETS,  # noqa:
 
 def __getattr__(name):
     import importlib
-    if name in ("gpt", "moe", "sdxl_unet", "evabyte"):
+    if name in ("gpt", "moe", "sdxl_unet", "evabyte", "qwen3_next"):
         return importlib.import_module(f".{name}", __name__)
     raise AttributeError(name)

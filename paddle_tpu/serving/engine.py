@@ -337,10 +337,14 @@ class Engine:
                  role: str = "both",
                  lora=None):
         if not _paged_supported(model):
+            # a model file may say what serving it still needs
+            # (``paged_serving_needs``: docs/SERVING.md "Cache kinds")
+            needs = getattr(model, "paged_serving_needs", None)
             raise NotImplementedError(
                 f"{type(model).__name__} does not support the paged "
                 "serving path (needs supports_paged decoder layers and "
-                "pipeline_stages == 1)")
+                "pipeline_stages == 1)"
+                + (f": serving it needs {needs}" if needs else ""))
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'both', 'prefill' or 'decode', got "

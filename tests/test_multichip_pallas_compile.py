@@ -511,7 +511,18 @@ CELL_KERNELS = {
     # ragged kernel under a name of its own (PR 32)
     "evabyte.serve-doc-bytes": {"eva_ragged_paged_attention": CELL_DEPTH,
                                 "fused_swiglu_mlp": CELL_DEPTH},
+    # one whole period (PR 34): flash attention in its one full layer, at
+    # d = 256; fused_adamw once a matrix whose last two dimensions fill
+    # its tiles: 2 a gated-delta layer (in_proj_qkvz, out_proj), 4 the
+    # full layer, 7 a sparse block (router, the three stacked expert
+    # leaves, the shared expert's three) and the embedding; the head's
+    # 18,992 columns are no whole 128 lanes and take XLA's composition
+    "qwen3-next-80b-a3b.train-8k": {"flash_attention_fwd": 1,
+                                    "flash_attention_bwd": 1,
+                                    "fused_adamw": 3 * 2 + 4 + 4 * 7 + 1},
 }
+# the cells compiled at another depth than CELL_DEPTH: a whole period
+CELL_DEPTHS = {"qwen3-next-80b-a3b.train-8k": 4}
 
 
 def _cell_files(name):
@@ -543,7 +554,8 @@ def _train_cell_hlo(name, topo):
     try:
         fleet.init(is_collective=True, devices=[topo.devices[0]])
         with nn.meta_init():
-            model = builder.build_model(config, CELL_DEPTH,
+            model = builder.build_model(config,
+                                        CELL_DEPTHS.get(name, CELL_DEPTH),
                                         config["max_position_embeddings"])
         opt = optimizer.AdamW(
             learning_rate=hp["learning_rate"], beta1=hp["beta1"],
@@ -624,6 +636,49 @@ def test_benchmark_cell_holds_its_kernel(cell, kernel, cell_kernels):
 @pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
 def test_benchmark_cell_holds_no_other_kernel(cell, cell_kernels):
     assert sorted(cell_kernels(cell)) == sorted(CELL_KERNELS[cell])
+
+
+QWEN = "qwen3-next-80b-a3b.train-8k"
+
+
+def _kernel_calls(hlo, kernel):
+    """The compiled step's custom-call lines of one Pallas kernel."""
+    return [ln for ln in hlo.splitlines() if " custom-call(" in ln
+            and re.match(r"\s*(?:ROOT )?%?" + kernel + r"[.\d]* = ", ln)]
+
+
+def test_qwen3_next_cell_runs_flash_attention_at_head_size_256(cell_hlo):
+    """16 query heads on 2 kv heads, d = 256, 8,192 positions: the
+    kernels' operands in the compiled step."""
+    hlo = cell_hlo(QWEN)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
+        (call,) = _kernel_calls(hlo, kernel)
+        assert "bf16[1,16,8192,256]" in call and "bf16[1,2,8192,256]" in call
+
+
+def test_qwen3_next_cell_updates_the_stacked_expert_leaves_fused(cell_hlo):
+    """``(32, 2048, 512)`` and ``(32, 512, 2048)`` go through
+    ``fused_adamw`` folded to ``(65536, 512)`` and ``(16384, 2048)``: 12
+    such calls in four layers."""
+    calls = _kernel_calls(cell_hlo(QWEN), "fused_adamw")
+    folded = [c for c in calls
+              if "f32[65536,512]" in c or "f32[16384,2048]" in c]
+    assert len(folded) == 12, len(folded)
+
+
+@pytest.mark.parametrize("scope", ["gated_delta_rule", "moe_router",
+                                   "moe_experts"])
+def test_qwen3_next_cell_carries_its_scopes(scope, cell_hlo):
+    """The three scopes that the cell's per-layer metrics read are in the
+    compiled step's ``op_name``s, under the regions they belong to."""
+    region = "attn_core" if scope == "gated_delta_rule" else "mlp"
+    names = re.findall(r'op_name="([^"]*)"', cell_hlo(QWEN))
+    mine = [n for n in names if f"/{scope}/" in n]
+    assert mine, scope
+    assert all(f"/{region}/" in n.split(scope)[0] + "/" for n in mine), \
+        [n for n in mine if f"/{region}/" not in n][:3]
+    # forward and backward alike
+    assert any("transpose(jvp(forward))" in n for n in mine)
 
 
 def test_fused_swiglu_mlp_block_width_at_evabyte_ffn():
