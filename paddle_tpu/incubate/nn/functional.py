@@ -779,18 +779,10 @@ def eva_paged_attend(cache, q, new_k, new_v, block_tables, span_lens, aux,
                                      page=kc.shape[1]), (kc, vc))
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
-    """The gated delta rule (Gated DeltaNet; ``models/qwen3_next.py``) in
-    its chunked form.  Per head, with a state ``S`` of ``(dk, dv)`` from
-    zero, position ``t`` does::
-
-        S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);
-        S <- S + k_t d^T;  o_t = S^T q_t
-
-    ``q``, ``k`` ``(B, S, H, dk)`` as the rule reads them (normalised and
-    scaled by the caller), ``v`` ``(B, S, H, dv)``, ``g`` (the log decay,
-    <= 0) and ``beta`` ``(B, S, H)``.  Returns ``o`` ``(B, S, H, dv)`` in
-    ``v``'s dtype.
+def _gated_delta_rule_ref(q, k, v, g, beta, chunk: int = 64):
+    """The chunked gated delta rule as an XLA composition: the path of
+    :func:`gated_delta_rule` where no kernel serves, and the yardstick the
+    kernel is held to.
 
     Nothing runs position by position.  Within a chunk of ``chunk``
     positions, with ``G`` the running sum of ``g`` and ``M[i, j] =
@@ -804,18 +796,18 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
         S <- exp(G_C) S + (k exp(G_C - G))^T v_new
 
     Every exponent is <= 0, so a strongly negative ``g`` underflows to an
-    exact forgetting and nothing overflows.  Float32 throughout; the
+    exact forgetting and nothing overflows.  Float32 throughout, ``o``
+    included (whatever the operands' dtypes: they are cast on entry); the
     triangular system at full precision, the products that read ``S`` at
-    the precision of :func:`_prec` (bfloat16 operands where the model is
-    bfloat16).  The backward pass is autodiff through the scan: one ``S``
-    and one ``v_new`` a chunk are kept (``S / chunk`` x ``H (dk + chunk)
-    dv`` float32 numbers).  A sequence that is no multiple of ``chunk``
-    is padded with positions that neither write the state nor are read
-    back (``k = beta = g = 0``)."""
+    the precision of :func:`_prec` of ``q``'s dtype.  The backward pass is
+    autodiff through the scan: one ``S`` and one ``v_new`` a chunk are
+    kept (``S / chunk`` x ``H (dk + chunk) dv`` float32 numbers)."""
     from jax.scipy.linalg import solve_triangular
 
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    if h != hk:
+        q, k = (jnp.repeat(x, h // hk, axis=2) for x in (q, k))
     p = _prec(q.dtype)
     hi = jax.lax.Precision.HIGHEST
     pad = -s % chunk
@@ -870,7 +862,50 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
             step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
         o = jnp.moveaxis(o, 0, 2)                             # (B,H,n,C,dv)
         o = jnp.moveaxis(o, 1, 3).reshape(b, n * chunk, h, dv)
-    return o[:, :s].astype(v.dtype)
+    return o[:, :s]
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
+    """The gated delta rule (Gated DeltaNet; ``models/qwen3_next.py``) in
+    its chunked form.  Per head, with a state ``S`` of ``(dk, dv)`` from
+    zero, position ``t`` does::
+
+        S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);
+        S <- S + k_t d^T;  o_t = S^T q_t
+
+    ``q``, ``k`` ``(B, S, Hk, dk)`` as the rule reads them (normalised and
+    scaled by the caller), ``v`` ``(B, S, Hv, dv)`` with ``Hv`` a multiple
+    of ``Hk`` (key head ``h // (Hv / Hk)`` serves value head ``h``), ``g``
+    (the log decay, <= 0) and ``beta`` ``(B, S, Hv)``.  Returns ``o``
+    ``(B, S, Hv, dv)`` in float32 on either path (the state is float32
+    and the caller's gated norm reads it so).  A sequence that is no multiple
+    of ``chunk`` is padded with positions that neither write the state nor
+    are read back (``k = beta = g = 0``).
+
+    Which path runs is read from the operands.  On TPU, with head sizes
+    that are multiples of 128, ``chunk`` 64, an even number of value heads
+    to a key head and no active mesh: the Pallas kernel pair of
+    ``ops/pallas/gated_delta.py`` under a ``jax.custom_vjp``: a chunk's
+    triangular system and ``S`` stay in VMEM; the triangular system in
+    float32 at full precision, the products that read or write ``S`` at
+    the precision the composition gives them (by ``q``'s dtype: float32 q
+    and k at ``HIGHEST``); the backward pass is a kernel too and the
+    forward keeps for it, a chunk, the state it started from and the
+    system's inverse (``S / chunk`` x ``Hv (dk dv + 2 chunk^2)`` float32
+    numbers).  Elsewhere (the CPU, a mesh, other shapes):
+    :func:`_gated_delta_rule_ref`, the same chunked algorithm as an XLA
+    composition under ``jax.checkpoint``, so that only its operands are
+    kept for autodiff's backward and its batched intermediates (2.5 GB a
+    layer at 8,192 positions of 32 heads) are recomputed there."""
+    from ...ops import dispatch as _dispatch
+    kernel = _dispatch.get("gated_delta_rule")
+    if kernel is not None:
+        out = kernel(q, k, v, g, beta, chunk)
+        if out is not None:
+            return out
+    return jax.checkpoint(
+        functools.partial(_gated_delta_rule_ref, chunk=chunk))(
+            q, k, v, g, beta)
 
 
 def grouped_matmul(x, w, group_sizes):

@@ -21,8 +21,13 @@ kinds and whose feed-forward block is sparse.
   ``linear_conv_kernel_dim`` with SiLU over the concatenated q, k, v;
   ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``; q and
   k L2-normalised; the gated delta rule in its chunked form
-  (``incubate.nn.functional.gated_delta_rule``); per head
-  ``(w * RMS(o)) * silu(z)`` (this one norm is plain); ``out_proj``.
+  (``incubate.nn.functional.gated_delta_rule``: on TPU the Pallas kernel
+  pair of ``ops/pallas/gated_delta.py``, which reads key head ``h // r``
+  for value head ``h`` itself, so q and k go in unrepeated, and which
+  keeps what its backward needs, so the rule is under no
+  ``jax.checkpoint`` here; elsewhere an XLA composition that carries its
+  own); per head ``(w * RMS(o)) * silu(z)`` (this one norm is plain);
+  ``out_proj``.
 - **Sparse block.**  ``distributed.moe.DroplessMoE``: softmax over all
   ``num_experts``, top ``num_experts_per_tok`` renormalised, the experts
   held here (``experts_held``) through one grouped product with no
@@ -50,7 +55,6 @@ loss.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -180,15 +184,14 @@ def l2_normalise(x, eps: float = 1e-6):
     return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
 
 
-def delta_core(q, k, v, g, beta, repeat: int):
+def delta_core(q, k, v, g, beta):
     """q, k ``(B, S, Hk, dk)`` after the conv, v ``(B, S, Hv, dv)``, g and
-    beta ``(B, S, Hv)``: L2-normalise q and k, scale q, let key head ``j``
-    serve value heads ``repeat * j ..``, and run the gated delta rule in
-    chunks of 64 positions."""
+    beta ``(B, S, Hv)``: L2-normalise q and k, scale q, and run the gated
+    delta rule in chunks of 64 positions; key head ``j`` serves value
+    heads ``(Hv / Hk) j ..`` inside the rule, unrepeated."""
     from ..incubate.nn.functional import gated_delta_rule
-    q = jnp.repeat(l2_normalise(q) * q.shape[-1] ** -0.5, repeat, axis=2)
-    k = jnp.repeat(l2_normalise(k), repeat, axis=2)
-    return gated_delta_rule(q, k, v, g, beta)
+    return gated_delta_rule(l2_normalise(q) * q.shape[-1] ** -0.5,
+                            l2_normalise(k), v, g, beta)
 
 
 class Qwen3NextAttention(Layer):
@@ -290,11 +293,10 @@ class Qwen3NextGatedDeltaNet(Layer):
                 ba[..., r:].reshape(b, s, hv).astype(jnp.float32)
                 + self.dt_bias.astype(jnp.float32))
         with region("attn_core"):
-            # only q, k, v, g and beta are kept for the backward pass: the
-            # core's own intermediates (some 2.5 GB a layer at 8,192
-            # positions) are recomputed there
-            o = jax.checkpoint(functools.partial(delta_core, repeat=r))(
-                q, k, v, g, beta)
+            # the rule keeps what its backward needs itself: a state and
+            # a (64, 64) inverse a chunk from the kernel, its operands
+            # alone (under jax.checkpoint) from the XLA composition
+            o = delta_core(q, k, v, g, beta)
         with region("norm"):
             o = F.rms_norm(o, self.norm.weight.astype(jnp.float32),
                            cfg.rms_norm_eps)

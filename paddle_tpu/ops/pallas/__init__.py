@@ -7,6 +7,7 @@ XLA fallback so CPU tests remain authoritative for numerics.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from ...core import compat as _compat
 from .. import dispatch
@@ -168,6 +169,20 @@ def _fused_adamw_dispatch(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
 
 
 dispatch.register("fused_adamw", _fused_adamw_dispatch, platform="tpu")
+
+from . import gated_delta as _gd
+
+
+def _gated_delta_rule_dispatch(q, k, v, g, beta, chunk, interpret=False):
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if _active_mesh() is not None \
+            or not _gd.supported(q, k, v, g, beta, chunk):
+        return None
+    return _gd.gated_delta_rule(q, k, v, g, beta, interpret)
+
+
+dispatch.register("gated_delta_rule", _gated_delta_rule_dispatch,
+                  platform="tpu")
 
 from . import lora_matmul as _lora
 

@@ -516,9 +516,13 @@ CELL_KERNELS = {
     # its tiles: 2 a gated-delta layer (in_proj_qkvz, out_proj), 4 the
     # full layer, 7 a sparse block (router, the three stacked expert
     # leaves, the shared expert's three) and the embedding; the head's
-    # 18,992 columns are no whole 128 lanes and take XLA's composition
+    # 18,992 columns are no whole 128 lanes and take XLA's composition;
+    # the gated delta rule's kernel pair once a gated-delta layer (PR 35:
+    # no jax.checkpoint around it, so the forward runs once)
     "qwen3-next-80b-a3b.train-8k": {"flash_attention_fwd": 1,
                                     "flash_attention_bwd": 1,
+                                    "gated_delta_rule_fwd": 3,
+                                    "gated_delta_rule_bwd": 3,
                                     "fused_adamw": 3 * 2 + 4 + 4 * 7 + 1},
 }
 # the cells compiled at another depth than CELL_DEPTH: a whole period
@@ -654,6 +658,49 @@ def test_qwen3_next_cell_runs_flash_attention_at_head_size_256(cell_hlo):
     for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
         (call,) = _kernel_calls(hlo, kernel)
         assert "bf16[1,16,8192,256]" in call and "bf16[1,2,8192,256]" in call
+
+
+def _delta_rule_operands(shape):
+    """The cell's own: 8,192 positions, 16 key heads serving 32 value
+    heads of 128; q and k float32 from the normalisation, v bfloat16."""
+    return (shape((1, 8192, 16, 128), F32), shape((1, 8192, 16, 128), F32),
+            shape((1, 8192, 32, 128)), shape((1, 8192, 32), F32),
+            shape((1, 8192, 32), F32))
+
+
+def test_gated_delta_rule_pair_compiles_for_v5e_at_the_cell_operands(
+        shape, as_tpu):
+    """The public entry takes the kernel pair at the cell's operands, and
+    Mosaic compiles both: q and k read unrepeated as ``(1, 8192, 2048)``
+    views, the forward keeps a state a value head and chunk and an
+    inverse a head pair and chunk, the backward returns dq and dk a key
+    head."""
+    from paddle_tpu.incubate.nn import functional as IF
+    args = _delta_rule_operands(shape)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(IF.gated_delta_rule(q, k, v, g, beta)
+                       .astype(jnp.float32))
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    (fwd,) = _kernel_calls(hlo, "gated_delta_rule_fwd")
+    (bwd,) = _kernel_calls(hlo, "gated_delta_rule_bwd")
+    for call in (fwd, bwd):
+        assert "f32[1,8192,2048]" in call and "bf16[1,8192,4096]" in call
+        assert "f32[1,32,128,128,128]" in call      # a state a chunk
+        assert "f32[1,16,128,128,128]" in call      # an inverse a chunk
+        assert "f32[1,16,128,1,128]" in call        # G and beta, packed
+    outs = bwd.split(" custom-call(")[0]
+    assert outs.count("f32[1,8192,2048]") == 2, outs    # dq, dk
+
+
+def test_gated_delta_rule_gate_at_the_cell_operands(shape):
+    from paddle_tpu.ops.pallas import gated_delta as m
+    q, k, v, g, beta = _delta_rule_operands(shape)
+    assert m.supported(q, k, v, g, beta, 64)
+    assert not m.supported(q, k, v, g, beta, 128)
+    odd = shape((1, 8192, 16, 192), F32)
+    assert not m.supported(odd, odd, v, g, beta, 64)
 
 
 def test_qwen3_next_cell_updates_the_stacked_expert_leaves_fused(cell_hlo):

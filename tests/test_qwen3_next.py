@@ -200,6 +200,196 @@ def test_chunked_delta_rule_gradients_match_the_recurrence():
             jnp.max(jnp.abs(b))), rtol=0)
 
 
+def interpret_delta_kernel(monkeypatch):
+    """The gated delta rule's TPU dispatch (its gate and both kernels)
+    through the Pallas interpreter on the CPU; counts the calls the
+    kernel served and the calls it declined."""
+    from paddle_tpu.ops import dispatch
+    from paddle_tpu.ops import pallas as P
+    calls = {"served": 0, "declined": 0}
+
+    def kernel(q, k, v, g, beta, chunk):
+        out = P._gated_delta_rule_dispatch(q, k, v, g, beta, chunk,
+                                           interpret=True)
+        calls["declined" if out is None else "served"] += 1
+        return out
+    monkeypatch.setitem(dispatch._REGISTRY, "gated_delta_rule", kernel)
+    monkeypatch.setitem(dispatch._PLATFORM, "gated_delta_rule", "cpu")
+    return calls
+
+
+@pytest.fixture
+def interpreted_delta_kernel(monkeypatch):
+    return interpret_delta_kernel(monkeypatch)
+
+
+# name: (positions, key heads, value heads, v's dtype, decay).  Two value
+# heads are one grid step's packed rows and read one key head through the
+# index map (dq, dk summed inside the step); 200 positions are three
+# whole chunks of 64 and a ragged fourth, so the state crosses chunks and
+# dS crosses back; "two_keys" is two grid steps a chunk, a key head each,
+# "repeat_4" two steps that share one (their dq, dk summed after the
+# kernel); "forget" is exp underflowing inside the decay matrix and
+# between chunks; "bf16" is the cell's operands (float32 q and k from the
+# normalisation beside bfloat16 v: every product at full precision),
+# "bf16_qk" q and k in bfloat16 too (the state products round their
+# operands to bfloat16)
+DELTA_KERNEL_CASES = {
+    "ragged": (200, 1, 2, jnp.float32, "mixed"),
+    "whole": (256, 1, 2, jnp.float32, "mixed"),
+    "forget": (192, 1, 2, jnp.float32, "strong"),
+    "keep": (130, 1, 2, jnp.float32, "near_zero"),
+    "two_keys": (136, 2, 4, jnp.float32, "mixed"),
+    "repeat_4": (136, 1, 4, jnp.float32, "mixed"),
+    "bf16": (200, 1, 2, jnp.bfloat16, "mixed"),
+    "bf16_qk": (200, 1, 2, jnp.bfloat16, "mixed"),
+}
+
+
+def delta_kernel_operands(case):
+    s, hk, hv, dtype, decay = DELTA_KERNEL_CASES[case]
+    rng = np.random.default_rng(s + hk + hv)
+    d = 128
+    q, k = (rng.normal(size=(1, s, hk, d)) for _ in range(2))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=(1, s, hv, d))
+    beta = rng.uniform(0.05, 1.0, size=(1, s, hv))
+    g = {"strong": np.full((1, s, hv), -20.0),
+         "near_zero": np.full((1, s, hv), -1e-3),
+         "mixed": -rng.exponential(1.0, size=(1, s, hv))
+         * rng.choice([1e-3, 1.0, 30.0], size=(1, s, hv))}[decay]
+    # q and k float32 as the model's normalisation hands them over
+    # ("bf16_qk": in v's dtype)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    qk = lambda x: jnp.asarray(x, dtype if case == "bf16_qk" else jnp.float32)
+    return qk(q), qk(k), jnp.asarray(v, dtype), f32(g), f32(beta)
+
+
+def delta_recurrence(q, k, v, g, beta):
+    """The reference's position-by-position rule, float32, every value
+    head with its key head's q and k."""
+    rep = v.shape[2] // q.shape[2]
+    return ref.delta_recurrence(
+        *(jnp.repeat(x[0].astype(jnp.float32), rep, 1) for x in (q, k)),
+        v[0].astype(jnp.float32), g[0], beta[0])[None]
+
+
+def rel_gap(a, b, floor=0.0):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), floor))
+
+
+def delta_kernel_tolerance(case, dtype):
+    """Of the norm: float32 sums in another order; ``dv`` rounded to
+    bfloat16 once; with bfloat16 q and k every state product's operands
+    rounded."""
+    return 2e-2 if case == "bf16_qk" else \
+        2e-5 if dtype == jnp.float32 else 4e-3
+
+
+@pytest.mark.parametrize("case", sorted(DELTA_KERNEL_CASES))
+def test_delta_kernel_matches_the_recurrence_and_the_composition(
+        case, interpreted_delta_kernel):
+    """The Pallas kernel (interpreted) against the definition and against
+    the XLA composition it replaces on the chip.  Float32: sums in
+    another order, with bfloat16 ``v`` too (``o`` is float32 on both
+    paths).  bfloat16 q and k: every operand of the state products is
+    rounded to 8 bits of mantissa."""
+    args = delta_kernel_operands(case)
+    got = IF.gated_delta_rule(*args)
+    assert interpreted_delta_kernel == {"served": 1, "declined": 0}
+    assert got.dtype == jnp.float32 and got.shape == args[2].shape
+    tol = delta_kernel_tolerance(case, got.dtype)
+    for want in (delta_recurrence(*args), IF._gated_delta_rule_ref(*args)):
+        assert float(jnp.max(jnp.abs(want))) > 0.02
+        assert rel_gap(got, want) < tol
+
+
+@pytest.mark.parametrize("case", sorted(DELTA_KERNEL_CASES))
+def test_delta_kernel_gradients_match_the_recurrence_and_the_composition(
+        case, interpreted_delta_kernel):
+    """The backward kernel against autodiff through the recurrence and
+    through the composition, in all five inputs; every gradient finite
+    (a strongly negative ``g`` among the cases).  The floor under the
+    norm is for ``dg`` where everything is forgotten: it is 1e-10 an
+    element there, and the composition's own reads 1e-9 (the diagonal of
+    ``q k^T * M`` reaches ``G_i`` with both signs and cancels in
+    rounding only; the kernel leaves it out)."""
+    args = delta_kernel_operands(case)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape),
+                    jnp.float32)
+
+    def grads(rule):
+        return jax.grad(lambda *a: jnp.sum(
+            rule(*a).astype(jnp.float32) * w), argnums=range(5))(*args)
+    got = grads(IF.gated_delta_rule)
+    assert interpreted_delta_kernel["served"] >= 1
+    assert interpreted_delta_kernel["declined"] == 0
+    for rule in (delta_recurrence, IF._gated_delta_rule_ref):
+        for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), got,
+                              grads(rule)):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))), name
+            assert rel_gap(a, b, floor=1e-2) < delta_kernel_tolerance(
+                case, a.dtype), (name, rule.__name__)
+
+
+@pytest.mark.parametrize(
+    "why", ["head_size", "odd_heads", "own_keys", "chunk", "mesh"])
+def test_delta_kernel_declines_into_the_composition(
+        why, interpreted_delta_kernel):
+    """What the gate does not admit takes the XLA composition, with the
+    composition's results: a head size that is no multiple of 128, an odd
+    number of value heads to a key head (three, or one: a key head a
+    value head), another chunk than the kernel's, an active mesh (Mosaic
+    kernels cannot be partitioned by GSPMD)."""
+    import contextlib
+    rng = np.random.default_rng(5)
+    hk, hv, d = {"odd_heads": (1, 3, 128), "own_keys": (2, 2, 128),
+                 "head_size": (1, 2, 64)}.get(why, (1, 2, 128))
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 70, h, d)) * d ** -0.5,
+                           jnp.float32) for h in (hk, hk, hv))
+    g = jnp.asarray(-rng.exponential(1.0, size=(1, 70, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.1, 1.0, size=(1, 70, hv)), jnp.float32)
+    chunk = 32 if why == "chunk" else 64
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("mp",)) \
+        if why == "mesh" else contextlib.nullcontext()
+    with mesh:
+        got = IF.gated_delta_rule(q, k, v, g, beta, chunk=chunk)
+    assert interpreted_delta_kernel == {"served": 0, "declined": 1}
+    np.testing.assert_array_equal(
+        got, IF._gated_delta_rule_ref(q, k, v, g, beta, chunk=chunk))
+
+
+def test_gated_delta_mixer_is_the_same_through_the_kernel(monkeypatch):
+    """One gated-delta mixer at the release's head size (128; one key
+    head serving two value heads, unrepeated): the loss and every
+    parameter's gradient through the kernel pair equal those through the
+    recurrence (the composition's ``A_log`` and ``dt_bias`` gradients are
+    1 % off both here: the rounding in its ``dg``, as above)."""
+    pt.seed(0)
+    cfg = Q.Qwen3NextConfig(
+        hidden_size=64, linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128)
+    mixer = Q.Qwen3NextGatedDeltaNet(cfg)
+    params = raw_params(mixer)
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(1, 150, 64)),
+                    jnp.float32)
+
+    def loss(params, x):
+        return jnp.sum(jnp.sin(functional_call(mixer, params, x)))
+    with monkeypatch.context() as m:
+        m.setattr(IF, "gated_delta_rule", delta_recurrence)
+        want = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    calls = interpret_delta_kernel(monkeypatch)
+    got = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    assert calls == {"served": 1, "declined": 0}
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, a), (_, b) in zip(flat(got), flat(want)):
+        assert rel_gap(a, b) < 1e-4, jax.tree_util.keystr(path)
+
+
 def test_conv_matches_the_explicit_sum():
     rng = np.random.default_rng(1)
     u = jnp.asarray(rng.normal(size=(2, 11, 6)), jnp.float32)
