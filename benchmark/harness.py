@@ -48,7 +48,8 @@ class Tracer:
 
 def span(name: str):
     """A host span in the profiler's own trace (``bench.<name>``), so that
-    an idle gap of the device gets an owner."""
+    an idle gap of the device gets an owner; the program's own spans are
+    there as ``pdtpu.<name>`` and ``trace_reduce`` reads both."""
     import jax
 
     return jax.profiler.TraceAnnotation("bench." + name)
@@ -83,6 +84,16 @@ def judge(numbers: dict, limits: dict):
     return ok, checks
 
 
+def registry_names(ctx: dict) -> list:
+    """The entries of the program's metrics registry that this cell's
+    per-layer readers name (``REGISTRY = [...]`` in a metric's file): what
+    a runner snapshots, since the registry is gone before a reader runs."""
+    names = set()
+    for name in ctx["per_layer"]:
+        names.update(getattr(ctx["load_metric"](name), "REGISTRY", ()))
+    return sorted(names)
+
+
 def per_layer_metrics(ctx: dict, rctx: dict) -> dict:
     """Run this cell's readers.  One that finds nothing to read returns
     None and its metric is left out of the line."""
@@ -113,6 +124,9 @@ def result_line(ctx: dict, *, correct: bool, attempted: int, failed: int,
         res["breakdown"] = trace_reduce.breakdown(rctx["trace"])
         log("device operations by time, seconds in the traced window:",
             trace_reduce.top_ops(rctx["trace"], 40))
+        log("the longest idle gaps of the device, start on the trace's "
+            "clock, milliseconds, owners:",
+            trace_reduce.longest_idle_gaps(rctx["trace"]))
     else:
         res["metrics"] = {n: {"value": end_to_end[n], "unit": u}
                           for n, u in ctx["end_to_end"].items()}

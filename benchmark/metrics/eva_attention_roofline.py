@@ -8,9 +8,10 @@ engine's own count (``kv_blocks_used``, pages of both kinds), polled every
 query sees.  On a program without the kernel there is nothing to read."""
 
 from benchmark import trace_reduce
-from benchmark.work import eva, transformer
+from benchmark.work import counters, eva, transformer
 
 PATTERNS = [r"eva_ragged_paged_attention"]
+REGISTRY = ["serve.ragged_occupancy"]
 
 
 def read(ctx):
@@ -27,8 +28,8 @@ def read(ctx):
     live_rows, slots = transformer.live_context(ctx["counters"], eng)
     nbytes = len(runs) * live_rows * eva.row_bytes(ctx["config"],
                                                    ctx["layers"])
-    t = ctx["counters"].get("traced") or {}
-    processed = t.get("occ_sum", 0.0) * eng["max_batch"] * eng["prefill_chunk"]
+    occ = counters.histogram(ctx, "traced", REGISTRY[0]) or (0.0, 0)
+    processed = occ[0] * eng["max_batch"] * eng["prefill_chunk"]
     flops = processed * eva.attention_flops_per_token(
         ctx["config"], ctx["layers"], live_rows / slots)
     least, bound = transformer.roofline_seconds(flops, nbytes, ctx["peaks"])

@@ -5,17 +5,19 @@ head per emitted token, attention over the mean context), over the
 traced window and the peak.  Padded lanes earn nothing."""
 
 from benchmark import trace_reduce
-from benchmark.work import transformer
+from benchmark.work import counters, transformer
+
+REGISTRY = ["serve.ragged_occupancy"]
 
 
 def read(ctx):
-    t = ctx["counters"].get("traced") or {}
+    occ = counters.histogram(ctx, "traced", REGISTRY[0])
     bw = trace_reduce.busy_and_window(ctx["trace"])
-    if not t.get("occ_count") or bw["window_s"] <= 0:
+    if occ is None or bw["window_s"] <= 0:
         return None
     eng = ctx["engine"]
     lanes = eng["max_batch"] * eng["prefill_chunk"]
-    processed = t["occ_sum"] * lanes
+    processed = occ[0] * lanes
     live_tokens, slots = transformer.live_context(ctx["counters"], eng)
     flops = transformer.serve_flops(
         ctx["config"], ctx["layers"], processed,

@@ -6,16 +6,18 @@ summariser's products), over the traced window and the peak.  Padded
 lanes and the seven unread prediction heads earn nothing."""
 
 from benchmark import trace_reduce
-from benchmark.work import eva, transformer
+from benchmark.work import counters, eva, transformer
+
+REGISTRY = ["serve.ragged_occupancy"]
 
 
 def read(ctx):
-    t = ctx["counters"].get("traced") or {}
+    occ = counters.histogram(ctx, "traced", REGISTRY[0])
     bw = trace_reduce.busy_and_window(ctx["trace"])
-    if not t.get("occ_count") or bw["window_s"] <= 0:
+    if occ is None or bw["window_s"] <= 0:
         return None
     eng = ctx["engine"]
-    processed = t["occ_sum"] * eng["max_batch"] * eng["prefill_chunk"]
+    processed = occ[0] * eng["max_batch"] * eng["prefill_chunk"]
     live_rows, slots = transformer.live_context(ctx["counters"], eng)
     flops = eva.serve_flops(ctx["config"], ctx["layers"], processed,
                             ctx["counters"].get("traced_emitted", 0),

@@ -5,9 +5,10 @@ pages are the engine's own count (``kv_blocks_used``), polled every 50 ms
 while the trace runs."""
 
 from benchmark import trace_reduce
-from benchmark.work import transformer
+from benchmark.work import counters, transformer
 
 PATTERNS = [r"ragged_paged_attention"]
+REGISTRY = ["serve.ragged_occupancy"]
 
 
 def read(ctx):
@@ -24,8 +25,8 @@ def read(ctx):
     live_tokens, slots = transformer.live_context(ctx["counters"], eng)
     nbytes = len(runs) * live_tokens * transformer.kv_bytes_per_token(
         ctx["config"], ctx["layers"])
-    t = ctx["counters"].get("traced") or {}
-    processed = t.get("occ_sum", 0.0) * eng["max_batch"] * eng["prefill_chunk"]
+    occ = counters.histogram(ctx, "traced", REGISTRY[0]) or (0.0, 0)
+    processed = occ[0] * eng["max_batch"] * eng["prefill_chunk"]
     h = ctx["config"]["num_attention_heads"] * ctx["config"]["head_dim"]
     context = live_tokens / slots
     flops = 4.0 * h * context * ctx["layers"] * processed

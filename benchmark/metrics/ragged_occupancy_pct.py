@@ -4,9 +4,11 @@ mean over the window's steps of the registry histogram
 registry exists only under ``observability.enable()``, which the traced
 run turns on."""
 
+from benchmark.work import counters
+
+REGISTRY = ["serve.ragged_occupancy"]
+
 
 def read(ctx):
-    w = ctx["counters"].get("window") or {}
-    if not w.get("occ_count"):
-        return None
-    return 100.0 * w["occ_sum"] / w["occ_count"]
+    mean = counters.mean(ctx, "window", REGISTRY[0])
+    return None if mean is None else 100.0 * mean

@@ -156,9 +156,12 @@ def make_step(family, cfg, layers: int, hp: dict, mode: str = "f32",
     lr, wd, clip = hp["learning_rate"], hp["weight_decay"], hp["clip_norm"]
 
     def loss_of(p, ids, labels):
-        if fault == "half_batch":
-            half = max(1, ids.shape[0] // 2)
+        if fault == "half_batch" and ids.shape[0] > 1:
+            half = ids.shape[0] // 2
             ids, labels = ids[:half], labels[:half]
+        elif fault == "half_batch":       # one row: half of its positions
+            half = ids.shape[1] // 2
+            ids, labels = ids[:, :half], labels[:, :half]
         return batch_loss(family, p, ids, labels, cfg, prec, layers)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -194,7 +197,8 @@ def adamw_reference(family, cfg, layers: int, make_params0, batches: list,
 
     ``fault`` plants one of the faults a training cell can have, for the
     readings that set the limits: ``half_batch`` leaves the second half of
-    every batch out and takes the mean over the rest; ``state_unchanged``
+    every batch out (of a batch of one row, the second half of its
+    positions) and takes the mean over the rest; ``state_unchanged``
     is a step that returns the state it was given."""
     step = make_step(family, cfg, layers, hp, mode, fault)
     change = jax.jit(change_readings)

@@ -25,7 +25,7 @@ import time
 
 import numpy as np
 
-from benchmark import harness, traffic, weights
+from benchmark import harness, hlo, traffic, weights
 from benchmark.reference import common as ref_common
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -61,6 +61,21 @@ class Program:
         self.engine.warmup()
         self.server = serving.ServingServer(self.engine, port=0)
         self.host, self.port = self.server.start()
+
+    def compiled(self):
+        """(text, memory analysis) of the compiled step program at the
+        shapes ``Engine.warmup`` compiled it for: a cache hit."""
+        import jax.numpy as jnp
+
+        eng = self.engine
+        b, mb, c = eng.max_batch, eng.max_blocks_per_seq, eng.prefill_chunk
+        zeros = jnp.zeros((b,), jnp.int32)
+        compiled = eng._step_fn.lower(
+            eng.params, eng.kv.caches, jnp.zeros((b, c), jnp.int32),
+            jnp.full((b, mb), eng.kv.oob_block, jnp.int32), zeros, zeros,
+            jnp.zeros((b,), jnp.float32), eng._key, zeros, zeros,
+            eng._lora_stacks(), zeros, eng._device_aux([])).compile()
+        return compiled.as_text(), compiled.memory_analysis()
 
     def stop(self) -> None:
         self.server.begin_drain()
@@ -139,9 +154,10 @@ def make_schedule(ctx: dict, rate_rps: float = None, seconds: float = None,
 
 
 def drive(ctx: dict, prog: Program, schedule: list, seconds: float,
-          tracer=None, registry=None) -> dict:
+          tracer=None, registry=None, names=()) -> dict:
     """Lead-in, window and grace.  Returns the child's records and what the
-    main thread read meanwhile."""
+    main thread read meanwhile: of the registry, the entries ``names`` at
+    the window's start and end and around the traced seconds."""
     cell = ctx["cell"]
     t = cell["traffic"]
     lead, grace = float(t["lead_in_s"]), float(t["grace_s"])
@@ -153,10 +169,10 @@ def drive(ctx: dict, prog: Program, schedule: list, seconds: float,
         info["setup_s"] = time.perf_counter() - ctx["t_start"]
         with harness.sentinel() as sent:
             c0 = sent.compiles()
-            snap0 = _registry_snapshot(registry)
+            snap0 = _registry_snapshot(registry, names)
             if tracer is not None:
                 time.sleep(cell["trace"]["after_s"])
-                tr0 = _registry_snapshot(registry)
+                tr0 = _registry_snapshot(registry, names)
                 tracer.start()
                 tw0 = time.monotonic() - t0
                 t_end = time.monotonic() + cell["trace"]["seconds"]
@@ -166,11 +182,11 @@ def drive(ctx: dict, prog: Program, schedule: list, seconds: float,
                 tracer.stop()
                 info["trace_window"] = (tw0, time.monotonic() - t0)
                 info["trace_counters"] = _registry_delta(
-                    tr0, _registry_snapshot(registry))
+                    tr0, _registry_snapshot(registry, names))
             time.sleep(max(0.0, t0 + seconds - time.monotonic()))
             info["compiles"] = sent.compiles() - c0
             info["window_counters"] = _registry_delta(
-                snap0, _registry_snapshot(registry))
+                snap0, _registry_snapshot(registry, names))
         info["device"] = ctx["device_report"]()
         info["records"] = gen.results(timeout=grace + 120.0)
     finally:
@@ -178,17 +194,28 @@ def drive(ctx: dict, prog: Program, schedule: list, seconds: float,
     return info
 
 
-def _registry_snapshot(registry):
+def _registry_snapshot(registry, names=()):
+    """name -> ``{"sum", "count"}`` of a histogram, ``{"value"}`` of a
+    counter, for the entries of ``names`` that exist by now."""
     if registry is None:
         return None
-    h = registry.histogram("serve.ragged_occupancy")
-    return {"occ_sum": h.sum, "occ_count": h.count}
+    out = {}
+    for name in names:
+        entry = registry.get(name)
+        if hasattr(entry, "sum") and hasattr(entry, "count"):
+            out[name] = {"sum": entry.sum, "count": entry.count}
+        elif isinstance(getattr(entry, "value", None), (int, float)):
+            out[name] = {"value": entry.value}
+    return out
 
 
 def _registry_delta(a, b):
+    """What was added between two snapshots, entry by entry; an entry the
+    program made in between started from nought."""
     if a is None or b is None:
         return {}
-    return {k: b[k] - a[k] for k in a}
+    return {name: {k: v - a.get(name, {}).get(k, 0) for k, v in now.items()}
+            for name, now in b.items()}
 
 
 def client_metrics(records: list, schedule: list, seconds: float,
@@ -268,18 +295,20 @@ def reference_gaps(ctx: dict, shapes: dict, sequences: list,
 
 def run(ctx: dict) -> dict:
     cell = ctx["cell"]
-    registry = None
+    registry, names = None, ()
     if ctx["trace"]:
         # the registry exists only under observability.enable(): the
         # traced run enables it, the untraced run does not
         from paddle_tpu import observability as obs
 
         registry = obs.enable(crash_hooks=False).registry
+        names = harness.registry_names(ctx)
     prog = Program(ctx)
     schedule = make_schedule(ctx)
     tracer = harness.Tracer() if ctx["trace"] else None
     try:
-        info = drive(ctx, prog, schedule, ctx["seconds"], tracer, registry)
+        info = drive(ctx, prog, schedule, ctx["seconds"], tracer, registry,
+                     names)
     finally:
         prog.stop()
         if ctx["trace"]:
@@ -299,6 +328,20 @@ def run(ctx: dict) -> dict:
     engine_facts = {"max_batch": prog.engine.max_batch,
                     "prefill_chunk": prog.engine.prefill_chunk,
                     "page_size": prog.engine.page_size}
+    scopes = {}
+    if ctx["trace"]:
+        text, mem = prog.compiled()
+        scopes = hlo.instruction_scopes(text)
+        harness.log("pallas kernels in the compiled step:",
+                    hlo.pallas_kernels(text), "argument bytes",
+                    mem.argument_size_in_bytes, "temporary bytes",
+                    mem.temp_size_in_bytes, "instructions with a scope "
+                    "path:", len(scopes))
+        if not scopes:
+            harness.log("the compiled step's text names no op_name (a "
+                        "compile cache keyed without op metadata can "
+                        "return one): the readers of regions read nothing")
+        del text
     prog.free()
 
     numbers = {"requests_failed": (float(m["failed"]),
@@ -324,7 +367,7 @@ def run(ctx: dict) -> dict:
         in_flight = sum(1 for r in recs if r["sent_s"] is not None
                         and r["sent_s"] <= mid and r["token_s"]
                         and r["token_s"][-1] >= mid)
-        rctx = {"trace": tracer.read(), "scopes": {}, "cell": cell,
+        rctx = {"trace": tracer.read(), "scopes": scopes, "cell": cell,
                 "config": ctx["config"], "layers": cell["num_hidden_layers"],
                 "peaks": ctx["peaks"], "client": m,
                 "engine": engine_facts,

@@ -91,3 +91,9 @@ def events():
                         "trace_events.json")
     with open(path) as f:
         return json.load(f)
+
+
+@pytest.fixture
+def serve_events(events):
+    """The file's second trace: a serving loop's thread and a handler's."""
+    return events["serve"]

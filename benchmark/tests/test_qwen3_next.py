@@ -190,6 +190,9 @@ def test_run_is_correct_and_the_control_is_not(seed):
     assert rows["fault_state_unchanged"]["correct"] is False
     assert rows["fault_state_unchanged"]["numbers"]["change_norm_gap"] == \
         pytest.approx(1.0)
+    # the cell's batch is one row: the fault leaves half its positions out
+    assert rows["fault_half_batch"]["correct"] is False, \
+        rows["fault_half_batch"]
 
 
 def test_run_prints_the_cells_line():

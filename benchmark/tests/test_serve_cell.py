@@ -4,7 +4,11 @@ a token altered where it is produced."""
 
 from conftest import tiny_ctx
 
+from benchmark import harness
+from benchmark import run as brun
+from benchmark import trace_reduce
 from benchmark.runners import serve
+from benchmark.work import regions
 
 CELL = "mistral-7b.serve-chat"
 
@@ -17,6 +21,63 @@ def test_run_is_correct():
                                    "serve_tokens_per_s", "setup_s"}
     assert all(m["value"] > 0 for m in res["metrics"].values())
     assert list(res)[-1] == "checks"
+
+
+def test_a_traced_run_reads_what_the_program_writes(monkeypatch):
+    """Under the profiler the loop's leaf phases are read on one thread
+    and no second of a gap is credited twice; the compiled step's text
+    gives the model's regions a leaf each; the registry entries the cell's
+    metric files name reach their readers."""
+    seen = {}
+    real = harness.result_line
+
+    def keep(ctx, **kw):
+        seen.update(kw["rctx"])
+        return real(ctx, **kw)
+
+    monkeypatch.setattr(harness, "result_line", keep)
+    ctx = tiny_ctx(CELL, 2**31 + 44, seconds=3.0,
+                   trace={"after_s": 0.3, "seconds": 1.5})
+    bench = brun.load_json("..", "BENCHMARK.json")
+    ctx.update(trace=True, per_layer={
+        m["name"]: m["unit"]
+        for m in brun.cell_metrics(bench, CELL, "per_layer")})
+    res = serve.run(ctx)
+    assert res["correct"] is True, res["checks"]
+
+    host = seen["trace"]["host"]
+    leaves = {"pdtpu.serve.step." + p for p in
+              ("admit", "plan", "dispatch", "sync", "emit", "account")}
+    threads = trace_reduce.by_thread(host)
+    loops = [t for t, spans in threads.items()
+             if leaves & {e[0] for e in spans}]
+    assert len(loops) == 1
+    assert leaves <= {e[0] for e in threads[loops[0]]}
+    # one gap over all the traced seconds: its owners sum to its length
+    t0, t1 = trace_reduce.span_of(host)
+    thread, rows = trace_reduce.gap_owners([(t0, t1)], host)
+    assert thread == loops[0]
+    (_, row), = rows
+    assert abs(sum(row.values()) - (t1 - t0)) < 1e-9
+    assert "pdtpu.serve.stream.write" not in row
+    # the parents own what their leaves leave (credited by overlap they
+    # would own as much as the leaves together)
+    parents = row.get("pdtpu.serve.step", 0) \
+        + row.get("pdtpu.serve.step.finish", 0)
+    assert parents < 0.25 * sum(v for k, v in row.items() if k in leaves)
+
+    found = {regions.region_of(path) for path in seen["scopes"].values()}
+    assert {"attn_proj", "attn_core", "mlp"} <= found
+
+    window = seen["counters"]["window"]
+    assert set(window) == {"serve.ragged_occupancy", "serve.queue_ms",
+                           "serve.prefill_steps", "serve.mlp_live_tiles"}
+    assert all(e["count"] > 0 for e in window.values())
+    for name in ("ragged_occupancy_pct", "queue_wait_mean_ms",
+                 "prefill_steps_mean", "mlp_live_tiles_mean"):
+        assert res["metrics"][name]["value"] > 0
+    # no device plane on the CPU: the readers of the trace read nothing
+    assert "host_ms_per_step.dispatch" not in res["metrics"]
 
 
 def test_fault_token_altered(monkeypatch):

@@ -48,6 +48,94 @@ def test_gap_attribution(events):
     assert owners["unattributed"] == pytest.approx(0.003 - 0.0016)
 
 
+LOOP, HANDLER = "/host:CPU/3:python", "/host:CPU/5:python"
+
+
+def serve_gaps(trace):
+    """The device's idle gaps, a binary fraction's rounding apart."""
+    return [(a, b) for a, b in tr.idle_gaps(trace["devices"][0]["ops"])
+            if b - a > 1e-9]
+
+
+def test_self_segments_give_a_parent_what_its_children_leave():
+    spans = [["parent", 0.0, 10.0], ["a", 2.0, 2.0], ["b", 6.0, 2.0],
+             ["b.inner", 6.5, 1.0], ["late", 12.0, 1.0]]
+    assert tr.self_segments(spans) == [
+        (0.0, 2.0, "parent"), (2.0, 4.0, "a"), (4.0, 6.0, "parent"),
+        (6.0, 6.5, "b"), (6.5, 7.5, "b.inner"), (7.5, 8.0, "b"),
+        (8.0, 10.0, "parent"), (12.0, 13.0, "late")]
+    # a child that outlasts its parent by a tick keeps what it covers
+    assert tr.self_segments([["p", 0.0, 1.0], ["c", 0.5, 0.7]]) == [
+        (0.0, 0.5, "p"), (0.5, 1.2, "c")]
+
+
+def test_gaps_go_to_the_loop_threads_leaves_by_self_time(serve_events):
+    gaps = serve_gaps(serve_events)
+    assert [(round(a, 4), round(b, 4)) for a, b in gaps] == [
+        (2.01, 2.015), (2.025, 2.03)]
+    thread, rows = tr.gap_owners(gaps, serve_events["host"])
+    assert thread == LOOP
+    # the first gap straddles sync's tail, emit ... dispatch: milliseconds
+    want = {"pdtpu.serve.step.sync": 0.1, "pdtpu.serve.step.emit": 0.5,
+            "pdtpu.serve.step.account": 0.2, "pdtpu.serve.step.finish": 0.1,
+            "pdtpu.serve.stream.route": 0.3, "pdtpu.serve.loop.wait": 0.1,
+            "pdtpu.serve.pump": 0.2, "pdtpu.serve.step": 0.3,
+            "pdtpu.serve.step.admit": 0.2, "pdtpu.serve.step.plan": 0.8,
+            "pdtpu.serve.step.dispatch": 1.9, "unattributed": 0.3}
+    for (a, b), row in rows:
+        assert {k: round(1e3 * v, 6) for k, v in row.items()} == want
+        # owners + unattributed = the gap's seconds
+        assert sum(row.values()) == pytest.approx(b - a, abs=1e-12)
+    owners = dict(tr.attribute_gaps(gaps, serve_events["host"], n=20))
+    assert sum(owners.values()) == pytest.approx(0.010, abs=1e-12)
+    assert owners["pdtpu.serve.step.dispatch"] == pytest.approx(0.0038)
+    # the parents own what their leaves leave, next to nothing
+    assert owners["pdtpu.serve.step"] == pytest.approx(0.0006)
+    assert owners["pdtpu.serve.step.finish"] == pytest.approx(0.0002)
+
+
+def test_one_thread_owns_the_gaps(serve_events):
+    gaps = serve_gaps(serve_events)
+    owners = dict(tr.attribute_gaps(gaps, serve_events["host"], n=20))
+    # the handler's write lies over each gap for 2 ms: credited, it would
+    # count those seconds twice
+    assert "pdtpu.serve.stream.write" not in owners
+    handler = [e for e in serve_events["host"] if e[3] == HANDLER]
+    assert tr.gap_owners(gaps, handler)[0] == HANDLER
+    alone = dict(tr.attribute_gaps(gaps, handler))
+    assert alone == {"pdtpu.serve.stream.write": pytest.approx(0.004),
+                     "unattributed": pytest.approx(0.006)}
+    # no span at all: everything is unattributed
+    assert tr.attribute_gaps(gaps, []) == [["unattributed",
+                                            pytest.approx(0.010)]]
+
+
+def test_the_smallest_owners_fold_into_other(serve_events):
+    gaps = serve_gaps(serve_events)
+    rows = tr.attribute_gaps(gaps, serve_events["host"], n=4)
+    assert [r[0] for r in rows] == [
+        "pdtpu.serve.step.dispatch", "pdtpu.serve.step.plan",
+        "pdtpu.serve.step.emit", "other"]
+    assert sum(r[1] for r in rows) == pytest.approx(0.010, abs=1e-12)
+    assert len(tr.breakdown(serve_events)["idle_gaps"]) == 10
+
+
+def test_the_longest_gaps_name_their_owners(serve_events):
+    ops = serve_events["devices"][0]["ops"]
+    # a pause of 40 ms in ``plan`` after the last step
+    ops = ops + [["fusion.1", 2.082, 0.003]]
+    host = serve_events["host"] + [
+        ["pdtpu.serve.step.plan", 2.043, 0.038, LOOP]]
+    got = tr.longest_idle_gaps({"devices": [{"ops": ops}], "host": host}, 2)
+    assert [g["ms"] for g in got] == [40.0, 5.0]
+    assert got[0]["start_s"] == 2.042
+    assert got[0]["owners"] == {"pdtpu.serve.step.plan": 38.0,
+                                "unattributed": 2.0}
+    assert list(got[1]["owners"])[0] == "pdtpu.serve.step.dispatch"
+    assert [g["ms"] for g in tr.longest_idle_gaps(serve_events)[:2]] == [
+        5.0, 5.0]
+
+
 def test_step_periods(events):
     per = tr.step_periods(events, r"^jit__step")
     assert per["periods"] == 1 and per["seconds"] == pytest.approx(0.010)

@@ -8,7 +8,10 @@ A trace, as this file holds it::
     {"devices": [{"name": "/device:TPU:0",
                   "ops": [[name, start_s, dur_s], ...],       # line "XLA Ops"
                   "modules": [[name, start_s, dur_s], ...]}], # "XLA Modules"
-     "host": [[name, start_s, dur_s], ...]}   # the harness's own spans
+     "host": [[name, start_s, dur_s, thread], ...]}
+     # the harness's ``bench.*`` spans and the program's ``pdtpu.*`` spans,
+     # each with the host line it was on (a hand-built list may leave the
+     # thread out: its spans are then on one thread)
 
 Times are seconds on the trace's one clock.
 """
@@ -22,6 +25,8 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+HOST_PREFIXES = ("bench.", "pdtpu.")
+UNATTRIBUTED = "unattributed"
 
 
 def find_xplane(log_dir: str) -> str:
@@ -38,7 +43,7 @@ def short_name(name: str) -> str:
     return name.split(" = ", 1)[0].lstrip("%")[:120]
 
 
-def read_xplane(path: str, host_prefix: str = "bench.") -> dict:
+def read_xplane(path: str) -> dict:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -59,11 +64,14 @@ def read_xplane(path: str, host_prefix: str = "bench.") -> dict:
             dev["modules"].sort(key=lambda e: e[1])
             out["devices"].append(dev)
         elif plane.name.startswith("/host:"):
-            for ln in lines:
+            for i, ln in enumerate(lines):
+                # every Python thread's line is named "python": the
+                # line's place in its plane tells them apart
+                thread = f"{plane.name}/{i}:{ln.name}"
                 for ev in ln.events:
-                    if ev.name.startswith(host_prefix):
+                    if ev.name.startswith(HOST_PREFIXES):
                         out["host"].append([ev.name, ev.start_ns * 1e-9,
-                                            ev.duration_ns * 1e-9])
+                                            ev.duration_ns * 1e-9, thread])
     out["host"].sort(key=lambda e: e[1])
     return out
 
@@ -181,23 +189,93 @@ def idle_gaps(events, t0: float = None, t1: float = None) -> list:
     return [(a, b) for a, b in gaps if b > a]
 
 
-def attribute_gaps(gaps, host_spans, n: int = 10) -> list:
-    """[[name, seconds]]: each gap's seconds go to the host spans that
-    overlap it (the harness's ``bench.*`` annotations), the rest to
-    ``unattributed``; the ``n`` largest owners."""
-    owners = {}
+def self_segments(spans) -> list:
+    """One thread's spans as disjoint ``[(start, end, name)]`` in time
+    order: every instant goes to the innermost span open at it, so a
+    span's seconds here are its self time, its duration less what its
+    children cover."""
+    out, stack, cursor = [], [], None        # stack: [name, end]
+
+    def close_until(t):
+        nonlocal cursor
+        while stack and stack[-1][1] <= t:
+            name, end = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, name))
+                cursor = end
+
+    for name, start, dur in sorted((e[:3] for e in spans),
+                                   key=lambda e: (e[1], -e[2])):
+        close_until(start)
+        if stack and start > cursor:
+            out.append((cursor, start, stack[-1][0]))
+        cursor = start if cursor is None or not stack else max(cursor, start)
+        stack.append([name, start + dur])
+    close_until(float("inf"))
+    return out
+
+
+def by_thread(host_spans) -> dict:
+    """thread -> its spans."""
+    out = {}
+    for ev in host_spans:
+        out.setdefault(ev[3] if len(ev) > 3 else "", []).append(ev)
+    return out
+
+
+def _owned(gaps, segments) -> list:
+    """For each gap, {name: seconds} of the segments' overlap with it;
+    both in time order and disjoint, so one pass."""
+    out, j = [], 0
     for a, b in gaps:
-        covered = []
-        for name, start, dur in host_spans:
-            lo, hi = max(a, start), min(b, start + dur)
+        while j < len(segments) and segments[j][1] <= a:
+            j += 1
+        row, k = {}, j
+        while k < len(segments) and segments[k][0] < b:
+            lo, hi = max(a, segments[k][0]), min(b, segments[k][1])
             if hi > lo:
-                owners[name] = owners.get(name, 0.0) + (hi - lo)
-                covered.append(["", lo, hi - lo])
-        rest = (b - a) - union_seconds(covered)
+                row[segments[k][2]] = row.get(segments[k][2], 0.0) + hi - lo
+            k += 1
+        out.append(row)
+    return out
+
+
+def gap_owners(gaps, host_spans):
+    """(thread, rows): for each gap ``{name: seconds}`` by the self time
+    of ONE thread's spans inside it, the rest of the gap under
+    ``unattributed``, so that a gap's row sums to its length.  The
+    thread is the one whose spans cover most of the gaps' seconds: the
+    one that dispatches the step program (the serving loop, a training
+    runner's main thread), whose phases tile its iteration.  Another
+    thread's spans (a handler writing a stream) overlap those phases and
+    would count seconds twice."""
+    gaps = sorted(gaps)
+    best, rows = None, [{} for _ in gaps]
+    for thread, spans in sorted(by_thread(host_spans).items()):
+        got = _owned(gaps, self_segments(spans))
+        total = sum(v for row in got for v in row.values())
+        if best is None or total > best[0]:
+            best, rows = (total, thread), got
+    for (a, b), row in zip(gaps, rows):
+        rest = (b - a) - sum(row.values())
         if rest > 0:
-            owners["unattributed"] = owners.get("unattributed", 0.0) + rest
-    return [[k, v] for k, v in
-            sorted(owners.items(), key=lambda kv: -kv[1])[:n]]
+            row[UNATTRIBUTED] = rest
+    return (best[1] if best else None), list(zip(gaps, rows))
+
+
+def attribute_gaps(gaps, host_spans, n: int = 10) -> list:
+    """[[name, seconds]]: the gaps' seconds by owner (``gap_owners``),
+    largest first, at most ``n`` rows: where there are more owners the
+    smallest are folded into ``other``, so the rows still sum to the
+    gaps' seconds."""
+    owners = {}
+    for _, row in gap_owners(gaps, host_spans)[1]:
+        for name, sec in row.items():
+            owners[name] = owners.get(name, 0.0) + sec
+    rows = sorted(owners.items(), key=lambda kv: -kv[1])
+    if len(rows) > n:
+        rows = rows[:n - 1] + [("other", sum(v for _, v in rows[n - 1:]))]
+    return [[k, v] for k, v in rows]
 
 
 def module_runs(trace: dict, pattern: str) -> list:
@@ -230,6 +308,20 @@ def breakdown(trace: dict) -> dict:
     ops = trace["devices"][0]["ops"]
     return {"device_ops": top_ops(trace),
             "idle_gaps": attribute_gaps(idle_gaps(ops), trace["host"])}
+
+
+def longest_idle_gaps(trace: dict, n: int = 5) -> list:
+    """The first device's ``n`` longest idle gaps, each ``{"start_s",
+    "ms", "owners"}`` with its owners' milliseconds, largest first: where
+    a pause fell."""
+    if not trace["devices"]:
+        return []
+    rows = gap_owners(idle_gaps(trace["devices"][0]["ops"]), trace["host"])[1]
+    rows.sort(key=lambda r: r[0][0] - r[0][1])
+    return [{"start_s": round(a, 6), "ms": round(1e3 * (b - a), 3),
+             "owners": {k: round(1e3 * v, 3) for k, v in
+                        sorted(row.items(), key=lambda kv: -kv[1])}}
+            for (a, b), row in rows[:n]]
 
 
 def step_periods(trace: dict, pattern: str):

@@ -16,7 +16,9 @@ leaves the regions empty, and the readers return nothing.
 
 from __future__ import annotations
 
+import bisect
 import re
+import statistics
 
 from benchmark import trace_reduce
 
@@ -40,6 +42,21 @@ def region_of(scope_path: str):
     return found
 
 
+def _step_leaves(ctx: dict):
+    """(runs of the step program, leaf events from the first run's start
+    to the last run's end) on the first device; reckoned once a run."""
+    if "step_leaves" not in ctx:
+        runs = trace_reduce.module_runs(ctx["trace"],
+                                        ctx["cell"]["step_program"])
+        leaves = []
+        if runs:
+            t0, t1 = trace_reduce.span_of(runs)
+            leaves = trace_reduce.leaf_ops(trace_reduce.clip_events(
+                ctx["trace"]["devices"][0]["ops"], t0, t1))
+        ctx["step_leaves"] = (runs, leaves)
+    return ctx["step_leaves"]
+
+
 def region_table(ctx: dict):
     """{"regions": {name: [seconds, events]}, "steps": runs of the step
     program, "seconds": all leaf device time} over the first run's start
@@ -52,15 +69,12 @@ def region_table(ctx: dict):
     if "region_table" in ctx:
         return ctx["region_table"]
     table = None
-    runs = trace_reduce.module_runs(ctx["trace"], ctx["cell"]["step_program"])
+    runs, leaves = _step_leaves(ctx)
     if runs:
-        t0, t1 = trace_reduce.span_of(runs)
-        ops = trace_reduce.clip_events(ctx["trace"]["devices"][0]["ops"],
-                                       t0, t1)
         scopes = ctx.get("scopes") or {}
         rows = {name: [0.0, 0] for name in REGIONS + (UNSCOPED,)}
         kinds = {name: {} for name in rows}
-        for name, _, dur in trace_reduce.leaf_ops(ops):
+        for name, _, dur in leaves:
             key = region_of(scopes.get(name)) or UNSCOPED
             rows[key][0] += dur
             rows[key][1] += 1
@@ -92,6 +106,40 @@ def region_seconds(ctx: dict, name: str):
         return None
     seconds, events = table["regions"][name]
     return seconds, events, table["steps"]
+
+
+def step_median_ms(ctx: dict, name: str):
+    """Median over the runs of the step program of one region's leaf
+    device milliseconds inside a run, or None where the scopes name no
+    region or this one is empty in every run.  A serving step's work
+    differs from step to step (a prompt's fan-out beside decode rows), so
+    the median is the usual step's; the table's sums are all steps'."""
+    if "step_medians" not in ctx:
+        medians = None
+        runs, leaves = _step_leaves(ctx)
+        table = region_table(ctx)
+        if table is not None and any(table["regions"][r][0] > 0
+                                     for r in REGIONS):
+            scopes = ctx.get("scopes") or {}
+            starts = [r[1] for r in runs]
+            per_run = [dict.fromkeys(REGIONS + (UNSCOPED,), 0.0)
+                       for _ in runs]
+            for op, start, dur in leaves:
+                i = bisect.bisect_right(starts, start) - 1
+                if i >= 0 and start < runs[i][1] + runs[i][2]:
+                    per_run[i][region_of(scopes.get(op)) or UNSCOPED] += dur
+            medians = {k: 1e3 * statistics.median(row[k] for row in per_run)
+                       for k in per_run[0]}
+            ctx["notes"].append(
+                f"the median step of {len(runs)} by region, ms: "
+                + ", ".join(f"{k} {v:.3f}" for k, v in medians.items())
+                + f"; together {sum(medians.values()):.3f} of a median "
+                f"step of {1e3 * statistics.median(r[2] for r in runs):.3f}")
+        ctx["step_medians"] = medians
+    medians = ctx["step_medians"]
+    if medians is None or medians[name] <= 0:
+        return None
+    return medians[name]
 
 
 def mlp_train_flops(config: dict, layers: int, tokens: int) -> float:
