@@ -15,9 +15,11 @@ remainder, and the step copy-on-writes any borrowed page before writing
 into it.
 
 Recompile contract: after :meth:`warmup` — ONE compile for the unified
-step plus one for the CoW page-copy helper — requests of ANY length mix
-joining and leaving the batch trigger ZERO further compiles (fixed span
-shapes, see ``scheduler.py``; enforced by the ``serving-smoke`` CI gate).
+step, one for the program that unpacks its inputs (they cross to the
+device as one buffer) and one for the CoW page-copy helper — requests of
+ANY length mix joining and leaving the batch trigger ZERO further
+compiles (fixed span shapes, see ``scheduler.py``; enforced by the
+``serving-smoke`` CI gate).
 Chunked prefill is what keeps that single shape honest: a 2k-token
 prompt and a decode token ride the same ``(B, C)`` dispatch, so heavy
 admission can no longer stall decode behind per-bucket prefill programs
@@ -620,6 +622,43 @@ class Engine:
         self._step_fn = jax.jit(step_fn, donate_argnums=(1,))
         self._cow_fn = jax.jit(cow_fn, donate_argnums=(0,))
 
+        # the step's per-row inputs cross to the device as ONE int32
+        # buffer (_step_args): each array an (empty) plan's inputs hold
+        # at a fixed (offset, size, shape, dtype), which the engine's own
+        # sizes and its cache kind's aux_shapes fix; float32 temps travel
+        # as their bits
+        self._aux_keys = tuple(self.cache_spec.aux_shapes(
+            self.max_batch, self.prefill_chunk) or ())
+        self._layout, off = [], 0
+        for a in self._host_inputs([]):
+            self._layout.append((off, a.size, a.shape, a.dtype))
+            off += a.size
+        self._packed = np.zeros((off,), np.int32)
+        # where the buffer lands: a single-chip engine's on the default
+        # device, uncommitted, as jnp.asarray puts an array (so the step
+        # program is the one separate arrays give); a mesh engine's
+        # replicated over its own devices, so that a DP replica's unpack
+        # never queues behind another replica's step on the default device
+        self._packed_to = None if self.mesh is None else \
+            jax.sharding.NamedSharding(self.mesh,
+                                       jax.sharding.PartitionSpec())
+        layout, aux_keys = tuple(self._layout), self._aux_keys
+
+        def unpack_fn(packed):
+            """The step's per-row inputs, cut from the one buffer by
+            static slices: the eight arrays of ``span_arrays`` and the
+            cache kind's aux dict (None where it has none)."""
+            out = []
+            for o, n, shape, dtype in layout:
+                seg = packed[o:o + n]
+                if dtype != np.int32:
+                    seg = jax.lax.bitcast_convert_type(seg, dtype)
+                out.append(seg.reshape(shape))
+            return (*out[:8],
+                    dict(zip(aux_keys, out[8:])) if aux_keys else None)
+
+        self._unpack_fn = jax.jit(unpack_fn)
+
     def _lora_stacks(self):
         """The stacked adapter pytree threaded through every step — the
         pool's cached device arrays (fixed shapes, so a hot load/evict
@@ -666,23 +705,14 @@ class Engine:
                 else contextlib.nullcontext()
 
         with span("serve.warmup"), self._trace_mesh():
-            b, mb, c = self.max_batch, self.max_blocks_per_seq, \
-                self.prefill_chunk
-            oob = np.full((b, mb), self.kv.oob_block, np.int32)
-            zeros_i = np.zeros((b,), np.int32)
             with _site("serve.step"):
+                # the unpack program and the step, as step_begin runs
+                # them: an empty plan's inputs
                 nxt, caches = self._step_fn(
-                    self.params, self.kv.caches,
-                    jnp.asarray(np.zeros((b, c), np.int32)),
-                    jnp.asarray(oob),
-                    jnp.asarray(zeros_i), jnp.asarray(zeros_i),
-                    jnp.asarray(np.zeros((b,), np.float32)),
-                    self._key, jnp.asarray(zeros_i), jnp.asarray(zeros_i),
-                    self._lora_stacks(), jnp.asarray(zeros_i),
-                    self._device_aux([]))
+                    *self._step_args(self._host_inputs([])))
                 jax.block_until_ready(nxt)
             self.kv.caches = caches
-            pad = np.full((b,), self.kv.oob_block, np.int32)
+            pad = np.full((self.max_batch,), self.kv.oob_block, np.int32)
             with _site("serve.cow"):
                 caches = self._cow_fn(self.kv.caches, jnp.asarray(pad),
                                       jnp.asarray(pad))
@@ -721,12 +751,40 @@ class Engine:
 
     def _device_aux(self, plan):
         """The cache kind's further step inputs for ``plan`` (an empty
-        plan: the warm-up's, whose writes all drop), on the device."""
+        plan: the warm-up's, whose writes all drop), on the device, each
+        array moved on its own: what a lowering of the step at its shapes
+        passes (the step itself takes them from :meth:`_step_args`)."""
         aux = self.cache_spec.step_aux(plan, self.max_batch,
                                        self.prefill_chunk,
                                        self.kv.oob_block)
         return None if aux is None else \
             {k: jnp.asarray(v) for k, v in aux.items()}
+
+    def _host_inputs(self, plan):
+        """The step's per-row inputs for ``plan`` (an empty plan: the
+        warm-up's) as host arrays, in the packed buffer's order: the
+        eight of ``span_arrays``, then the cache kind's ``step_aux``."""
+        arrays = self.scheduler.span_arrays(plan, self.prefill_chunk,
+                                            spec_emit=self.spec is not None)
+        aux = self.cache_spec.step_aux(plan, self.max_batch,
+                                       self.prefill_chunk,
+                                       self.kv.oob_block)
+        return arrays if aux is None else \
+            arrays + tuple(aux[k] for k in self._aux_keys)
+
+    def _step_args(self, host):
+        """The step program's thirteen arguments: ``host`` (from
+        :meth:`_host_inputs`) written into the one preallocated int32
+        buffer, moved by ONE ``device_put``, and cut back into arrays on
+        the device by ``_unpack_fn``."""
+        buf = self._packed
+        for (off, n, _, _), a in zip(self._layout, host):
+            buf[off:off + n] = a.reshape(-1).view(np.int32)
+        (tokens, tables, starts, lens, temps, seeds, emit, adapters,
+         aux) = self._unpack_fn(jax.device_put(buf, self._packed_to))
+        return (self.params, self.kv.caches, tokens, tables, starts, lens,
+                temps, self._key, seeds, emit, self._lora_stacks(),
+                adapters, aux)
 
     def hbm_stats(self) -> Dict[str, int]:
         """Live HBM accounting: bytes owned by each device-resident
@@ -1480,27 +1538,23 @@ class Engine:
                     plan = self._run_cow(plan)
                 live_tokens = sum(sp.n for sp in plan)
                 if plan:
-                    (tokens, tables, starts, lens, temps, seeds, emit,
-                     adapters) = self.scheduler.span_arrays(
-                        plan, self.prefill_chunk,
-                        spec_emit=self.spec is not None)
-                    aux = self._device_aux(plan)
+                    host = self._host_inputs(plan)
             nxt = None
             if plan:
-                # device_put of ready numpy arrays only: jnp.asarray of
-                # a Python list/scalar traces a tiny program whose
-                # one-off compile would break the zero-compiles-after-
-                # warmup contract — draft length reaches the step ONLY
-                # inside these traced arrays (span lens/tokens), never
-                # as a per-step Python scalar (pdtpu-lint R4f).  The
-                # same rule covers adapter ids: per-slot DATA in the
-                # adapters array, never a static argument.
+                # ONE device_put of a ready numpy buffer, the step's
+                # per-row arrays packed into it (_step_args), then the
+                # compiled unpack: each transfer costs far more than its
+                # bytes, so one replaces the eight (eleven with a cache
+                # kind's aux).  Ready numpy only: jnp.asarray of a Python
+                # list/scalar traces a tiny program whose one-off compile
+                # would break the zero-compiles-after-warmup contract —
+                # draft length reaches the step ONLY inside the packed
+                # arrays (span lens/tokens), never as a per-step Python
+                # scalar (pdtpu-lint R4f).  The same rule covers adapter
+                # ids: per-slot DATA in the adapters array, never a
+                # static argument.
                 with span("serve.step.dispatch", emit=False):
-                    args = (self.params, self.kv.caches, jnp.asarray(tokens),
-                            jnp.asarray(tables), jnp.asarray(starts),
-                            jnp.asarray(lens), jnp.asarray(temps), self._key,
-                            jnp.asarray(seeds), jnp.asarray(emit),
-                            self._lora_stacks(), jnp.asarray(adapters), aux)
+                    args = self._step_args(host)
                     # two empty marks around the call of the step program,
                     # on the profiler's clock: the call's host time, and
                     # with the device's run the launch after it and the

@@ -1037,6 +1037,7 @@ class TestSpanFanOut:
             outs, _ = self._churn(eng, prompts, new)
             assert tel.sentinel.compiles() - c0 == 0
             assert eng._step_fn._cache_size() == 1
+            assert eng._unpack_fn._cache_size() == 1
             snap = tel.registry.snapshot()
             rows, steps = snap["serve.prefill_rows"], \
                 snap["serve.prefill_steps"]
@@ -1082,6 +1083,165 @@ class TestSpanFanOut:
                 + tiles["count"]
         finally:
             obs.disable()
+
+
+class TestPackedStepInputs:
+    """The step's per-row inputs cross to the device as ONE int32 buffer
+    (``Engine._step_args``), unpacked by ``_unpack_fn``: the same step
+    program as separate arrays give, the same tokens, one host-to-device
+    transfer a planned step."""
+
+    @staticmethod
+    def _engine(kind):
+        """(warmed engine, request kwargs, prompt ids' modulus)."""
+        from paddle_tpu.models.llama import llama
+        pt.seed(0)
+        if kind == "eva":
+            from paddle_tpu.models import evabyte as E
+            cfg = E.EvaByteConfig(hidden_size=64, intermediate_size=128,
+                                  num_hidden_layers=2, num_attention_heads=4,
+                                  max_position_embeddings=512, window_size=64)
+            eng = serving.Engine(E.EvaByteForCausalLM(cfg), max_batch=3,
+                                 max_seq_len=512, num_blocks=64,
+                                 prefill_chunk=16,
+                                 enable_prefix_caching=False)
+            return eng.warmup(), {}, 320
+        model = llama("tiny")
+        kw, req = dict(max_batch=4, max_seq_len=128, page_size=8,
+                       prefill_chunk=4), {}
+        if kind == "spec":
+            kw.update(spec_decode=True, draft_depth=3)
+        if kind == "lora":
+            from paddle_tpu.serving import LoRAPool, random_adapter
+            kw["lora"] = LoRAPool(model, max_adapters=2, rank=8)
+            kw["lora"].load("a", random_adapter(
+                model, rank=8, rng=np.random.default_rng(7), scale=0.05))
+            req = {"adapter": "a"}
+        return serving.Engine(model, **kw).warmup(), req, 256
+
+    @staticmethod
+    def _churn(eng, req, mod):
+        """A decoding request (a motif, so drafts hit), then a long prompt
+        that fans out over the free rows, then a temperature request."""
+        eng.add_request(np.tile(_prompt(5), 6) % mod, max_new_tokens=12)
+        for _ in range(3):
+            eng.step()
+        # (150 closes an EVA window of 64)
+        eng.add_request(_prompt(min(150, eng.max_seq_len - 8)) % mod,
+                        max_new_tokens=4, **req)
+        eng.step()
+        eng.step()
+        eng.add_request(_prompt(9) % mod, max_new_tokens=5,
+                        temperature=0.8)
+        eng.run()
+        assert eng.kv_blocks_used == 0
+
+    @pytest.mark.parametrize("kind", ["fp", "lora", "spec", "eva"])
+    def test_packed_tokens_equal_separate_arrays(self, kind):
+        """Every step's unpacked inputs are its host arrays, value, shape
+        and dtype; the step on them gives the tokens and pools that the
+        step on separate ``jnp.asarray`` arrays gives (the pools copied:
+        the step donates them); and both hit the one compiled step, with
+        nothing compiled after warmup."""
+        import jax
+        import paddle_tpu.observability as obs
+        eng, req, mod = self._engine(kind)
+        real_step, real_inputs = eng._step_fn, eng._host_inputs
+        seen = []
+
+        def host_inputs(plan):
+            seen.append(real_inputs(plan))
+            return seen[-1]
+
+        def step(*args):
+            host = seen[-1]
+            aux = args[12]
+            got = [*args[2:7], *args[8:10], args[11]] + \
+                [aux[k] for k in eng._aux_keys]
+            assert len(got) == len(host) == len(eng._layout)
+            for g, h in zip(got, host):
+                assert g.shape == h.shape and g.dtype == h.dtype
+                np.testing.assert_array_equal(np.asarray(g), h)
+            dev = [jnp.asarray(h) for h in host]
+            separate = (args[0], jax.tree.map(jnp.copy, args[1]), *dev[:5],
+                        args[7], *dev[5:7], args[10], dev[7],
+                        dict(zip(eng._aux_keys, dev[8:])) if aux else None)
+            want, want_caches = real_step(*separate)
+            nxt, caches = real_step(*args)
+            np.testing.assert_array_equal(np.asarray(nxt), np.asarray(want))
+            for a, b in zip(jax.tree.leaves(caches),
+                            jax.tree.leaves(want_caches)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            return nxt, caches
+
+        eng._host_inputs, eng._step_fn = host_inputs, step
+        tel = obs.enable(sinks=[obs.InMemorySink()], crash_hooks=False)
+        try:
+            jax.tree.map(jnp.copy, eng.kv.caches)     # the test's own copy
+            c0 = tel.sentinel.compiles()
+            self._churn(eng, req, mod)
+            assert tel.sentinel.compiles() - c0 == 0
+        finally:
+            obs.disable()
+        assert real_step._cache_size() == 1
+        assert eng._unpack_fn._cache_size() == 1
+        lens = [h[3] for h in seen]
+        # a decode row beside a prompt's fan-out rows, and sampling at a
+        # temperature (float32 temps travel as their bits)
+        assert any((ln == 1).any() and (ln > 1).sum() > 1 for ln in lens)
+        assert any((h[4] > 0).any() for h in seen)
+        if kind == "lora":
+            assert any((h[7] > 0).any() for h in seen)
+        if kind == "spec":
+            assert eng.spec_stats()["proposed"] > 0
+        if kind == "eva":
+            assert eng._aux_keys and len(seen[0]) == 8 + len(eng._aux_keys)
+
+    @pytest.mark.parametrize("kind", ["fp", "eva"])
+    def test_one_transfer_a_planned_step(self, kind, monkeypatch):
+        """A planned ``step_begin`` moves exactly one host buffer to the
+        device, the packed one: any other host-to-device transfer raises
+        under the guard."""
+        import jax
+        eng, req, mod = self._engine(kind)
+        real_put, puts = jax.device_put, []
+
+        def put(x, *a, **kw):
+            puts.append(x.nbytes)
+            with jax.transfer_guard_host_to_device("allow"):
+                return real_put(x, *a, **kw)
+
+        eng.add_request(_prompt(40) % mod, max_new_tokens=5, **req)
+        eng.add_request(_prompt(7) % mod, max_new_tokens=3, **req)
+        monkeypatch.setattr(jax, "device_put", put)
+        planned = 0
+        while eng.has_work():
+            n = len(puts)
+            with jax.transfer_guard_host_to_device("disallow_explicit"):
+                pending = eng.step_begin()
+            assert len(puts) - n == (1 if pending[0] else 0)
+            planned += bool(pending[0])
+            eng.step_finish(pending)
+        assert planned > 1 and len(puts) == planned
+        assert set(puts) == {eng._packed.nbytes}
+
+    @pytest.mark.parametrize("kind", ["fp", "eva"])
+    def test_the_benchmarks_lowering_of_the_step_is_unchanged(self, kind):
+        """``_step_fn`` lowered with thirteen separate arguments, as the
+        benchmark's serving runner lowers it after ``warmup``, gives the
+        text that the packed path's arguments give, lowered and
+        compiled."""
+        eng, _, _ = self._engine(kind)
+        b, mb, c = eng.max_batch, eng.max_blocks_per_seq, eng.prefill_chunk
+        zeros = jnp.zeros((b,), jnp.int32)
+        separate = eng._step_fn.lower(
+            eng.params, eng.kv.caches, jnp.zeros((b, c), jnp.int32),
+            jnp.full((b, mb), eng.kv.oob_block, jnp.int32), zeros, zeros,
+            jnp.zeros((b,), jnp.float32), eng._key, zeros, zeros,
+            eng._lora_stacks(), zeros, eng._device_aux([]))
+        packed = eng._step_fn.lower(*eng._step_args(eng._host_inputs([])))
+        assert separate.as_text() == packed.as_text()
+        assert separate.compile().as_text() == packed.compile().as_text()
 
 
 class TestFaultIsolation:

@@ -865,6 +865,7 @@ def gate_serving_smoke(max_batch: int = 4, n_requests: int = 10) -> int:
                   "joined/left the batch: 0 compiles after warmup")
         sizes = []
         for fn, want, name in ((eng._step_fn, 1, "step"),
+                               (eng._unpack_fn, 1, "unpack"),
                                (eng._cow_fn, 1, "cow")):
             n = getattr(fn, "_cache_size", lambda: None)()
             sizes.append(f"{name}={n}")
@@ -1604,7 +1605,8 @@ def gate_serving_dist(max_batch: int = 4) -> int:
         if churn_compiles:
             failures.append(
                 f"TP=2: {churn_compiles} compile(s) after warmup")
-        for fn, name in ((eng._step_fn, "step"), (eng._cow_fn, "cow")):
+        for fn, name in ((eng._step_fn, "step"),
+                         (eng._unpack_fn, "unpack"), (eng._cow_fn, "cow")):
             n = getattr(fn, "_cache_size", lambda: None)()
             if n is not None and n > 1:
                 failures.append(
