@@ -3,11 +3,22 @@ attention core, the loss, AdamW with global-norm clipping, and the
 numbers a training cell compares.  ``jax.numpy`` in float32 with
 ``precision=HIGHEST``; nothing here imports the program.
 
-A *family* module (``llama_ref``, ``gpt_ref``) gives four functions:
-``embed(params, ids, cfg)``, ``layer(x, lp, cfg, prec)`` over one
-sequence ``(S, h)`` with ``lp`` the layer's own leaves by their short
-names, ``head(x, params, cfg, prec)`` -> logits, and
-``layer_prefix(i)`` for the names of layer ``i``'s leaves.
+A *family* module (``llama_ref``, ``gpt_ref``, ``evabyte_ref``,
+``qwen3_next_ref``) gives:
+
+- ``layer_kind(cfg, i)``: a small hashable tag for the equations of
+  layer ``i`` (a family whose layers are all alike returns one tag);
+- ``layer_shapes(cfg, i)``: layer ``i``'s leaves, short name -> shape;
+- ``layer(x, lp, cfg, prec, kind)``: one layer of that kind over one
+  sequence ``(S, h)``, with ``lp`` the layer's own leaves by their short
+  names;
+- ``embed(params, ids, cfg)``, ``head(x, params, cfg, prec)`` -> logits,
+  ``layer_prefix(i)`` for the names of layer ``i``'s leaves and
+  ``outer_names(cfg)`` for the leaves outside the layers.
+
+Layers of one kind may hold the same leaves and differ in their
+equations alone (a window against full attention, RoPE against none):
+the kind, not the leaves, says which.
 """
 
 from __future__ import annotations
@@ -62,29 +73,54 @@ class Precision:
         return jnp.matmul(self._round(x), self._round(w), precision=HIGHEST)
 
 
-def causal_attention(q, k, v):
+def causal_attention(q, k, v, window: int = None, block: int = 512):
     """q ``(S, H, D)``, k and v ``(S, KV, D)`` with ``H = G * KV`` (query
-    head ``i`` reads KV head ``i // G``): causal softmax attention, one
-    query head at a time and recomputed in the backward pass, so that only
-    one ``S x S`` block of scores lives at once.  Returns ``(S, H * D)``."""
+    head ``i`` reads KV head ``i // G``): causal softmax attention in
+    which query ``t`` sees key ``s`` when ``0 <= t - s < window`` (every
+    ``s <= t`` when ``window`` is None).  Returns ``(S, H * D)``.
+
+    One query head at a time and ``block`` query rows at a time, each
+    recomputed in the backward pass, so that one ``block x S`` slab of
+    scores lives at once (``block x (block + window - 1)`` where a window
+    leaves the rest of the keys unseen), and never an ``S x S`` one."""
     s, h, d = q.shape
     g = h // k.shape[1]
-    qh = q.transpose(1, 0, 2)                              # (H, S, D)
+    block = min(block, s)
+    nb = -(-s // block)
+    # keys a block of queries can see: all of them, or a slice that
+    # covers the block's rows and the window behind its first
+    span = s if window is None or block + window - 1 >= s \
+        else block + window - 1
+    qh = jnp.pad(q, ((0, nb * block - s), (0, 0), (0, 0))).transpose(1, 0, 2)
     kh = jnp.repeat(k.transpose(1, 0, 2), g, axis=0)       # (H, S, D)
     vh = jnp.repeat(v.transpose(1, 0, 2), g, axis=0)
-    mask = jnp.tril(jnp.ones((s, s), bool))
 
-    @jax.checkpoint
-    def one(args):
-        q1, k1, v1 = args
-        sc = jnp.einsum("qd,kd->qk", q1, k1, precision=HIGHEST)
-        sc = sc * (1.0 / d ** 0.5)
-        sc = jnp.where(mask, sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("qk,kd->qd", p, v1, precision=HIGHEST)
+    def one_head(args):
+        q1, k1, v1 = args                                  # (nb*B, D), (S, D)
 
-    out = jax.lax.map(one, (qh, kh, vh))                   # (H, S, D)
-    return out.transpose(1, 0, 2).reshape(s, h * d)
+        @jax.checkpoint
+        def one_block(b):
+            qs = jax.lax.dynamic_slice_in_dim(q1, b * block, block)
+            lo = jnp.clip(b * block - (span - block), 0, s - span)
+            ks = jax.lax.dynamic_slice_in_dim(k1, lo, span)
+            vs = jax.lax.dynamic_slice_in_dim(v1, lo, span)
+            # the padded rows past S read as row S - 1, so that none of
+            # them is left with no key (and a NaN in the backward pass)
+            t = jnp.minimum(b * block + jnp.arange(block), s - 1)[:, None]
+            dist = t - (lo + jnp.arange(span))[None, :]
+            seen = dist >= 0
+            if window is not None:
+                seen &= dist < window
+            sc = jnp.einsum("qd,kd->qk", qs, ks, precision=HIGHEST)
+            sc = sc * (1.0 / d ** 0.5)
+            sc = jnp.where(seen, sc, -jnp.inf)
+            p = jax.nn.softmax(sc, axis=-1)
+            return jnp.einsum("qk,kd->qd", p, vs, precision=HIGHEST)
+
+        return jax.lax.map(one_block, jnp.arange(nb)).reshape(nb * block, d)
+
+    out = jax.lax.map(jax.checkpoint(one_head), (qh, kh, vh))  # (H, nb*B, D)
+    return out[:, :s].transpose(1, 0, 2).reshape(s, h * d)
 
 
 def cross_entropy_sum(logits, labels):
@@ -93,18 +129,20 @@ def cross_entropy_sum(logits, labels):
     return jnp.sum(logz - picked)
 
 
-def layer_leaves(params: dict, prefix: str) -> dict:
-    n = len(prefix)
-    return {k[n:]: v for k, v in params.items() if k.startswith(prefix)}
+def layer_leaves(family, params: dict, cfg, i: int) -> dict:
+    """Layer ``i``'s leaves by their short names."""
+    prefix = family.layer_prefix(i)
+    return {k: params[prefix + k] for k in family.layer_shapes(cfg, i)}
 
 
 def sequence_hidden(family, params, ids, cfg, prec, layers: int):
     """The last layer's output ``(S, h)`` for one sequence."""
     x = family.embed(params, ids, cfg)
     for i in range(layers):
-        lp = layer_leaves(params, family.layer_prefix(i))
+        kind = family.layer_kind(cfg, i)
         x = jax.checkpoint(
-            lambda x, lp: family.layer(x, lp, cfg, prec))(x, lp)
+            lambda x, lp, kind=kind: family.layer(x, lp, cfg, prec, kind))(
+                x, layer_leaves(family, params, cfg, i))
     return x
 
 
@@ -296,16 +334,18 @@ def served_token_gaps(family, cfg, layers: int, weights_of, sequences: list,
     outer = weights_of(family.outer_names(cfg))
     embed = jax.jit(lambda p, i: family.embed(p, i, cfg))
     xs = {m: [embed(outer, i) for i in ids] for m in modes}
-    layer_fns = {m: jax.jit(lambda x, lp, m=m: family.layer(
-        x, lp, cfg, Precision(m))) for m in modes}
-    shapes = family.layer_shapes(cfg)
+    layer_fns = {}          # one compiled layer per (mode, kind)
     for i in range(layers):
-        prefix = family.layer_prefix(i)
-        full = weights_of([prefix + k for k in shapes])
-        lp = {k: full[prefix + k] for k in shapes}
+        kind, prefix = family.layer_kind(cfg, i), family.layer_prefix(i)
+        lp = layer_leaves(family, weights_of(
+            [prefix + k for k in family.layer_shapes(cfg, i)]), cfg, i)
         for m in modes:
-            xs[m] = [layer_fns[m](x, lp) for x in xs[m]]
-        del full, lp
+            if (m, kind) not in layer_fns:
+                layer_fns[m, kind] = jax.jit(
+                    lambda x, lp, m=m, kind=kind: family.layer(
+                        x, lp, cfg, Precision(m), kind))
+            xs[m] = [layer_fns[m, kind](x, lp) for x in xs[m]]
+        del lp
 
     def read(x, start, tok, outer, m):
         """(logits of the ``max_out`` positions from ``start``, the gap of

@@ -64,8 +64,12 @@ def outer_names(cfg: dict) -> list:
             "lm_head.weight"]
 
 
-def layer_shapes(cfg: dict) -> dict:
-    h, i = cfg["hidden_size"], cfg["intermediate_size"]
+def layer_kind(cfg: dict, i: int) -> str:
+    return "eva"
+
+
+def layer_shapes(cfg: dict, i: int) -> dict:
+    h, f = cfg["hidden_size"], cfg["intermediate_size"]
     nh, d = cfg["num_attention_heads"], cfg["head_dim"]
     return {"input_layernorm.weight": (h,),
             "self_attn.q_proj.weight": (h, nh * d),
@@ -75,9 +79,9 @@ def layer_shapes(cfg: dict) -> dict:
             "self_attn.adaptive_phi": (nh, d),
             "self_attn.adaptive_mu_k": (nh, d),
             "post_attention_layernorm.weight": (h,),
-            "mlp.gate_proj.weight": (h, i),
-            "mlp.up_proj.weight": (h, i),
-            "mlp.down_proj.weight": (i, h)}
+            "mlp.gate_proj.weight": (h, f),
+            "mlp.up_proj.weight": (h, f),
+            "mlp.down_proj.weight": (f, h)}
 
 
 def param_shapes(cfg: dict, layers: int) -> dict:
@@ -86,7 +90,7 @@ def param_shapes(cfg: dict, layers: int) -> dict:
     out = {"model.embed_tokens.weight": (v, h), "model.norm.weight": (h,),
            "lm_head.weight": (h, cfg["num_pred_heads"] * v)}
     for i in range(layers):
-        for k, s in layer_shapes(cfg).items():
+        for k, s in layer_shapes(cfg, i).items():
             out[layer_prefix(i) + k] = s
     return out
 
@@ -161,7 +165,7 @@ def embed(params, ids, cfg):
     return params["model.embed_tokens.weight"].astype(jnp.float32)[ids]
 
 
-def layer(x, lp, cfg, prec):
+def layer(x, lp, cfg, prec, kind):
     lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
     s = x.shape[0]
     d, nh = cfg["head_dim"], cfg["num_attention_heads"]

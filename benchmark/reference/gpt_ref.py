@@ -27,7 +27,11 @@ def outer_names(cfg: dict) -> list:
     return names
 
 
-def layer_shapes(cfg: dict) -> dict:
+def layer_kind(cfg: dict, i: int) -> str:
+    return "full"
+
+
+def layer_shapes(cfg: dict, i: int) -> dict:
     h, f = cfg["hidden_size"], cfg["intermediate_size"]
     return {"ln_1.weight": (h,), "ln_1.bias": (h,),
             "attn.qkv_proj.weight": (h, 3 * h), "attn.qkv_proj.bias": (3 * h,),
@@ -45,7 +49,7 @@ def param_shapes(cfg: dict, layers: int) -> dict:
     if not cfg["tie_word_embeddings"]:
         out["lm_head.weight"] = (h, v)
     for i in range(layers):
-        for k, s in layer_shapes(cfg).items():
+        for k, s in layer_shapes(cfg, i).items():
             out[layer_prefix(i) + k] = s
     return out
 
@@ -63,7 +67,7 @@ def embed(params, ids, cfg):
     return wte[ids] + wpe[:s]
 
 
-def layer(x, lp, cfg, prec):
+def layer(x, lp, cfg, prec, kind):
     lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
     s = x.shape[0]
     nh, d, eps = cfg["num_attention_heads"], cfg["head_dim"], \
@@ -96,6 +100,7 @@ def head(x, params, cfg, prec):
 
 
 def matmul_params(cfg: dict, layers: int) -> int:
-    per = sum(a * b for (a, b) in
-              (s for s in layer_shapes(cfg).values() if len(s) == 2))
-    return layers * per + cfg["hidden_size"] * cfg["vocab_size"]
+    return sum(a * b for i in range(layers)
+               for (a, b) in (s for s in layer_shapes(cfg, i).values()
+                              if len(s) == 2)) \
+        + cfg["hidden_size"] * cfg["vocab_size"]

@@ -25,8 +25,12 @@ def outer_names(cfg: dict) -> list:
     return names
 
 
-def layer_shapes(cfg: dict) -> dict:
-    h, i = cfg["hidden_size"], cfg["intermediate_size"]
+def layer_kind(cfg: dict, i: int) -> str:
+    return "full"
+
+
+def layer_shapes(cfg: dict, i: int) -> dict:
+    h, f = cfg["hidden_size"], cfg["intermediate_size"]
     d = cfg["head_dim"]
     nq, nk = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
     return {"input_layernorm.weight": (h,),
@@ -35,9 +39,9 @@ def layer_shapes(cfg: dict) -> dict:
             "self_attn.v_proj.weight": (h, nk),
             "self_attn.o_proj.weight": (nq, h),
             "post_attention_layernorm.weight": (h,),
-            "mlp.gate_proj.weight": (h, i),
-            "mlp.up_proj.weight": (h, i),
-            "mlp.down_proj.weight": (i, h)}
+            "mlp.gate_proj.weight": (h, f),
+            "mlp.up_proj.weight": (h, f),
+            "mlp.down_proj.weight": (f, h)}
 
 
 def param_shapes(cfg: dict, layers: int) -> dict:
@@ -47,7 +51,7 @@ def param_shapes(cfg: dict, layers: int) -> dict:
     if not cfg["tie_word_embeddings"]:
         out["lm_head.weight"] = (h, v)
     for i in range(layers):
-        for k, s in layer_shapes(cfg).items():
+        for k, s in layer_shapes(cfg, i).items():
             out[layer_prefix(i) + k] = s
     return out
 
@@ -72,7 +76,7 @@ def embed(params, ids, cfg):
     return params["model.embed_tokens.weight"].astype(jnp.float32)[ids]
 
 
-def layer(x, lp, cfg, prec):
+def layer(x, lp, cfg, prec, kind):
     lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
     s = x.shape[0]
     d, nh, nkv = (cfg["head_dim"], cfg["num_attention_heads"],
@@ -110,6 +114,7 @@ def head(x, params, cfg, prec):
 def matmul_params(cfg: dict, layers: int) -> int:
     """Parameters that a token multiplies: the layers' matrices and the
     output head; the input embedding is a lookup."""
-    per = sum(a * b for (a, b) in
-              (s for s in layer_shapes(cfg).values() if len(s) == 2))
-    return layers * per + cfg["hidden_size"] * cfg["vocab_size"]
+    return sum(a * b for i in range(layers)
+               for (a, b) in (s for s in layer_shapes(cfg, i).values()
+                              if len(s) == 2)) \
+        + cfg["hidden_size"] * cfg["vocab_size"]

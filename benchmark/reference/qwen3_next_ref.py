@@ -44,6 +44,11 @@ def is_full_attention(cfg: dict, i: int) -> bool:
     return (i + 1) % cfg["full_attention_interval"] == 0
 
 
+def layer_kind(cfg: dict, i: int) -> str:
+    """``"full"``: gated softmax attention; ``"linear"``: gated delta."""
+    return "full" if is_full_attention(cfg, i) else "linear"
+
+
 def outer_names(cfg: dict) -> list:
     return ["model.embed_tokens.weight", "model.norm.weight",
             "lm_head.weight"]
@@ -63,7 +68,7 @@ def sparse_shapes(cfg: dict) -> dict:
 
 
 def layer_shapes(cfg: dict, i: int) -> dict:
-    """The leaves of layer ``i``: its kind follows from its index."""
+    """The leaves of layer ``i``: they follow from its kind."""
     h = cfg["hidden_size"]
     out = {"input_layernorm.weight": (h,),
            "post_attention_layernorm.weight": (h,)}
@@ -262,13 +267,12 @@ def embed(params, ids, cfg):
     return params["model.embed_tokens.weight"].astype(jnp.float32)[ids]
 
 
-def layer(x, lp, cfg, prec):
-    """One layer over one sequence ``(S, h)``; its kind is told from the
-    leaves it is given."""
+def layer(x, lp, cfg, prec, kind):
+    """One layer of ``kind`` (``layer_kind``) over one sequence ``(S, h)``."""
     lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
     eps = cfg["rms_norm_eps"]
     a = norm(x, lp["input_layernorm.weight"], eps)
-    mixer = gated_delta if "linear_attn.A_log" in lp else full_attention
+    mixer = {"linear": gated_delta, "full": full_attention}[kind]
     x = x + mixer(a, lp, cfg, prec)
     return x + sparse_block(
         norm(x, lp["post_attention_layernorm.weight"], eps), lp, cfg, prec)

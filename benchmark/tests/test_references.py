@@ -14,7 +14,8 @@ from benchmark import weights
 from benchmark.reference import common
 
 
-@pytest.mark.parametrize("name", ["mistral-7b", "gpt3-6.7b"])
+@pytest.mark.parametrize("name", ["mistral-7b", "gpt3-6.7b", "evabyte",
+                                  "qwen3-next-80b-a3b"])
 def test_reference_matches_program_forward(name):
     from paddle_tpu.nn.layer import functional_call, raw_params
 
@@ -22,15 +23,17 @@ def test_reference_matches_program_forward(name):
     builder = importlib.import_module("benchmark.builders." + config["builder"])
     family = importlib.import_module("benchmark.reference."
                                      + config["reference"])
-    model = builder.build_model(config, 2, 64)
+    # Qwen3-Next's fourth layer is its first of full attention
+    layers = 4 if name == "qwen3-next-80b-a3b" else 2
+    model = builder.build_model(config, layers, 64)
     shapes = {k: tuple(v.shape) for k, v in raw_params(model).items()}
-    assert shapes == family.param_shapes(config, 2)
+    assert shapes == family.param_shapes(config, layers)
     params = weights.make_weights(shapes, 2**31 + 3, dtype="float32")
     ids = np.random.default_rng(0).integers(0, config["vocab_size"], (2, 48))
     got = functional_call(model, params, jnp.asarray(ids), training=False)
     for row in range(2):
         ref = common.sequence_logits(family, params, jnp.asarray(ids[row]),
-                                     config, common.Precision("f32"), 2)
+                                     config, common.Precision("f32"), layers)
         np.testing.assert_allclose(np.asarray(got[row]), np.asarray(ref),
                                    atol=2e-5, rtol=1e-4)
 
